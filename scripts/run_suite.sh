@@ -9,11 +9,6 @@ cd "$(dirname "$0")/.."
 TIER="${1:-fast}"
 PYTEST=(python -m pytest -q -p no:randomly)
 
-run_gate() {
-  echo "== multichip gate (driver-shape invocation -> MULTICHIP_LOCAL.json) =="
-  python scripts/multichip_check.py 8
-}
-
 run_lint() {
   # static-analysis lane (budget <30s, no device/JAX needed): tpulint
   # enforces the engine invariants (host-sync accounting, semaphore
@@ -27,24 +22,16 @@ run_lint() {
   python scripts/gen_configs_doc.py --check
   # bench-round drift gate: the differ's synthetic-round behavior
   # checks (regression detected -> non-zero exit, improvement passes,
-  # missing phase tolerated), then a report-only diff of the two
-  # newest committed rounds so round-to-round drift is visible in
-  # every lint run without gating on environmental noise
+  # missing phase tolerated)
   python scripts/bench_diff.py --selftest
-  latest=$(ls BENCH_r*.json 2>/dev/null | sort | tail -2)
-  if [ "$(echo "$latest" | wc -l)" -eq 2 ]; then
-    # shellcheck disable=SC2086
-    python scripts/bench_diff.py $latest --no-gate | tail -5
-  fi
 }
 
 run_fast() {
   run_lint
-  run_gate
   echo "== fast tier (unit + integration, virtual 8-device CPU mesh) =="
-  "${PYTEST[@]}" tests/ -m "not slow" --ignore=tests/test_workloads.py
+  "${PYTEST[@]}" tests/ -m "not slow" --ignore-glob="tests/test_workloads_*.py"
   echo "== workload parity (TPC-H / TPC-DS / TPCx-BB / Mortgage) =="
-  "${PYTEST[@]}" tests/test_workloads.py
+  "${PYTEST[@]}" tests/test_workloads_*.py
   run_oom_soak
   run_pipeline
   run_recovery
@@ -150,7 +137,7 @@ print("kernelprof summary: kernels=%d dispatches=%d kernel_ms=%.1f "
       "(%s-bound) catalog=%d" % (
           len(rows), sum(r["dispatches"] for r in rows), kernel_ms,
           compute_ms, cov, top["label"], top["device_ms"],
-          top.get("roofline_pct", 0.0), top.get("bound", "?"),
+          top.get("roofline_pct") or 0.0, top.get("bound") or "?",
           KP.catalog_size()))
 KP.reset()
 PYEOF
@@ -703,7 +690,6 @@ run_bench() {
 
 case "$TIER" in
   lint)     run_lint ;;
-  gate)     run_gate ;;
   fast)     run_fast ;;
   slow)     run_slow ;;
   shims)    run_shims ;;
@@ -723,6 +709,6 @@ case "$TIER" in
   residency) run_residency ;;
   oocore)   run_oocore ;;
   all)      run_fast; run_slow; run_shims; run_bench ;;
-  *) echo "usage: $0 [lint|gate|fast|slow|shims|bench|oom|pipeline|recovery|watchdog|profile|movement|concurrency|fusion|spmd|speculation|telemetry|kernelprof|residency|oocore|all]" >&2
+  *) echo "usage: $0 [lint|fast|slow|shims|bench|oom|pipeline|recovery|watchdog|profile|movement|concurrency|fusion|spmd|speculation|telemetry|kernelprof|residency|oocore|all]" >&2
      exit 2 ;;
 esac

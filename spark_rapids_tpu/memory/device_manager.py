@@ -23,7 +23,10 @@ from spark_rapids_tpu import config as C
 
 log = logging.getLogger("spark_rapids_tpu.device_manager")
 
-_DEFAULT_HBM = 16 * 1024**3  # v5p chip-class default when PJRT has no stats
+#: stand-in HBM size for the CPU TEST BACKEND only (XLA:CPU devices
+#: report no memory_stats); never used on a TPU, where a missing
+#: `bytes_limit` is an error
+_CPU_TEST_HBM = 16 * 1024**3
 
 
 class SpillCallback:
@@ -88,6 +91,9 @@ class DeviceManager:
         conf = conf or C.get_active_conf()
         self.conf = conf
         self.device = self._pick_device()
+        #: where hbm_total came from: "conf" (caller-supplied),
+        #: "memory_stats" (the TPU's own report) or "cpu-test-constant"
+        self.hbm_total_source = "conf"
         total = hbm_total or self._query_hbm_total()
         frac = conf[C.HBM_ALLOC_FRACTION]
         reserve = conf[C.HBM_RESERVE]
@@ -141,29 +147,28 @@ class DeviceManager:
     # -- device ---------------------------------------------------------------
     @staticmethod
     def _pick_device():
+        """First device of the default backend: the TPU on the chip,
+        a CPU device only where the process was started on the CPU
+        backend (the tests' lane)."""
         import jax
-        devs = jax.devices()
-        for d in devs:
-            if d.platform == "tpu":
-                return d
-        return devs[0]
+        return jax.devices()[0]
 
     def _query_hbm_total(self) -> int:
-        try:
-            stats = self.device.memory_stats()
-            if stats and "bytes_limit" in stats:
-                return int(stats["bytes_limit"])
-        except Exception:
-            pass
-        return _DEFAULT_HBM
+        if self.device.platform != "tpu":
+            self.hbm_total_source = "cpu-test-constant"
+            return _CPU_TEST_HBM
+        stats = self.device.memory_stats()
+        if not stats or "bytes_limit" not in stats:
+            raise RuntimeError(
+                f"{self.device} reports no memory_stats()['bytes_limit']"
+                "; the HBM budget cannot be derived")
+        self.hbm_total_source = "memory_stats"
+        return int(stats["bytes_limit"])
 
     def resident_bytes(self) -> int:
-        try:
-            stats = self.device.memory_stats()
-            if stats and "bytes_in_use" in stats:
-                return int(stats["bytes_in_use"])
-        except Exception:
-            pass
+        stats = self.device.memory_stats()  # None on the CPU backend
+        if stats and "bytes_in_use" in stats:
+            return int(stats["bytes_in_use"])
         with self._acct:
             return self._store_bytes + self._reserved
 

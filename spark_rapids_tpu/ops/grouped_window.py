@@ -34,7 +34,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from spark_rapids_tpu.ops.pallas_kernels import _LANES, _on_tpu, _x64_off
+from spark_rapids_tpu.ops.pallas_kernels import (_LANES, _note_mosaic,
+                                                 _on_tpu, _x64_off)
 
 #: rows per block == slab width.  256 keeps the one-hot [R, 2R] at
 #: 256x512 (two MXU tiles) and the locals array at cap/R x M x 2R f32.
@@ -106,6 +107,8 @@ def window_group_sums(gid, vals, *, out_cap: int, capacity: int,
     ins = [gid.reshape(1, -1)] + [v.astype(jnp.float32).reshape(1, -1)
                                   for v in vals]
     block_in = pl.BlockSpec((1, r), lambda i: (0, i))
+    if not interpret_kernel:
+        _note_mosaic("window_group_sums")
     with _x64_off():
         locals_ = pl.pallas_call(
             functools.partial(_window_block_kernel,

@@ -28,10 +28,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-try:  # jax >= 0.6 promoted shard_map to the top-level namespace
-    from jax import shard_map
-except ImportError:  # jax 0.4.x ships it under experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.ops.murmur3 import partition_ids as murmur3_pids

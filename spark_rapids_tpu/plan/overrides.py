@@ -290,7 +290,16 @@ def _tag_aggregate(meta) -> None:
 def _conv_source(meta, kids) -> TpuExec:
     node: N.CpuSource = meta.node
     from spark_rapids_tpu.plan.transitions import batch_from_df
-    parts = [[batch_from_df(df, node.output_schema())] if len(df) else []
+    # chunk at the upload boundary like every other source (transitions
+    # RowToColumnarExec / HostColumnarToDeviceExec): device batch
+    # capacities stay in batchMaxRows' bounded bucket set.  Whole
+    # partitions as one batch made an SF1 lineitem partition a 4M-row
+    # kernel shape, and XLA:TPU compile time grows steeply with it (q6's
+    # reduce kernel: past the 300 s task watchdog on the v5e's host).
+    max_rows = meta.conf[C.MAX_BATCH_ROWS]
+    schema = node.output_schema()
+    parts = [[batch_from_df(df.iloc[lo:lo + max_rows], schema)
+              for lo in range(0, len(df), max_rows)]
              for df in node.partitions]
     src = B.LocalBatchSource(parts, node.output_schema())
     # stable identity across plan rebuilds: the uploaded device batches

@@ -53,7 +53,7 @@ def _split_kernel_for(cache: KernelCache, batch: ColumnarBatch,
             ctx = make_eval_context(columns, cap, num_rows, mask)
             pids = pid_fn(ctx, salt, extra)
             pids = jnp.where(ctx.row_mask, pids, num_partitions)
-            cols, counts = _payload_sort_reorder(
+            cols, counts = _pid_sort_reorder(
                 pids, columns, ctx.row_mask, num_partitions)
             return cols, counts
 
@@ -62,62 +62,33 @@ def _split_kernel_for(cache: KernelCache, batch: ColumnarBatch,
     return cache.get_or_build(key, build)
 
 
-def _payload_sort_reorder(pids, columns, row_mask, npart: int):
-    """Stable partition reorder via ONE payload-carrying sort network.
+def _pid_sort_reorder(pids, columns, row_mask, npart: int):
+    """Stable partition reorder by pid: ONE (pid, iota) sort, then the
+    columns follow through `_gather_reordered` (a few stacked gathers).
 
-    Every column array (data, validity, lengths, narrow shadows) rides
-    the pid sort as a PAYLOAD operand: measured at 4M rows, the u32
-    sort network costs ~172ms and six 64-bit payload operands add <10%
-    — while the old two-step (counting-sort ranks + inversion scatter
-    ~202ms, then per-stream gathers at ~53ns per 4-byte ELEMENT,
-    ~250ms for two streams) paid per element moved.  Random access is
-    the most expensive primitive on this chip; the sort network moves
-    payloads with vectorized compare-exchanges instead.
-
-    Only string CHAR MATRICES (2D) can't ride along (lax.sort operands
-    must share one shape) — those gather through a carried iota order.
+    The sort network carries nothing but the iota.  The columns used to
+    ride it as payload operands, and XLA:TPU variadic-sort COMPILE time
+    grows steeply with operand count past ~16K rows: compiled for a
+    described v5e (host time, no chip), a stable 64K-row sort took 28 s
+    with 2 operands, 78 s with 5, 185 s with 9, and the 13-operand split
+    of TPC-H q3's lineitem 514 s — one kernel, per batch shape (this
+    form: 14.7 s).  Whether payload operands beat the gathers at run
+    time: not measured on the current machine.
     Returns (reordered ColumnVectors, per-partition counts)."""
     from jax import lax
-    from spark_rapids_tpu.columnar.vector import ColumnVector
     cap = pids.shape[0]
     # counts via one-hot reduce (bincount lowers to a serialized
     # scatter-add on XLA:TPU)
     counts = (pids[:, None] ==
               jnp.arange(npart, dtype=pids.dtype)[None, :]
               ).astype(jnp.int32).sum(axis=0)
-    ops = [pids.astype(jnp.uint32)]
-    any_string = any(c.dtype.is_string for c in columns)
-    if any_string:
-        ops.append(lax.iota(jnp.int32, cap))
-    ops.append(row_mask)
-    slots = []
-    for c in columns:
-        start = len(ops)
-        if c.dtype.is_string:
-            ops.extend([c.validity, c.lengths])
-        else:
-            ops.append(c.data)
-            ops.append(c.validity)
-            if c.narrow is not None:
-                ops.append(c.narrow)
-        slots.append((start, len(ops)))
-    sortd = lax.sort(ops, num_keys=1, is_stable=True)
-    pos = 2 if any_string else 1
-    order = sortd[1] if any_string else None
-    valid = sortd[pos]
-    out = []
-    for c, (start, _end) in zip(columns, slots):
-        if c.dtype.is_string:
-            v, ln = sortd[start], sortd[start + 1]
-            data = jnp.take(c.data, order, axis=0, mode="clip")
-            out.append(ColumnVector(c.dtype, data, v & valid, ln))
-        else:
-            data = sortd[start]
-            v = sortd[start + 1]
-            narrow = sortd[start + 2] if c.narrow is not None else None
-            out.append(ColumnVector(c.dtype, data, v & valid, None,
-                                    narrow))
-    return out, counts
+    # iota as the last key of an unstable sort == the stable order,
+    # without the tie-break operand a stable sort adds
+    _, order = lax.sort([pids.astype(jnp.uint32),
+                         lax.iota(jnp.int32, cap)],
+                        num_keys=2, is_stable=False)
+    valid = jnp.take(row_mask, order)
+    return _gather_reordered(columns, order, valid), counts
 
 
 def _gather_reordered(columns, order, valid, packed_bits=None):

@@ -485,10 +485,14 @@ class QueryKernelLedger:
                 gb = byts / est_s / 1e9
                 row["gflops"] = round(gf, 3)
                 row["gbps"] = round(gb, 3)
-                cf = gf / peak_gf if peak_gf > 0 else 0.0
-                mf = gb / hbm if hbm > 0 else 0.0
-                row["roofline_pct"] = round(100.0 * max(cf, mf), 3)
-                row["bound"] = "compute" if cf >= mf else "memory"
+                if peak_gf is None or hbm is None:
+                    # a device with no nominal peaks (utils/roofline
+                    # DEVICE_PEAKS): no share, never another chip's
+                    row["roofline_pct"] = row["bound"] = None
+                else:
+                    cf, mf = gf / peak_gf, gb / hbm
+                    row["roofline_pct"] = round(100.0 * max(cf, mf), 3)
+                    row["bound"] = "compute" if cf >= mf else "memory"
             rows.append(row)
         rows.sort(key=lambda r: r["device_ms"], reverse=True)
         return rows
@@ -505,7 +509,7 @@ def format_report(rows: list, top_n: int = 12) -> str:
     for r in rows[:top_n]:
         roof = (f"  {r['gflops']:.1f} GF/s {r['gbps']:.2f} GB/s "
                 f"{r['roofline_pct']:.2f}% roofline ({r['bound']})"
-                if "roofline_pct" in r else "")
+                if r.get("roofline_pct") is not None else "")
         owner = f"  <- {r['owners'][0]}" if r["owners"] else ""
         members = (f" [{'+'.join(r['members'])}]"
                    if r["members"] else "")

@@ -601,7 +601,7 @@ def _fmt_kernel_annot(rows: list) -> str:
     inside one [..] so every report line still ends with a bracket)."""
     top = rows[0]
     roof = (f" {top['roofline_pct']}%-roofline {top['bound']}-bound"
-            if "roofline_pct" in top else "")
+            if top.get("roofline_pct") is not None else "")
     more = f" +{len(rows) - 1} more" if len(rows) > 1 else ""
     return (f"  [kernel {top['fingerprint']} {top['device_ms']}ms "
             f"x{top['dispatches']}{roof}{more}]")

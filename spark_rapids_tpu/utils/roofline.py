@@ -14,7 +14,11 @@ percentages judge against the same numbers.
 Edge ceilings use the movement ledger's edge names (upload / readback /
 spill / wire / collective); the compute side adds the HBM bandwidth
 ceiling and the peak-GFLOP/s ceiling the per-kernel roofline join
-needs.  Defaults are v5e-class nominals — see each conf's doc.
+needs.  The two device ceilings (HBM GB/s, peak GFLOP/s) come from
+`DEVICE_PEAKS`, keyed by the `device_kind` JAX reports, unless the conf
+overrides them; a device that is not in the table has NO ceiling, and
+the roofline shares judged against it are None, never a number graded
+against some other chip.
 """
 from __future__ import annotations
 
@@ -58,13 +62,32 @@ def edge_table(conf: Optional[C.RapidsConf] = None) -> dict:
     return {edge: edge_gbps(edge, conf) for edge in _EDGE_CONFS}
 
 
-def hbm_gbps(conf: Optional[C.RapidsConf] = None) -> float:
+#: nominal per-chip peaks by `jax.devices()[0].device_kind`.
+#: "TPU v5 lite" is the v5e — source: Google Cloud documentation,
+#: "TPU v5e": 819 GB/s of HBM bandwidth, 197 TFLOP/s in bf16.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"hbm_gbps": 819.0, "peak_gflops": 197000.0},
+}
+
+
+def _device_peak(entry: C.ConfEntry, name: str,
+                 conf: Optional[C.RapidsConf]) -> Optional[float]:
+    override = float(_conf(conf)[entry])
+    if override > 0:
+        return override
+    import jax
+    peaks = DEVICE_PEAKS.get(jax.devices()[0].device_kind)
+    return None if peaks is None else peaks[name]
+
+
+def hbm_gbps(conf: Optional[C.RapidsConf] = None) -> Optional[float]:
     """HBM bandwidth ceiling (GB/s) for the per-kernel memory-bound
-    roofline fraction (XLA bytes-accessed / device time vs this)."""
-    return float(_conf(conf)[C.ROOFLINE_HBM_GBPS])
+    roofline fraction (XLA bytes-accessed / device time vs this); None
+    on a device with no entry in DEVICE_PEAKS and no conf override."""
+    return _device_peak(C.ROOFLINE_HBM_GBPS, "hbm_gbps", conf)
 
 
-def peak_gflops(conf: Optional[C.RapidsConf] = None) -> float:
+def peak_gflops(conf: Optional[C.RapidsConf] = None) -> Optional[float]:
     """Compute ceiling (GFLOP/s) for the per-kernel compute-bound
-    roofline fraction."""
-    return float(_conf(conf)[C.ROOFLINE_PEAK_GFLOPS])
+    roofline fraction; None like `hbm_gbps`."""
+    return _device_peak(C.ROOFLINE_PEAK_GFLOPS, "peak_gflops", conf)

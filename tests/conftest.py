@@ -78,9 +78,15 @@ RSS_CLEAR_MB = 6 << 10
 
 
 @pytest.fixture(autouse=True)
-def _bound_process_rss():
+def _bound_process_rss(request):
+    """A module that sets RELEASE_CACHES_PER_TEST (the TPC-DS workload
+    files: tens of kernels per query) drops the caches after every
+    test: its live executables otherwise reach the count at which the
+    XLA:CPU client segfaults (in `executable.serialize()`, writing the
+    persistent cache) before its RSS reaches the ceiling."""
     yield
-    if _rss_mb() > RSS_CLEAR_MB:
+    if _rss_mb() > RSS_CLEAR_MB or getattr(
+            request.module, "RELEASE_CACHES_PER_TEST", False):
         _release_caches()
 
 

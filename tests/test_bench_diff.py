@@ -2,7 +2,7 @@
 regression detection, direction awareness (rows/s up = good, wall_ms
 down = good), missing/errored-phase tolerance, both round formats
 (driver wrapper with tail + submetrics fallback, raw JSON lines),
-attribution notes, and the committed rounds staying parseable."""
+attribution notes, and round files staying parseable."""
 import json
 import os
 import subprocess
@@ -112,10 +112,18 @@ def test_wrapper_and_submetrics_formats():
     assert "join_sort_q3_rows_per_sec" in trunc["metrics"]
 
 
-@pytest.mark.parametrize("rounds", [("BENCH_r05.json", "BENCH_r07.json")])
-def test_committed_rounds_parse_and_diff(rounds):
-    a = BD.load_round(os.path.join(REPO, rounds[0]))
-    b = BD.load_round(os.path.join(REPO, rounds[1]))
+@pytest.mark.parametrize("rounds", [("round_a.json", "round_b.json")])
+def test_committed_rounds_parse_and_diff(rounds, tmp_path):
+    """Two round FILES in the driver's wrapper shape load and diff (the
+    repo commits no bench rounds of its own: the driver's ledger is the
+    record)."""
+    for name, scale in zip(rounds, (1.0, 0.9)):
+        tail = "\n".join(json.dumps({**m, "value": m["value"] * scale})
+                         for m in BASE)
+        (tmp_path / name).write_text(json.dumps(
+            {"n": 1, "rc": 0, "tail": tail}))
+    a = BD.load_round(str(tmp_path / rounds[0]))
+    b = BD.load_round(str(tmp_path / rounds[1]))
     assert a["metrics"], "old round parsed no lanes"
     rep = BD.compare_rounds(a, b)
     # report renders without error regardless of lane overlap

@@ -153,8 +153,12 @@ def test_cost_capture_and_roofline_join(q1_profiled):
         assert r["flops_per_dispatch"] >= 0
         assert r["bytes_per_dispatch"] > 0
         assert r["gbps"] > 0
-        assert 0 <= r["roofline_pct"] <= 100 * 50  # sane, not clamped
-        assert r["bound"] in ("compute", "memory")
+        # the CPU test device has no entry in roofline.DEVICE_PEAKS:
+        # no share is graded against another chip's peaks
+        assert r["roofline_pct"] is None and r["bound"] is None
+    assert RL.hbm_gbps(C.RapidsConf()) is None
+    assert RL.DEVICE_PEAKS["TPU v5 lite"] == {
+        "hbm_gbps": 819.0, "peak_gflops": 197000.0}
     assert any(c["cost"] for c in cat)
     fams = {c["family"] for c in cat}
     assert any("/" in f for f in fams), fams
@@ -190,7 +194,9 @@ def test_explain_inline_annotations(q1_profiled):
     member_annotated = [l for l in annotated if l.lstrip().
                         startswith("* ")]
     assert member_annotated, "fused member lines not annotated"
-    assert any("roofline" in l for l in annotated)
+    # ... when the device has nominal peaks; the CPU test device has
+    # none, so no line claims a share
+    assert not any("roofline" in l for l in annotated)
     # the report contract other lanes assert: every line ends with ]
     assert all(l.rstrip().endswith("]") for l in lines)
 

@@ -66,6 +66,24 @@ def test_exchange_exec_mesh_vs_local_lane(mesh8, rng):
         compare_frames(lp, mp, f"part{p}")
 
 
+def test_mesh_lane_shards_on_every_device_then_one_device_out(mesh8):
+    """The exchange's output holds a shard on every mesh device, and the
+    partitions it hands downstream are single-device arrays: on real
+    chips a Mosaic kernel fed an array still spread over the mesh
+    cannot be partitioned (four v5e chips, PR 25)."""
+    ShuffleExchangeExec._MESH_SHARD_DEVICES = []
+    with active_mesh(mesh8):
+        meshed = ShuffleExchangeExec(
+            HashPartitioning([col("k")], 8), _source(
+                np.random.default_rng(42)))
+        batches = [b for it in meshed.execute_partitions() for b in it]
+    assert ShuffleExchangeExec._MESH_SHARD_DEVICES == [list(range(8))]
+    assert batches
+    for b in batches:
+        for c in b.columns:
+            assert len(c.data.devices()) == 1, c.data.sharding
+
+
 def test_mesh_lane_declines_without_mesh(rng):
     ex = ShuffleExchangeExec(HashPartitioning([col("k")], 8),
                              _source(rng))

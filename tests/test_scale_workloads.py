@@ -29,7 +29,7 @@ SCALE_CONF = {
 def _run_pair(build_plan, t):
     import sys
     sys.path.insert(0, "tests")
-    from test_workloads import run_cpu, run_tpu
+    from workload_helpers import run_cpu, run_tpu
     expected = run_cpu(build_plan, t)
     assert len(expected) > 0
     got = run_tpu(build_plan, t, conf=C.RapidsConf(dict(SCALE_CONF)))

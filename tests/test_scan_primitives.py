@@ -1,8 +1,7 @@
 """Regression coverage for the round-5 kernel primitives: the
 hand-rolled segmented scan, top_k-based masked positions, and the
-payload-sort partition reorder (VERDICT r4 #2/#3 follow-up — these
-replaced lax.associative_scan, jnp.nonzero, and gather-based reorder,
-whose XLA:TPU lowerings were the measured bottlenecks)."""
+pid-sort partition reorder (the first two replaced
+lax.associative_scan and jnp.nonzero)."""
 import numpy as np
 import pandas as pd
 import pytest
@@ -118,12 +117,12 @@ def test_masked_positions_full_width_path():
 
 
 def test_payload_sort_reorder_with_strings_and_nulls():
-    """The payload-sort reorder moves every column kind (i64+narrow,
-    f64, bool validity, string char matrices via the carried order)
+    """The pid-sort reorder moves every column kind (i64+narrow,
+    f64, bool validity, string char matrices via the sorted order)
     and is STABLE within a partition."""
     from spark_rapids_tpu.columnar.batch import ColumnarBatch
     from spark_rapids_tpu.shuffle.partitioning import \
-        _payload_sort_reorder
+        _pid_sort_reorder
     n = 40
     rng = np.random.default_rng(5)
     pids_np = rng.integers(0, 4, n).astype(np.int32)
@@ -137,7 +136,7 @@ def test_payload_sort_reorder_with_strings_and_nulls():
     pids = jnp.asarray(np.pad(pids_np, (0, cap - n),
                               constant_values=4)).astype(jnp.uint32)
     row_mask = jnp.arange(cap) < n
-    cols, counts = _payload_sort_reorder(pids, b.columns, row_mask, 4)
+    cols, counts = _pid_sort_reorder(pids, b.columns, row_mask, 4)
     counts = np.asarray(counts)
     np.testing.assert_array_equal(counts,
                                   np.bincount(pids_np, minlength=4))
