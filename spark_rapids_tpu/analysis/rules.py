@@ -399,10 +399,10 @@ class CompileUnderLockRule(Rule):
     every other query behind one compile."""
 
     rule_id = "compile-under-lock"
-    doc = ("no jax.jit / kernel build inside a 'with lock:' body — "
-           "compile outside the lock (KernelCache single-flight)")
+    doc = ("no jax.jit / named_jit / kernel build inside a 'with lock:' "
+           "body — compile outside the lock (KernelCache single-flight)")
 
-    _COMPILE_ATTRS = {"jit", "pallas_call", "get_or_build",
+    _COMPILE_ATTRS = {"jit", "named_jit", "pallas_call", "get_or_build",
                       "_build_watched"}
 
     def check(self, ctx: FileContext) -> list[Finding]:

@@ -302,15 +302,18 @@ OOCORE_RUN_REPLICAS = conf(
 # --- query profiles (utils/profile.py) ---------------------------------------
 PROFILE_ENABLED = conf(
     "spark.rapids.sql.profile.enabled", False,
-    "Record a per-query observability profile: a span tree (query -> "
-    "stage/exchange -> operator -> batch/compile/shuffle-fetch/retry) "
+    "Record a per-query observability profile, from accelerate() to the "
+    "answer: a span tree (query -> plan:accelerate and its source "
+    "uploads -> stage/exchange -> operator -> "
+    "batch/compile/shuffle-fetch/retry -> readback) "
     "with thread-propagated parenting, dual-emitted to "
     "jax.profiler.TraceAnnotation (xprof captures still work) and to an "
     "in-process ring buffer, plus a structured event log (retries, "
     "fetch failures, blacklists, watchdog dumps, cancellations — all "
     "carrying the query id).  On collect() the spans, events, an "
     "EXPLAIN-with-metrics plan report, and a wall-clock breakdown "
-    "(compute vs pipeline wait vs shuffle vs compile vs retry-block) "
+    "(plan vs source upload vs compute vs pipeline wait vs shuffle vs "
+    "compile vs retry-block) "
     "assemble into a QueryProfile kept in a bounded history.  Disabled "
     "(default) the batch hot loop allocates no tracer objects.")
 PROFILE_HISTORY_SIZE = conf(

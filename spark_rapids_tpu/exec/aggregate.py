@@ -28,7 +28,7 @@ from spark_rapids_tpu.columnar.vector import (ColumnVector,
                                               bucket_capacity)
 from spark_rapids_tpu.exec.base import (
     SchemaOnlyExec as _SchemaOnly, TpuExec, UnaryExecBase,
-    batch_signature, make_eval_context)
+    batch_signature, make_eval_context, named_jit)
 from spark_rapids_tpu.exprs.aggregates import (
     AggAlias, AggContext, AggregateFunction)
 from spark_rapids_tpu.exprs.base import Expression, output_name
@@ -315,7 +315,7 @@ class HashAggregateExec(UnaryExecBase):
             bound_groups = self._bound_groups
             funcs = self._funcs
 
-            @jax.jit
+            @named_jit(f"agg-{phase}")
             def kernel(columns, num_rows, mask=None):
                 ctx = self._make_ctx(columns, cap, num_rows, mask)
                 keys = [e.eval(ctx) for e in bound_groups]
@@ -575,7 +575,7 @@ class HashAggregateExec(UnaryExecBase):
             funcs = self._funcs
             n_groups_cols = len(self._group_fields)
 
-            @jax.jit
+            @named_jit("agg-eval")
             def kernel(columns, num_rows):
                 out = list(columns[:n_groups_cols])
                 off = n_groups_cols
@@ -678,7 +678,9 @@ class HashAggregateExec(UnaryExecBase):
         if self._dict_gpad is None:
             probe = self.kernels.get_or_build(
                 ("dict-probe", nk, batch_signature(batch)),
-                lambda: jax.jit(self._build_dict_probe(batch.capacity)),
+                lambda: named_jit(
+                    "agg-dict-probe",
+                    self._build_dict_probe(batch.capacity)),
                 meta=self.kp_meta("agg-dict-probe"))
             if batch.sparse is not None:
                 kmins, kmaxs = probe(batch.columns, batch.num_rows_i32,
@@ -719,15 +721,18 @@ class HashAggregateExec(UnaryExecBase):
         if nk == 1:
             fused = self.kernels.get_or_build(
                 ("dict-fused", g_pad, batch_signature(batch)),
-                lambda: jax.jit(
+                lambda: named_jit(
+                    "agg-dict-fused",
                     self._build_dict_fused(batch.capacity, g_pad)),
                 meta=self.kp_meta("agg-dict-fused",
                                   members=kp_members))
         else:
             fused = self.kernels.get_or_build(
                 ("dict-fused-multi", g_pad, batch_signature(batch)),
-                lambda: jax.jit(self._build_dict_fused_multi(
-                    batch.capacity, list(g_pad))),
+                lambda: named_jit(
+                    "agg-dict-fused-multi",
+                    self._build_dict_fused_multi(
+                        batch.capacity, list(g_pad))),
                 meta=self.kp_meta("agg-dict-fused-multi",
                                   members=kp_members))
         if batch.sparse is not None:
@@ -1395,7 +1400,7 @@ class HashAggregateExec(UnaryExecBase):
             cap = batch.capacity
             funcs = self._funcs
 
-            @jax.jit
+            @named_jit(f"agg-reduce-{phase}")
             def kernel(columns, num_rows, mask=None):
                 ctx = self._make_ctx(columns, cap, num_rows, mask)
                 seg_ids = jnp.zeros(cap, jnp.int32)

@@ -12,6 +12,7 @@ so ragged Spark batches hit a small set of bucketed compilations.
 from __future__ import annotations
 
 import dataclasses
+import re
 from typing import Callable, Iterator, Optional, Sequence
 
 import jax
@@ -23,7 +24,6 @@ from spark_rapids_tpu.columnar.vector import ColumnVector
 from spark_rapids_tpu.exprs.base import EvalContext, Expression
 from spark_rapids_tpu.utils import kernelprof as KP
 from spark_rapids_tpu.utils import metrics as M
-from spark_rapids_tpu.utils.tracing import trace_range
 
 
 # ---------------------------------------------------------------------------
@@ -50,6 +50,31 @@ def max_goal(a: Optional[CoalesceGoal], b: Optional[CoalesceGoal]
     if isinstance(a, TargetSize) and isinstance(b, TargetSize):
         return TargetSize(max(a.bytes, b.bytes))
     return a or b
+
+
+# ---------------------------------------------------------------------------
+#: the longest kernel name: a fused stage's member list is cut to it
+KERNEL_NAME_MAX = 64
+
+
+def kernel_name(label: str) -> str:
+    """A `kp_meta` label as an identifier: the one vocabulary of
+    kernel names, read on the host (utils/kernelprof) and on the device
+    (the profiler's `XLA Modules` line reads `jit_<name>(<hash>)`)."""
+    return re.sub(r"\W", "_", label)[:KERNEL_NAME_MAX]
+
+
+def named_jit(label: str, fn: Optional[Callable] = None, **jit_kwargs):
+    """`jax.jit` under the kernel's own name instead of `kernel` /
+    `<lambda>`, so a device trace tells the operators' programs apart.
+    `@named_jit("filter")` decorates; `named_jit("topn-k", fn)` wraps.
+    The name is part of the module the persistent compile cache
+    hashes: renaming a kernel compiles it once more.  `__qualname__`
+    stays: it says where the kernel is defined."""
+    def wrap(f):
+        f.__name__ = kernel_name(label)
+        return jax.jit(f, **jit_kwargs)
+    return wrap if fn is None else wrap(fn)
 
 
 # ---------------------------------------------------------------------------
@@ -466,24 +491,18 @@ class TpuExec:
                 if attempt:
                     CK.set_retrying(final)
                 try:
-                    out = self._collect_once().dense()
-                    out.prefetch()
-                    # ONE verify over batch checks + the query's
-                    # registered checks = one stacked flag readback (a
-                    # second verify call would pay its own round trip).
-                    # Under the async pipeline layer the batch's lazy
-                    # row count rides the SAME readback (host-sync
-                    # diet: the to_pandas conversion right after this
-                    # otherwise pays its own round trip for the count).
-                    checks = list(out.checks) + CK.drain_since(mark)
-                    from spark_rapids_tpu import config as C
-                    if (not out.num_rows_known
-                            and C.get_active_conf()[C.PIPELINE_ENABLED]):
-                        (rows,) = CK.verify(checks,
-                                            scalars=[out.num_rows_i32])
-                        out.num_rows = int(rows)
-                    else:
-                        CK.verify(checks)
+                    out = self._collect_once()
+                    # everything is dispatched; from here the host
+                    # waits for the device and reads back: the first
+                    # half of the query's `exec:Readback`
+                    with P.span(P.SPAN_READBACK) as sp:
+                        out = self._drain(out, mark)
+                        if sp is not None:
+                            sp.args = {
+                                "phase": "drain",
+                                "rows": out.num_rows
+                                if out.num_rows_known else None,
+                                "bytes": out.device_size_bytes()}
                     return out
                 except CK.FastPathInvalid as e:
                     if final:
@@ -525,6 +544,29 @@ class TpuExec:
                 P.end_query(prof_owner, self, error=prof_error)
             # plan lock / admission slot / thread-local context release
             scope.close()
+
+    @staticmethod
+    def _drain(out: ColumnarBatch, mark) -> ColumnarBatch:
+        """The sync boundary of a collect: densify, start the copies to
+        the host, resolve the deferred checks."""
+        from spark_rapids_tpu import config as C
+        from spark_rapids_tpu.utils import checks as CK
+        out = out.dense()
+        out.prefetch()
+        # ONE verify over batch checks + the query's registered checks
+        # = one stacked flag readback (a second verify call would pay
+        # its own round trip).  Under the async pipeline layer the
+        # batch's lazy row count rides the SAME readback (host-sync
+        # diet: the to_pandas conversion right after this otherwise
+        # pays its own round trip for the count).
+        checks = list(out.checks) + CK.drain_since(mark)
+        if (not out.num_rows_known
+                and C.get_active_conf()[C.PIPELINE_ENABLED]):
+            (rows,) = CK.verify(checks, scalars=[out.num_rows_i32])
+            out.num_rows = int(rows)
+        else:
+            CK.verify(checks)
+        return out
 
     def _collect_once(self) -> ColumnarBatch:
         from spark_rapids_tpu.columnar.batch import concat_batches, empty_batch

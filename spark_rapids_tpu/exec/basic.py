@@ -14,7 +14,6 @@ from __future__ import annotations
 import dataclasses
 from typing import Iterator, Optional, Sequence
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -23,7 +22,7 @@ from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.columnar.vector import ColumnVector, bucket_capacity
 from spark_rapids_tpu.exec.base import (
     LeafExec, TpuExec, UnaryExecBase, batch_signature,
-    bind_exprs, make_eval_context)
+    bind_exprs, make_eval_context, named_jit)
 from spark_rapids_tpu.exprs.base import Expression, output_name
 from spark_rapids_tpu.utils import metrics as M
 
@@ -75,7 +74,7 @@ class ProjectExec(UnaryExecBase):
 
             labels: list = []
 
-            @jax.jit
+            @named_jit("project")
             def kernel(columns, num_rows, mask=None):
                 ctx = make_eval_context(columns, cap, num_rows, mask)
                 out = [e.eval(ctx) for e in bound]
@@ -143,7 +142,7 @@ class FilterExec(UnaryExecBase):
 
             labels: list = []
 
-            @jax.jit
+            @named_jit("filter")
             def kernel(columns, num_rows, mask=None):
                 ctx = make_eval_context(columns, cap, num_rows, mask)
                 pred = bound.eval(ctx)

@@ -15,14 +15,14 @@ from __future__ import annotations
 
 from typing import Iterator, Sequence
 
-import jax
 import jax.numpy as jnp
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.columnar.vector import ColumnVector
 from spark_rapids_tpu.exec.base import (
-    TpuExec, UnaryExecBase, batch_signature, make_eval_context)
+    TpuExec, UnaryExecBase, batch_signature, make_eval_context,
+    named_jit)
 from spark_rapids_tpu.exprs.base import Expression, output_name
 from spark_rapids_tpu.utils import metrics as M
 
@@ -66,7 +66,7 @@ class ExpandExec(UnaryExecBase):
             nproj = len(self._bound)
             out_cap = cap * nproj
 
-            @jax.jit
+            @named_jit("expand")
             def kernel(columns, num_rows):
                 ctx = make_eval_context(columns, cap, num_rows)
                 # evaluate every projection, then interleave rows:

@@ -35,7 +35,7 @@ from spark_rapids_tpu.columnar.batch import ColumnarBatch, concat_batches
 from spark_rapids_tpu.columnar.vector import ColumnVector, bucket_capacity
 from spark_rapids_tpu.exec.base import (
     KernelCache, RequireSingleBatch, TpuExec, batch_signature,
-    make_eval_context)
+    make_eval_context, named_jit)
 from spark_rapids_tpu.exprs.base import Expression
 from spark_rapids_tpu.ops.sort_encode import (
     encode_key_bits, packed_lexsort, segment_boundaries)
@@ -137,7 +137,7 @@ class HashJoinExec(TpuExec):
             cap = bcap + pcap
             build_keys, probe_keys = self._build_keys, self._probe_keys
 
-            @jax.jit
+            @named_jit("join-match")
             def kernel(bcols, bnum, pcols, pnum):
                 bctx = make_eval_context(bcols, bcap, bnum)
                 pctx = make_eval_context(pcols, pcap, pnum)
@@ -236,7 +236,7 @@ class HashJoinExec(TpuExec):
             bcap, pcap = build.capacity, probe.capacity
             cap = bcap + pcap
 
-            @jax.jit
+            @named_jit("join-expand")
             def kernel(bcols, pcols, counts_p, start_p, perm, pnum):
                 eff = counts_p
                 if outer_probe:
@@ -276,7 +276,7 @@ class HashJoinExec(TpuExec):
         def build_fn():
             pcap = probe.capacity
 
-            @jax.jit
+            @named_jit("join-semi")
             def kernel(pcols, counts_p, pnum):
                 probe_valid = jnp.arange(pcap) < pnum
                 keep = probe_valid & ((counts_p == 0) if anti
@@ -317,7 +317,9 @@ class HashJoinExec(TpuExec):
             return cached[0]
         probe = self._join_cache.get_or_build(
             ("dense-probe", batch_signature(build)),
-            lambda: jax.jit(self._build_dense_probe(build.capacity)),
+            lambda: named_jit(
+                "join-dense-probe",
+                self._build_dense_probe(build.capacity)),
             meta=self.kp_meta("join-dense-probe"))
         kmin, kmax = probe(build.columns, build.num_rows_i32)
         kmin, kmax = int(kmin), int(kmax)
@@ -327,8 +329,9 @@ class HashJoinExec(TpuExec):
             g = int(bucket_capacity(max(span, 1)))
             tab_kern = self._join_cache.get_or_build(
                 ("dense-table2", g, batch_signature(build)),
-                lambda: jax.jit(self._build_dense_table_kernel(
-                    build.capacity, g)),
+                lambda: named_jit(
+                    "join-dense-table",
+                    self._build_dense_table_kernel(build.capacity, g)),
                 meta=self.kp_meta("join-dense-table"))
             bidx1_tab, vmask_tab, max_cnt = tab_kern(
                 build.columns, build.num_rows_i32, jnp.int64(kmin))
@@ -426,7 +429,7 @@ class HashJoinExec(TpuExec):
             probe_key = self._probe_keys[0]
             remat_ord = self._dense_key_remat_ordinal()
 
-            @jax.jit
+            @named_jit("join-dense")
             def kernel(pcols, pnum, bcols, bidx1_tab, vmask_tab, kmin,
                        pmask=None):
                 ctx = make_eval_context(pcols, pcap, pnum, pmask)
@@ -876,7 +879,7 @@ class NestedLoopJoinExec(TpuExec):
             lcap, rcap = lb.capacity, rb.capacity
             out_cap = lcap * rcap
 
-            @jax.jit
+            @named_jit("join-nlj")
             def kernel(lcols, lnum, rcols, rnum):
                 k = jnp.arange(out_cap)
                 li = k // rcap

@@ -412,14 +412,15 @@ class QueryScope:
     """Query ownership for a driver-side entry point: if the calling
     thread has no live QueryContext, creates one, begins its profile
     (BEFORE admission, so queue wait is a first-class span/category in
-    the query's own breakdown), and admits it; otherwise a no-op that
+    the query's own breakdown; a plan phase that accelerate() parked
+    on `plan` is resumed there), and admits it; otherwise a no-op that
     defers to the enclosing scope.  `plan/overrides.collect` holds one
     around the whole drive (deopt retries, the AQE stage loop, partial
     CPU plans) and `TpuExec.collect` holds one per direct collect."""
 
     __slots__ = ("qc", "owns", "prof_owner", "_prev_tls")
 
-    def __init__(self, conf: Optional[C.RapidsConf] = None):
+    def __init__(self, conf: Optional[C.RapidsConf] = None, plan=None):
         from spark_rapids_tpu.utils import profile as P
         self.qc = current()
         self.owns = self.qc is None
@@ -443,7 +444,7 @@ class QueryScope:
         from spark_rapids_tpu.utils import kernelprof as KP
         KP.maybe_enable(conf)
         try:
-            self.prof_owner = P.begin_query(conf)
+            self.prof_owner = P.begin_query(conf, plan)
             QueryScheduler.get().admit(self.qc, conf)
         except BaseException as e:
             self.close(error=e)
@@ -480,7 +481,7 @@ class CollectScope:
 
     def __init__(self, plan):
         self.plan = plan
-        self._qscope = QueryScope()
+        self._qscope = QueryScope(plan=plan)
         self.qc = self._qscope.qc
         self.owns_qc = self._qscope.owns
         self.prof_owner = self._qscope.prof_owner

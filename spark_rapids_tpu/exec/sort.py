@@ -17,7 +17,7 @@ from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.exec.base import (
     CoalesceGoal, RequireSingleBatch, TpuExec, UnaryExecBase,
-    batch_signature, make_eval_context)
+    batch_signature, make_eval_context, named_jit)
 from spark_rapids_tpu.exprs.base import Expression
 from spark_rapids_tpu.ops.sort_encode import multi_key_argsort
 from spark_rapids_tpu.utils import metrics as M
@@ -75,6 +75,7 @@ class SortExec(UnaryExecBase):
 
     def _kernel(self, batch: ColumnarBatch, head: Optional[int] = None):
         key = ("sort", head, batch_signature(batch))
+        label = "sort" if head is None else "sort-head"
 
         def build():
             bound = self._bound
@@ -86,7 +87,7 @@ class SortExec(UnaryExecBase):
                 from spark_rapids_tpu.columnar.vector import bucket_capacity
                 out_cap = bucket_capacity(head)
 
-            @jax.jit
+            @named_jit(label)
             def kernel(columns, num_rows, mask=None):
                 ctx = make_eval_context(columns, cap, num_rows, mask)
                 keys = [e.eval(ctx) for e in bound]
@@ -109,9 +110,7 @@ class SortExec(UnaryExecBase):
             return kernel
 
         return self.kernels.get_or_build(
-            key, build,
-            meta=self.kp_meta("sort" if head is None
-                              else f"sort-head{head}"))
+            key, build, meta=self.kp_meta(label))
 
     def output_partition_count(self) -> int:
         if not self.global_sort:
@@ -366,7 +365,7 @@ class SortedTopNExec(UnaryExecBase):
             return self._sort_one(batch).take_head(self.n)
         kern = self.kernels.get_or_build(
             ("topn-k", self.n, batch_signature(batch)),
-            lambda: jax.jit(self._build_topk(batch.capacity)),
+            lambda: named_jit("topn-k", self._build_topk(batch.capacity)),
             meta=self.kp_meta("topn-k"))
         if batch.sparse is not None:
             cols, count = kern(batch.columns, batch.num_rows_i32,

@@ -72,7 +72,8 @@ from spark_rapids_tpu import config as C
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.columnar.vector import ColumnVector, _pad_chars
-from spark_rapids_tpu.exec.base import make_eval_context, mesh_cache_scope
+from spark_rapids_tpu.exec.base import (
+    make_eval_context, mesh_cache_scope, named_jit)
 from spark_rapids_tpu.utils import metrics as M
 
 log = logging.getLogger("spark_rapids_tpu.exec.spmd")
@@ -293,8 +294,8 @@ def _gang_kernel(exec_, mesh, axis: str, cap: int, n_slots: int,
             total = rows.sum().astype(jnp.int32)
             return out_cols, keep, counts, pend, total
 
-        kernel = jax.jit(
-            gang,
+        kernel = named_jit(
+            "spmd-gang", gang,
             in_shardings=(data_shard, data_shard, data_shard),
             out_shardings=(data_shard, data_shard, data_shard, repl,
                            repl))

@@ -26,7 +26,6 @@ import dataclasses
 import math
 from typing import Iterator, Optional, Sequence
 
-import jax
 import jax.numpy as jnp
 
 from spark_rapids_tpu import types as T
@@ -34,7 +33,7 @@ from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.columnar.vector import ColumnVector
 from spark_rapids_tpu.exec.base import (
     CoalesceGoal, RequireSingleBatch, TpuExec, UnaryExecBase,
-    batch_signature, make_eval_context)
+    batch_signature, make_eval_context, named_jit)
 from spark_rapids_tpu.exec.sort import SortOrder
 from spark_rapids_tpu.exprs.base import Expression, output_name
 from spark_rapids_tpu.ops.sort_encode import (
@@ -215,7 +214,7 @@ class WindowExec(UnaryExecBase):
             cap = batch.capacity
             frame = self.spec.frame
 
-            @jax.jit
+            @named_jit("window")
             def kernel(columns, num_rows):
                 ctx = make_eval_context(columns, cap, num_rows)
                 parts = [e.eval(ctx) for e in self._bound_parts]

@@ -31,6 +31,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from jax import shard_map
 
 from spark_rapids_tpu import types as T
+from spark_rapids_tpu.exec.base import named_jit
 from spark_rapids_tpu.ops.murmur3 import partition_ids as murmur3_pids
 
 
@@ -211,7 +212,7 @@ def build_all_to_all_exchange(mesh: Mesh, axis: str,
         out_specs=([tuple(P(axis) if i < 2 or f.dtype.is_string else None
                           for i in range(3))
                     for f in schema.fields], P(axis)))
-    return jax.jit(smapped)
+    return named_jit("mesh-exchange", smapped)
 
 
 def build_count_exchange(mesh: Mesh, axis: str, schema: T.Schema,
@@ -241,7 +242,7 @@ def build_count_exchange(mesh: Mesh, axis: str, schema: T.Schema,
                          for i in range(3))
                    for f in schema.fields], P(axis)),
         out_specs=P(axis))
-    return jax.jit(smapped)
+    return named_jit("mesh-count", smapped)
 
 
 def stack_batches(batches, capacity: int):

@@ -71,11 +71,11 @@ below.
 """
 from __future__ import annotations
 
+import functools
 import logging
 import time
 from typing import Iterator, Optional
 
-import jax
 import jax.numpy as jnp
 
 from spark_rapids_tpu import config as C
@@ -83,7 +83,8 @@ from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.exec.aggregate import AggMode, HashAggregateExec
 from spark_rapids_tpu.exec.base import (
-    TpuExec, UnaryExecBase, batch_signature, make_eval_context)
+    TpuExec, UnaryExecBase, batch_signature, make_eval_context,
+    named_jit)
 from spark_rapids_tpu.exec.basic import FilterExec, ProjectExec, \
     _register_ansi
 from spark_rapids_tpu.exprs.base import (
@@ -142,6 +143,14 @@ class ComposedStage:
 
     def member_names(self) -> list:
         return [type(m).__name__ for m in self.members]
+
+    @functools.cached_property
+    def kernel_label(self) -> str:
+        """`fused-<n>-<members>`: the stage's kernel says it is a fused
+        one and of what, and never carries a hash or a shape.  Built
+        once a stage: `_kernel` runs per batch."""
+        ops = [n.replace("Exec", "").lower() for n in self.member_names()]
+        return "-".join(["fused", str(len(ops))] + ops)
 
     def fingerprint(self) -> tuple:
         return (fingerprint(self.out_exprs), fingerprint(self.preds),
@@ -267,6 +276,7 @@ class FusedStageExec(UnaryExecBase):
     # -- fused lane ----------------------------------------------------------
     def _kernel(self, batch: ColumnarBatch):
         key = ("fused-stage", batch_signature(batch))
+        label = self.stage.kernel_label
 
         def build():
             stage = self.stage
@@ -274,7 +284,7 @@ class FusedStageExec(UnaryExecBase):
             has_filter = bool(stage.preds)
             labels: list = []
 
-            @jax.jit
+            @named_jit(label)
             def kernel(columns, num_rows, mask=None):
                 ctx = make_eval_context(columns, cap, num_rows, mask)
                 cols, keep, counts = _eval_stage(stage, ctx)
@@ -294,7 +304,7 @@ class FusedStageExec(UnaryExecBase):
         # the kernel table points back at the fused plan nodes
         return self.kernels.get_or_build(
             key, build,
-            meta=self.kp_meta("fused-stage",
+            meta=self.kp_meta(label,
                               members=self.stage.member_names()))
 
     def _run_one(self, batch: ColumnarBatch) -> ColumnarBatch:

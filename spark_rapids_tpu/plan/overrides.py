@@ -289,7 +289,10 @@ def _tag_aggregate(meta) -> None:
 # exec converters
 def _conv_source(meta, kids) -> TpuExec:
     node: N.CpuSource = meta.node
-    from spark_rapids_tpu.plan.transitions import batch_from_df
+    from spark_rapids_tpu.columnar.batch import ColumnarBatch
+    from spark_rapids_tpu.plan.transitions import host_columns_from_df
+    from spark_rapids_tpu.utils import movement as MV
+    from spark_rapids_tpu.utils import profile as P
     # chunk at the upload boundary like every other source (transitions
     # RowToColumnarExec / HostColumnarToDeviceExec): device batch
     # capacities stay in batchMaxRows' bounded bucket set.  Whole
@@ -298,9 +301,38 @@ def _conv_source(meta, kids) -> TpuExec:
     # reduce kernel: past the 300 s task watchdog on the v5e's host).
     max_rows = meta.conf[C.MAX_BATCH_ROWS]
     schema = node.output_schema()
-    parts = [[batch_from_df(df.iloc[lo:lo + max_rows], schema)
-              for lo in range(0, len(df), max_rows)]
-             for df in node.partitions]
+    # the upload is eager and most of a hot scan query's wall: one span
+    # per source around all of it and, per partition, one around the
+    # host conversion of its chunks and one around their device_puts
+    # (the operator ranges of the reference's HostColumnarToGpu).  Per
+    # partition, not per chunk: a trace reader that explains every idle
+    # gap of the device by the spans around it pays for each span
+    tr = P.tracer()
+    label = "" if tr is None else \
+        f"{P.SPAN_SOURCE_UPLOAD}[s{tr.ordinal(P.SPAN_SOURCE_UPLOAD)}]"
+    parts, nbytes = [], 0
+    with P.span(label) as upload:
+        for p, df in enumerate(node.partitions):
+            los = range(0, len(df), max_rows)
+            with P.span(P.SPAN_UPLOAD_CONVERT, partition=p,
+                        chunks=len(los), rows=len(df)):
+                host = [host_columns_from_df(df.iloc[lo:lo + max_rows],
+                                             schema) for lo in los]
+            with P.span(P.SPAN_UPLOAD_PUT, partition=p,
+                        chunks=len(los), rows=len(df)) as put:
+                chunks = [ColumnarBatch.from_numpy(data, schema, validity)
+                          for data, validity in host]
+                if put is not None:
+                    put.args["device_bytes"] = b = sum(
+                        MV.vector_device_bytes(col)
+                        for batch in chunks for col in batch.columns)
+                    nbytes += b
+            parts.append(chunks)
+        if upload is not None:
+            upload.args = {"partitions": len(parts),
+                           "batches": sum(map(len, parts)),
+                           "rows": sum(map(len, node.partitions)),
+                           "device_bytes": nbytes}
     src = B.LocalBatchSource(parts, node.output_schema())
     # stable identity across plan rebuilds: the uploaded device batches
     # are fresh per accelerate(), but the backing pandas partitions are
@@ -911,10 +943,58 @@ def accelerate(cpu_plan: N.CpuNode,
                conf: Optional[C.RapidsConf] = None):
     """The full rewrite: returns a TpuExec (fully accelerated), or a
     CpuNode tree with accelerated islands (partial), or the original plan
-    (sql disabled)."""
+    (sql disabled).
+
+    A profiled query starts here: the rewrite and the eager source
+    upload are recorded as `plan:accelerate` and its children on a
+    tracer of this call's own, which is left on the returned plan,
+    inert, for the `collect()` that runs it (utils/profile.begin_plan /
+    park_plan)."""
     conf = conf or C.get_active_conf()
     if not conf[C.SQL_ENABLED]:
         return cpu_plan
+    from spark_rapids_tpu.utils import profile as P
+    owner = P.begin_plan(conf)
+    plan = None
+    try:
+        with P.span(P.SPAN_ACCELERATE, cat=P.CAT_PLAN) as sp:
+            plan = _accelerate(cpu_plan, conf)
+            if sp is not None:
+                tpu_nodes, cpu_islands = _plan_census(plan)
+                sp.args = {"nodes_in": _count_nodes(cpu_plan),
+                           "tpu_nodes_out": tpu_nodes,
+                           "cpu_islands": cpu_islands}
+        return plan
+    finally:
+        P.park_plan(owner, plan)
+
+
+def _count_nodes(cpu_plan: N.CpuNode) -> int:
+    return 1 + sum(_count_nodes(c) for c in cpu_plan.children)
+
+
+def _plan_census(plan) -> tuple[int, int]:
+    """(TPU nodes, CPU islands) of an accelerated plan; an island is a
+    CPU subtree at the root or under a RowToColumnarExec."""
+    from spark_rapids_tpu.plan.transitions import (
+        ColumnarToRowExec, RowToColumnarExec)
+    tpu = islands = 0
+    todo = [(plan, False)]
+    while todo:
+        node, cpu_above = todo.pop()
+        if isinstance(node, TpuExec):
+            tpu += 1
+            kids = [node.cpu_child] if isinstance(node, RowToColumnarExec) \
+                else node.children
+        else:
+            islands += not cpu_above
+            kids = [node.tpu_child] if isinstance(node, ColumnarToRowExec) \
+                else node.children
+        todo += [(k, not isinstance(node, TpuExec)) for k in kids]
+    return tpu, islands
+
+
+def _accelerate(cpu_plan: N.CpuNode, conf: C.RapidsConf):
     if conf[C.UDF_COMPILER_ENABLED]:
         from spark_rapids_tpu.udf import rewrite_udfs
         cpu_plan = rewrite_udfs(cpu_plan)
@@ -1000,7 +1080,7 @@ def _collect(plan, conf: C.RapidsConf) -> "object":
     path is disabled and the pure plan re-executes once."""
     from spark_rapids_tpu.exec import scheduler as S
     from spark_rapids_tpu.utils import checks as CK
-    scope = S.QueryScope(conf)
+    scope = S.QueryScope(conf, plan)
     error: Optional[BaseException] = None
     try:
         mark = CK.snapshot()
@@ -1021,9 +1101,23 @@ def _collect(plan, conf: C.RapidsConf) -> "object":
         scope.close(error=error)
 
 
+def _collect_to_host(plan: TpuExec) -> "object":
+    """`plan.collect()` as host rows.  The conversion is the second half
+    of the query's `exec:Readback`; the first, the wait for the device
+    and the stacked flag read, is `TpuExec.collect`'s."""
+    from spark_rapids_tpu.plan.transitions import df_from_batch
+    from spark_rapids_tpu.utils import profile as P
+    batch = plan.collect()
+    with P.span(P.SPAN_READBACK) as sp:
+        df = df_from_batch(batch)
+        if sp is not None:
+            sp.args = {"phase": "convert", "rows": len(df),
+                       "bytes": batch.device_size_bytes()}
+    return df
+
+
 def _collect_inner(plan, conf: C.RapidsConf) -> "object":
     if isinstance(plan, TpuExec):
-        from spark_rapids_tpu.plan.transitions import df_from_batch
         if conf[C.ADAPTIVE_ENABLED]:
             from spark_rapids_tpu.plan.aqe import (adaptive_execute,
                                                    release_stage_buffers)
@@ -1038,7 +1132,7 @@ def _collect_inner(plan, conf: C.RapidsConf) -> "object":
                 plan = adaptive_execute(plan, conf)
                 ExecutionPlanCapture.last_plan = plan
                 try:
-                    return df_from_batch(plan.collect())
+                    return _collect_to_host(plan)
                 finally:
                     # the captured plan must not pin the query's entire
                     # shuffle output in device memory
@@ -1048,5 +1142,5 @@ def _collect_inner(plan, conf: C.RapidsConf) -> "object":
                 raise
             finally:
                 P.end_query(prof_owner, plan, error=prof_error)
-        return df_from_batch(plan.collect())
+        return _collect_to_host(plan)
     return plan.collect()

@@ -28,10 +28,10 @@ from spark_rapids_tpu.plan.nodes import CpuNode, normalize_df
 from spark_rapids_tpu.utils import metrics as M
 
 
-def batch_from_df(df: pd.DataFrame, schema: T.Schema) -> ColumnarBatch:
-    """Host rows -> device batch honoring the schema's storage model
-    (GpuRowToColumnarExec converter analog, but columnar-at-once: pandas
-    already stores columns contiguously, so we upload per column)."""
+def host_columns_from_df(df: pd.DataFrame, schema: T.Schema
+                         ) -> tuple[dict, dict]:
+    """The host half of `batch_from_df`: pandas columns -> numpy storage
+    arrays and validity masks, nothing on the device yet."""
     data, validity = {}, {}
     for f in schema.fields:
         s = df[f.name]
@@ -56,6 +56,14 @@ def batch_from_df(df: pd.DataFrame, schema: T.Schema) -> ColumnarBatch:
                     vals = np.where(mask, 0, vals)
             data[f.name] = vals
         validity[f.name] = ~mask
+    return data, validity
+
+
+def batch_from_df(df: pd.DataFrame, schema: T.Schema) -> ColumnarBatch:
+    """Host rows -> device batch honoring the schema's storage model
+    (GpuRowToColumnarExec converter analog, but columnar-at-once: pandas
+    already stores columns contiguously, so we upload per column)."""
+    data, validity = host_columns_from_df(df, schema)
     return ColumnarBatch.from_numpy(data, schema, validity)
 
 

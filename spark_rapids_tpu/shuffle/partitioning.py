@@ -12,7 +12,6 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Sequence
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -20,7 +19,7 @@ from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.columnar.vector import bucket_capacity
 from spark_rapids_tpu.exec.base import KernelCache, batch_signature, \
-    columns_signature, make_eval_context
+    columns_signature, make_eval_context, named_jit
 from spark_rapids_tpu.exprs.base import Expression
 from spark_rapids_tpu.ops.murmur3 import partition_ids
 from spark_rapids_tpu.ops.sort_encode import multi_key_argsort
@@ -48,7 +47,7 @@ def _split_kernel_for(cache: KernelCache, batch: ColumnarBatch,
     def build():
         cap = batch.capacity
 
-        @jax.jit
+        @named_jit("exchange-split")
         def kernel(columns, num_rows, salt, extra, mask=None):
             ctx = make_eval_context(columns, cap, num_rows, mask)
             pids = pid_fn(ctx, salt, extra)
@@ -127,7 +126,7 @@ def _cut_kernel_for(schema: T.Schema, cols, total_cap: int, n_parts: int):
         from spark_rapids_tpu.columnar.vector import pack_validity_bits
         base = jnp.arange(total_cap)
 
-        @jax.jit
+        @named_jit("exchange-cut")
         def kernel(columns, counts):
             offs = jnp.cumsum(counts) - counts
             packed_bits = pack_validity_bits(columns)

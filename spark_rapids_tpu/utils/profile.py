@@ -10,10 +10,15 @@ timeline attribution plus data-movement accounting.
 
 Three pieces:
 
-* **QueryTracer** — one per profiled query (installed by the outermost
-  collect when `spark.rapids.sql.profile.enabled`).  Records a span
-  tree — query -> stage/exchange -> operator -> batch-loop / compile /
-  shuffle-fetch / retry — into a bounded ring buffer, dual-emitting
+* **QueryTracer** — one per profiled query, from `accelerate()` to the
+  answer (`spark.rapids.sql.profile.enabled`): `accelerate()` records
+  its plan phase (rewrite, source upload) into the query's tracer and
+  parks it on the plan it returns, inert (`begin_plan` / `park_plan`);
+  the outermost collect of that plan resumes it (`begin_query`), and a
+  plan never accelerated, or collected again, gets a fresh one.
+  Records a span tree — query -> plan / stage/exchange -> operator ->
+  batch-loop / compile / shuffle-fetch / retry / readback — into a
+  bounded ring buffer, dual-emitting
   each span to `jax.profiler.TraceAnnotation` so xprof/Perfetto device
   captures still line up.  Parenting is THREAD-PROPAGATED: the opening
   thread's innermost live span is the parent, and helper threads
@@ -26,10 +31,10 @@ Three pieces:
 * **QueryProfile** — assembled when the query's collect finishes: the
   plan `tree_string` annotated per-node with resolved MetricSet values
   (EXPLAIN-with-metrics, the Spark UI plan-graph analog), a wall-clock
-  breakdown (compute vs pipeline wait vs shuffle vs compile vs
-  retry-block), the top-N slowest spans, the span list (Chrome
-  trace-event JSON export, loadable in Perfetto), and the event
-  records.  A bounded history of the last
+  breakdown (plan vs source upload vs compute vs pipeline wait vs
+  shuffle vs compile vs retry-block), the top-N slowest spans, the
+  span list (Chrome trace-event JSON export, loadable in Perfetto),
+  and the event records.  A bounded history of the last
   `spark.rapids.sql.profile.historySize` profiles is queryable from
   tests and bench harnesses.
 
@@ -60,6 +65,15 @@ CAT_COMPILE = "compile"
 CAT_RETRY = "retry"        # OOM retry harness blocked (spill/reserve)
 CAT_UDF = "udf"
 CAT_QUEUE = "queue"        # parked in the scheduler's admission queue
+CAT_PLAN = "plan"          # accelerate(): rewrite, tagging, fusion
+
+#: span names a trace reader sums by (the annotation text is
+#: `<cat>:<name>`): constant per site, indices go in the span's args
+SPAN_ACCELERATE = "accelerate"            # plan:accelerate
+SPAN_SOURCE_UPLOAD = "SourceUpload"       # exec:SourceUpload[s<k>]
+SPAN_UPLOAD_CONVERT = "upload-convert"    # per partition: pandas -> numpy
+SPAN_UPLOAD_PUT = "upload-put"            # per partition: pad + device_put
+SPAN_READBACK = "Readback"                # the device-to-host half
 
 #: ring-buffer bounds — big enough for a deep TPC-DS plan's batch spans,
 #: small enough that a runaway loop cannot eat the heap
@@ -172,14 +186,12 @@ def tracer() -> Optional["QueryTracer"]:
     loops to gate on."""
     if _ACTIVE == 0:
         return None
-    try:
-        from spark_rapids_tpu.exec import scheduler as S
-        qc = S.current()
-    except ImportError:
-        qc = None
+    qc = _current_qc()
     if qc is not None:
         return qc.tracer   # None for an unprofiled query: isolation
-    return _TRACER
+    # a thread inside accelerate() has no QueryContext yet: its plan
+    # phase's tracer is its own, whatever other queries are running
+    return getattr(_TLS, "plan", None) or _TRACER
 
 
 def _tls_ctx(tr: "QueryTracer") -> Optional[Span]:
@@ -196,7 +208,15 @@ class QueryTracer:
                  query_id: Optional[str] = None):
         self.query_id = query_id or f"q{next(_QUERY_IDS):06d}"
         self.conf = conf
+        #: not live: the query finished, or accelerate() parked the
+        #: tracer on its plan and no collect() has resumed it yet
         self.ended = False
+        #: when accelerate() parked it, and the nanoseconds it has lain
+        #: parked: the caller's time between the two calls, which the
+        #: root span covers and the breakdown takes out of compute
+        self.parked_at = 0
+        self.held_ns = 0
+        self._ordinals: dict[str, int] = {}
         self.t_origin = time.perf_counter_ns()
         self.wall_start = time.time()
         self._ids = iter(range(1, 1 << 62))
@@ -275,6 +295,28 @@ class QueryTracer:
 
     def spans(self) -> list[Span]:
         return list(self._spans)
+
+    def ordinal(self, family: str) -> int:
+        """0, 1, 2, ... per span family, for names that are constant
+        per site and number their instances (`SourceUpload[s<k>]`)."""
+        n = self._ordinals.get(family, 0)
+        self._ordinals[family] = n + 1
+        return n
+
+    def resume(self, conf: C.RapidsConf, query_id: Optional[str]) -> None:
+        """collect() takes the parked plan phase up as its query's
+        tracer: one origin, one root, one set of ledgers, under the
+        query's id (the plan phase ran before the id was minted)."""
+        self.held_ns += time.perf_counter_ns() - self.parked_at
+        self.conf = conf
+        self.ended = False
+        if query_id is not None and query_id != self.query_id:
+            self.query_id = query_id
+            for led in (self.ledger, self.kernels, self.residency):
+                if led is not None:
+                    led.query_id = query_id
+            for rec in self._events:
+                rec["query_id"] = query_id
 
     def events(self) -> list[dict]:
         return list(self._events)
@@ -422,10 +464,75 @@ def _op_spans(name: str, idx: int, it: Iterator) -> Iterator:
 
 
 # ---------------------------------------------------------------------------
-def begin_query(conf: Optional[C.RapidsConf] = None
+def _current_qc():
+    try:
+        from spark_rapids_tpu.exec import scheduler as S
+        return S.current()
+    except ImportError:
+        return None
+
+
+def begin_plan(conf: C.RapidsConf) -> Optional[QueryTracer]:
+    """accelerate()'s half of a profiled query: a tracer of the calling
+    thread's own for the plan phase, so its spans are recorded and
+    emitted when they happen and `movement.ledger()` / residency
+    resolve as they do under collect().  None — nothing allocated —
+    with profiling off, and inside a running query or an enclosing
+    accelerate(), whose tracer (if any) records this one's spans.  The
+    caller hands what it gets to `park_plan`."""
+    global _ACTIVE
+    if not conf[C.PROFILE_ENABLED]:
+        return None
+    if getattr(_TLS, "plan", None) is not None \
+            or _current_qc() is not None:
+        return None
+    tr = QueryTracer(conf)
+    with _TRACER_LOCK:
+        _ACTIVE += 1
+    _TLS.plan = tr
+    tr.root = tr.open_span("query", CAT_QUERY, None, None)
+    _TLS.ctx = (tr, tr.root)
+    return tr
+
+
+def park_plan(owner: Optional[QueryTracer], plan=None) -> None:
+    """End of accelerate(): the plan phase's tracer leaves every
+    registry (`_ACTIVE` is back where it was, so a plan that is never
+    collected costs the hot loops nothing) and stays on `plan` as a
+    recording, for the collect() that runs the plan to resume.
+    Without a plan (accelerate() raised) the recording is dropped."""
+    global _ACTIVE
+    if owner is None:
+        return
+    owner.ended = True
+    owner.parked_at = time.perf_counter_ns()
+    _TLS.plan = None
+    if getattr(_TLS, "ctx", None) is not None and _TLS.ctx[0] is owner:
+        _TLS.ctx = None
+    with _TRACER_LOCK:
+        _ACTIVE = max(0, _ACTIVE - 1)
+    if plan is not None:
+        try:
+            plan._plan_phase = owner
+        except AttributeError:
+            pass  # frozen/slots nodes: the plan phase goes unreported
+
+
+def _take_parked(plan) -> Optional[QueryTracer]:
+    """The plan phase accelerate() parked on `plan`, taken off it: the
+    first collect() reports it, a second one has nothing to repeat."""
+    try:
+        return plan.__dict__.pop("_plan_phase", None)
+    except AttributeError:
+        return None
+
+
+def begin_query(conf: Optional[C.RapidsConf] = None, plan=None
                 ) -> Optional[QueryTracer]:
     """Install a tracer for a new top-level query if profiling is
-    enabled and ITS query has none yet.  With a QueryContext in scope
+    enabled and ITS query has none yet: the one accelerate() parked on
+    `plan`, resumed, so the query's profile starts where the query
+    did; a fresh one otherwise.  With a QueryContext in scope
     (the concurrent-serving path) the tracer lives on the context —
     several profiled queries record side by side, each into its own
     tracer; without one (legacy/bare paths) a single process-global
@@ -437,24 +544,22 @@ def begin_query(conf: Optional[C.RapidsConf] = None
     conf = conf if conf is not None else C.get_active_conf()
     if not conf[C.PROFILE_ENABLED]:
         return None
-    try:
-        from spark_rapids_tpu.exec import scheduler as S
-        qc = S.current()
-    except ImportError:
-        qc = None
+    qc = _current_qc()
     with _TRACER_LOCK:
-        if qc is not None:
-            if qc.tracer is not None:
-                return None
-            tr = QueryTracer(conf, query_id=qc.query_id)
-            qc.tracer = tr
+        if (qc.tracer if qc is not None else _TRACER) is not None:
+            return None
+        tr = _take_parked(plan)
+        if tr is not None:
+            tr.resume(conf, qc.query_id if qc is not None else None)
         else:
-            if _TRACER is not None:
-                return None
-            tr = QueryTracer(conf)
+            tr = QueryTracer(
+                conf, query_id=qc.query_id if qc is not None else None)
+        if qc is not None:
+            qc.tracer = tr
         _TRACER = tr        # fallback for query-less threads
         _ACTIVE += 1
-    tr.root = tr.open_span("query", CAT_QUERY, None, None)
+    if tr.root is None:
+        tr.root = tr.open_span("query", CAT_QUERY, None, None)
     _TLS.ctx = (tr, tr.root)
     return tr
 
@@ -472,11 +577,7 @@ def end_query(owner: Optional[QueryTracer], plan=None,
         owner.event(EV_QUERY_ERROR, error=f"{type(error).__name__}: "
                     f"{error}"[:500])
     owner.close_span(owner.root)
-    try:
-        from spark_rapids_tpu.exec import scheduler as S
-        qc = S.current()
-    except ImportError:
-        qc = None
+    qc = _current_qc()
     with _TRACER_LOCK:
         owner.ended = True
         if qc is not None and qc.tracer is owner:
@@ -768,7 +869,7 @@ class QueryProfile:
             oocore = None
         return cls(tr.query_id, tr.wall_start, wall_s,
                    spans, tr.events(), report,
-                   cls._breakdown(spans, tr.root),
+                   cls._breakdown(spans, tr.root, tr.held_ns),
                    dropped_spans=tr.dropped_spans,
                    movement=movement, movement_samples=samples,
                    kernels=kernels, kernel_samples=kernel_samples,
@@ -829,30 +930,44 @@ class QueryProfile:
         return {"operators": per_op, "totals": totals}
 
     @staticmethod
-    def _breakdown(spans: list[Span], root: Optional[Span]) -> dict:
+    def _breakdown(spans: list[Span], root: Optional[Span],
+                   held_ns: int = 0) -> dict:
         """Wall-clock attribution: per-category span time, counting only
         spans whose parent is in a DIFFERENT category (so nested
         same-category spans — a shuffle fetch inside a shuffle reader —
         are not double-counted), with the unattributed remainder of the
-        root span reported as compute.  Category times are CUMULATIVE
-        across threads: several consumers stalling concurrently can
-        push pipeline_wait_s past wall_s (that is real — it measures
-        total starvation, not elapsed time), in which case compute_s
-        clamps at 0."""
+        root span reported as compute.  The plan phase splits in two:
+        `upload_s` is the `SourceUpload[s<k>]` spans (host conversion
+        and device_put of the source batches) and `plan_s` what is left
+        of `plan:accelerate` without them, the planner's own time.
+        `between_calls_s` is the caller's: the root span starts at
+        accelerate(), and a plan may wait for its collect().  Category
+        times are CUMULATIVE across threads: several consumers stalling
+        concurrently can push pipeline_wait_s past wall_s (that is real
+        — it measures total starvation, not elapsed time), in which
+        case compute_s clamps at 0."""
         by_id = {s.sid: s for s in spans}
         wall_ns = root.dur_ns if root is not None else 0
         cats = {CAT_WAIT: 0, CAT_SHUFFLE: 0, CAT_COMPILE: 0,
-                CAT_RETRY: 0, CAT_UDF: 0, CAT_QUEUE: 0}
+                CAT_RETRY: 0, CAT_UDF: 0, CAT_QUEUE: 0, CAT_PLAN: 0}
+        upload_ns = 0
         for s in spans:
             if s.cat not in cats:
+                if s.name.startswith(SPAN_SOURCE_UPLOAD + "["):
+                    upload_ns += s.dur_ns
                 continue
             parent = by_id.get(s.parent_id)
             if parent is not None and parent.cat == s.cat:
                 continue
             cats[s.cat] += s.dur_ns
-        attributed = sum(cats.values())
+        # the uploads run inside plan:accelerate
+        plan_ns = max(cats.pop(CAT_PLAN), upload_ns)
+        attributed = sum(cats.values()) + plan_ns + held_ns
         return {
             "wall_s": round(wall_ns / 1e9, 6),
+            "plan_s": round((plan_ns - upload_ns) / 1e9, 6),
+            "upload_s": round(upload_ns / 1e9, 6),
+            "between_calls_s": round(held_ns / 1e9, 6),
             "pipeline_wait_s": round(cats[CAT_WAIT] / 1e9, 6),
             "shuffle_s": round(cats[CAT_SHUFFLE] / 1e9, 6),
             "compile_s": round(cats[CAT_COMPILE] / 1e9, 6),

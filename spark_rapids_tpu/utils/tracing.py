@@ -1,15 +1,12 @@
 """Trace annotation (reference NVTX ranges, `NvtxWithMetrics.scala:27`).
 
 On TPU the profiler story is xprof/Perfetto: `jax.profiler.TraceAnnotation`
-marks host-side ranges that show up in `jax.profiler.trace` captures, and
-`trace_with_metrics` simultaneously feeds an operator metric, exactly like
-the reference's NvtxWithMetrics feeds a SQLMetric.  The per-query span
-tracer (utils/profile.py) dual-emits through `annotation` so its spans
-line up with device activity in xprof captures."""
+marks host-side ranges that show up in `jax.profiler.trace` captures.  The
+per-query span tracer (utils/profile.py) dual-emits every span through
+`annotation`, so its spans sit on the device trace's clock."""
 from __future__ import annotations
 
-from contextlib import contextmanager, nullcontext
-import time
+from contextlib import nullcontext
 
 import jax
 
@@ -22,25 +19,3 @@ def annotation(name: str):
         return jax.profiler.TraceAnnotation(name)
     except Exception:
         return nullcontext()
-
-
-@contextmanager
-def trace_range(name: str):
-    # Guard only annotation construction — body exceptions must propagate
-    # unchanged (a bare except around the yield would swallow/rewrap them).
-    with annotation(name):
-        yield
-
-
-@contextmanager
-def trace_with_metrics(name: str, metrics, metric_name: str):
-    t0 = time.perf_counter_ns()
-    with trace_range(name):
-        try:
-            yield
-        finally:
-            metrics.add(metric_name, time.perf_counter_ns() - t0)
-
-
-def start_profiler_server(port: int = 9999) -> None:
-    jax.profiler.start_server(port)

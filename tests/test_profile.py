@@ -8,6 +8,7 @@ Wall-clock discipline: ONE profiled TPC-H q5 run (module fixture) backs
 all the span-tree/trace/parity assertions; the event-log tests ride
 cheap q1 runs.
 """
+import functools
 import json
 
 import numpy as np
@@ -350,3 +351,316 @@ def test_event_kind_registry_rejects_unregistered():
     # every constant round-trips through the registry
     assert all(getattr(P, k) in P.EVENT_KINDS
                for k in dir(P) if k.startswith("EV_"))
+
+
+# ---------------------------------------------------------------------------
+# the query's tracer starts at accelerate(): plan-phase spans, the
+# parked recording, the upload edge, kernel names
+CHUNK_ROWS = 128
+
+
+def _q6_plan(tables, conf):
+    """An accelerated, not yet collected q6 over two partitions that
+    each upload in several chunks."""
+    from spark_rapids_tpu.models.tpch_data import sources
+    from spark_rapids_tpu.models.tpch_queries import QUERIES
+    from spark_rapids_tpu.plan.overrides import accelerate
+    src = sources(tables, 2)
+    return accelerate(QUERIES[6](src, None), conf), src
+
+
+def _source_batches(plan):
+    from spark_rapids_tpu.exec.basic import LocalBatchSource
+    todo, out = [plan], []
+    while todo:
+        node = todo.pop()
+        if isinstance(node, LocalBatchSource):
+            out += [b for part in node.partitions for b in part]
+        todo += node.children
+    return out
+
+
+@pytest.fixture(scope="module")
+def q6_from_accelerate(tables):
+    """One profiled accelerate() + two collect()s of its plan, with the
+    movement ledger on: (first profile, second profile, source chunks,
+    padded device bytes of the source batches)."""
+    from spark_rapids_tpu.plan.overrides import collect
+    from spark_rapids_tpu.utils import movement as MV
+    P.clear_history()
+    conf = _conf(spark__rapids__tpu__batchMaxRows=CHUNK_ROWS,
+                 spark__rapids__sql__profile__movement__enabled=True)
+    plan, src = _q6_plan(tables, conf)
+    assert P._ACTIVE == 0 and P.tracer() is None
+    batches = _source_batches(plan)
+    nbytes = sum(MV.vector_device_bytes(c)
+                 for b in batches for c in b.columns)
+    first_df = collect(plan, conf)
+    first = P.last_profile()
+    second_df = collect(plan, conf)
+    second = P.last_profile()
+    pd.testing.assert_frame_equal(first_df, second_df)
+    chunks = sum(-(-len(df) // CHUNK_ROWS)
+                 for df in src["lineitem"].partitions)
+    assert chunks == len(batches) > 2
+    return first, second, chunks, nbytes
+
+
+def _named(prof, name):
+    return [s for s in prof.spans if f"{s.cat}:{s.name}" == name]
+
+
+def test_one_profile_spans_accelerate_through_readback(q6_from_accelerate):
+    first, _, chunks, _ = q6_from_accelerate
+    (root,) = [s for s in first.spans if s.cat == P.CAT_QUERY]
+    (accel,) = _named(first, "plan:accelerate")
+    (upload,) = _named(first, "exec:SourceUpload[s0]")
+    converts = _named(first, "exec:upload-convert")
+    puts = _named(first, "exec:upload-put")
+    readbacks = _named(first, "exec:Readback")
+    assert len(converts) == len(puts) == 2          # one a partition
+    assert sum(s.args["chunks"] for s in converts) == chunks
+    assert sum(s.args["chunks"] for s in puts) == chunks
+    assert {s.args["phase"] for s in readbacks} == {"drain", "convert"}
+    assert accel.parent_id == root.sid and upload.parent_id == accel.sid
+    assert all(s.parent_id == upload.sid for s in converts + puts)
+    assert accel.args == {"nodes_in": 3, "tpu_nodes_out": 4,
+                          "cpu_islands": 0}
+    assert upload.args["batches"] == chunks
+    assert upload.args["partitions"] == 2
+    assert [s.args["partition"] for s in converts] == \
+        [s.args["partition"] for s in puts] == [0, 1]
+    assert upload.args["rows"] == sum(s.args["rows"] for s in puts)
+    # one query id on everything the profile holds
+    assert {e["query_id"] for e in first.events} == {first.query_id}
+    assert first.chrome_trace()["otherData"]["query_id"] == first.query_id
+
+
+def test_plan_phase_children_lie_inside_their_parents_in_time(
+        q6_from_accelerate):
+    first, _, _, _ = q6_from_accelerate
+    by_id = {s.sid: s for s in first.spans}
+    checked = 0
+    for s in first.spans:
+        if s.thread_name != "MainThread" or s.parent_id is None:
+            continue
+        parent = by_id[s.parent_id]
+        if parent.thread_name != "MainThread":
+            continue
+        assert parent.t0 <= s.t0, (parent.name, s.name)
+        assert s.t0 + s.dur_ns <= parent.t0 + parent.dur_ns, \
+            (parent.name, s.name)
+        checked += 1
+    assert checked > 10
+    # true start times: the plan phase comes first, the readback last
+    (accel,) = _named(first, "plan:accelerate")
+    ops = [s for s in first.spans if s.name.startswith("HashAggregate")]
+    assert ops and all(accel.t0 + accel.dur_ns <= s.t0 for s in ops)
+    assert max(s.t0 for s in _named(first, "exec:Readback")) > \
+        max(s.t0 for s in ops)
+
+
+def test_breakdown_splits_plan_and_upload_from_compute(q6_from_accelerate):
+    first, second, _, _ = q6_from_accelerate
+    bd = first.breakdown
+    (accel,) = _named(first, "plan:accelerate")
+    (upload,) = _named(first, "exec:SourceUpload[s0]")
+    assert bd["upload_s"] == round(upload.dur_ns / 1e9, 6)
+    assert bd["plan_s"] == pytest.approx(
+        (accel.dur_ns - upload.dur_ns) / 1e9, abs=2e-6)
+    assert bd["between_calls_s"] > 0
+    parts = sum(v for k, v in bd.items() if k != "wall_s")
+    assert parts == pytest.approx(bd["wall_s"], abs=1e-4)
+    assert "plan_s" in first.explain() and "upload_s" in first.explain()
+    assert second.breakdown["plan_s"] == second.breakdown["upload_s"] == 0
+
+
+def test_second_collect_repeats_no_plan_phase_span(q6_from_accelerate):
+    first, second, _, _ = q6_from_accelerate
+    assert first.query_id != second.query_id
+    for name in ("plan:accelerate", "exec:SourceUpload[s0]",
+                 "exec:upload-convert", "exec:upload-put"):
+        assert _named(first, name) and not _named(second, name), name
+    assert len(_named(second, "exec:Readback")) == 2
+    assert second.breakdown["between_calls_s"] == 0
+
+
+def test_upload_edge_counts_the_source_batches(q6_from_accelerate):
+    from spark_rapids_tpu.utils import movement as MV
+    first, second, _, nbytes = q6_from_accelerate
+    edge = first.movement["edges"][MV.EDGE_UPLOAD]
+    assert edge["bytes"] == nbytes > 0
+    (upload,) = _named(first, "exec:SourceUpload[s0]")
+    assert upload.args["device_bytes"] == nbytes
+    assert sum(s.args["device_bytes"]
+               for s in _named(first, "exec:upload-put")) == nbytes
+    assert second.movement["edges"][MV.EDGE_UPLOAD]["bytes"] == 0
+
+
+def test_accelerate_without_collect_leaves_no_live_tracer(tables):
+    from spark_rapids_tpu.utils import movement as MV
+    plan, _ = _q6_plan(tables, _conf())
+    assert P._ACTIVE == 0 and P._TRACER is None
+    assert P.tracer() is None and MV.ledger() is None
+    assert P.span("x") is P._NULL_SPAN
+    parked = plan._plan_phase
+    assert parked.ended and P.attach((parked, parked.root)) is P._NULL_SPAN
+    assert [s.name for s in parked.spans()
+            if s.cat == P.CAT_PLAN] == ["accelerate"]
+    assert P.last_profile() is None          # a profile is collect()'s
+
+
+def test_accelerate_that_raises_leaves_no_live_tracer(tables):
+    from spark_rapids_tpu.models.tpch_data import sources
+    from spark_rapids_tpu.plan import nodes as N
+    from spark_rapids_tpu.plan.overrides import accelerate
+    src = sources(tables, 1)["lineitem"]
+
+    class NoRule(N.CpuNode):
+        def output_schema(self):
+            return self.child.output_schema()
+    with pytest.raises(AssertionError, match="did not run on the TPU"):
+        accelerate(NoRule(src),
+                   _conf(spark__rapids__sql__test__enabled=True))
+    assert P._ACTIVE == 0 and P.tracer() is None
+    assert getattr(P._TLS, "plan", None) is None
+
+
+def test_accelerate_inside_a_profiled_query_records_into_it(tables):
+    """No second tracer: a plan built while a query runs (a subquery, an
+    AQE re-plan) puts its spans in that query's profile and parks
+    nothing."""
+    from spark_rapids_tpu.exec import scheduler as S
+    conf = _conf()
+    scope = S.QueryScope(conf)
+    try:
+        plan, _ = _q6_plan(tables, conf)
+        assert "_plan_phase" not in plan.__dict__
+        assert P._ACTIVE == 1
+    finally:
+        scope.close()
+    prof = P.last_profile()
+    assert len(_named(prof, "plan:accelerate")) == 1
+    assert len(_named(prof, "exec:SourceUpload[s0]")) == 1
+    assert P._ACTIVE == 0
+
+
+def test_profiling_off_accelerate_opens_no_span_and_kernels_are_shared(
+        tables, monkeypatch):
+    from spark_rapids_tpu.exec import base as EB
+    from spark_rapids_tpu.plan.overrides import collect
+    made = []
+    real_init = P.QueryTracer.__init__
+    monkeypatch.setattr(P.QueryTracer, "__init__",
+                        lambda self, *a, **k: (made.append(self),
+                                               real_init(self, *a, **k))[1])
+    opened = []
+    monkeypatch.setattr(P._SpanCtx, "__enter__",
+                        lambda self: opened.append(self._name))
+    conf = _conf(spark__rapids__sql__profile__enabled=False)
+    plan, _ = _q6_plan(tables, conf)
+    assert "_plan_phase" not in plan.__dict__
+    before = dict(EB._GLOBAL_KERNELS)
+    collect(plan, conf)
+    plan2, _ = _q6_plan(tables, conf)
+    collect(plan2, conf)
+    assert not made and not opened and P.last_profile() is None
+    # the second plan instance built nothing: get_or_build handed out
+    # the executables the first one compiled, object for object
+    first = {k: v for k, v in EB._GLOBAL_KERNELS.items()
+             if k not in before or before[k] is v}
+    assert first and all(EB._GLOBAL_KERNELS[k] is v
+                         for k, v in first.items())
+    scope = plan2.children[0].children[0].kernels
+    key = next(k for s, k in EB._GLOBAL_KERNELS if s == scope._scope)
+    built = []
+    fn = scope.get_or_build(key, lambda: built.append(1))
+    assert not built and fn is EB._GLOBAL_KERNELS[(scope._scope, key)]
+
+
+# -- kernels named on the device ---------------------------------------------
+@functools.lru_cache(maxsize=None)
+def _kernel_vocabulary():
+    """Every literal label a `kp_meta(...)` or `named_jit(...)` call
+    site of the package passes, with the f-string phases spelled out."""
+    import os
+    import re
+    import spark_rapids_tpu
+    root = os.path.dirname(spark_rapids_tpu.__file__)
+    site = re.compile(r'(kp_meta|named_jit)\(\s*f?"([^"]+)"')
+    found = {"kp_meta": set(), "named_jit": set()}
+    for folder, _, files in os.walk(root):
+        for name in files:
+            if name.endswith(".py"):
+                with open(os.path.join(folder, name)) as f:
+                    for kind, label in site.findall(f.read()):
+                        found[kind].add(label)
+    return found
+
+
+def _spell(labels):
+    return sorted({lab.replace("{phase}", ph) for lab in labels
+                   for ph in ("update", "merge")})
+
+
+def test_kernel_names_and_kernelprof_labels_are_one_vocabulary():
+    found = _kernel_vocabulary()
+    # the two exchange kernels and the two mesh programs belong to no
+    # exec, so nothing hands kernelprof a label for them
+    own = {"exchange-split", "exchange-cut", "mesh-exchange", "mesh-count"}
+    assert found["named_jit"] - own == found["kp_meta"]
+    # plus `sort` / `sort-head` and the fused stage's, passed by name
+    assert own <= found["named_jit"] and len(found["named_jit"]) == 23
+
+
+@pytest.mark.parametrize("label", _spell(
+    _kernel_vocabulary()["named_jit"]) + [
+        "sort", "sort-head", "fused-3-project-filter-project"])
+def test_lowered_module_is_named_for_its_label(label):
+    import re
+    import jax.numpy as jnp
+    from spark_rapids_tpu.exec import base as EB
+    kern = EB.named_jit(label, lambda x: x + 1)
+    text = kern.lower(jnp.ones(8)).as_text()
+    name = re.search(r"module @(\w+)", text).group(1)
+    assert name == "jit_" + label.replace("-", "_")
+    assert not re.search(r"\d{2,}", name)       # no shape, no hash
+
+
+def test_built_kernels_carry_their_label_as_their_name(tables):
+    """Under kernelprof every cached executable knows the label its
+    call site passed: it is the executable's name, so the device trace
+    and the host's kernel table speak of the same kernel."""
+    from spark_rapids_tpu.exec import base as EB
+    from spark_rapids_tpu.utils import kernelprof as KP
+    try:
+        for q in (3, 6, 1):
+            _run_q(q, tables,
+                   spark__rapids__sql__profile__kernels__enabled=True)
+        # an entry with an owner was labelled by its call site
+        seen = {fn._kp_entry.label: fn._kp_fn.__name__
+                for fn in list(EB._GLOBAL_KERNELS.values())
+                if isinstance(fn, KP.WatchedKernel) and fn._kp_entry.owners}
+        assert {"agg-update", "agg-merge", "sort"} <= set(seen), seen
+        for label, name in seen.items():
+            assert name == EB.kernel_name(label), (label, name)
+    finally:
+        KP.reset()
+
+
+def test_a_fused_stage_names_its_members_and_no_shape():
+    from spark_rapids_tpu.exec import base as EB
+    from spark_rapids_tpu.plan.fusion import ComposedStage
+
+    class ProjectExec:
+        pass
+
+    class FilterExec:
+        pass
+    stage = ComposedStage([], [], None, None,
+                          [ProjectExec(), FilterExec(), ProjectExec()])
+    assert stage.kernel_label == "fused-3-project-filter-project"
+    stage = ComposedStage([], [], None, None, [ProjectExec()] * 40)
+    name = EB.kernel_name(stage.kernel_label)
+    assert name.startswith("fused_40_project_") and \
+        len(name) == EB.KERNEL_NAME_MAX

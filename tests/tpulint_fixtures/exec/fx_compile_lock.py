@@ -4,6 +4,8 @@ import threading
 
 import jax
 
+from spark_rapids_tpu.exec.base import named_jit
+
 _LOCK = threading.Lock()
 _CACHE = {}
 
@@ -14,6 +16,8 @@ def compiles_under_the_lock(key, builder, cache):
         _CACHE[key] = fn
     with cache._lock:
         fn = cache.get_or_build(key, builder)   # EXPECT: compile-under-lock
+    with _LOCK:
+        fn = named_jit("filter", builder)       # EXPECT: compile-under-lock
     return fn
 
 
