@@ -53,8 +53,9 @@ def _f32_shadow(x_f64: np.ndarray) -> np.ndarray:
       - sign preserved (incl. -0.0)."""
     with np.errstate(over="ignore"):
         n32 = x_f64.astype(np.float32)
-    over = np.isinf(n32) & np.isfinite(x_f64)
-    if over.any():
+    over = np.isinf(n32)
+    if over.any():          # rare: only then is the f64 column read again
+        over &= np.isfinite(x_f64)
         fmax = np.finfo(np.float32).max
         n32 = np.where(over, np.copysign(fmax, x_f64).astype(np.float32),
                        n32)
@@ -68,6 +69,45 @@ def _pad_to(arr: np.ndarray, capacity: int, axis: int = 0) -> np.ndarray:
     pad = [(0, 0)] * arr.ndim
     pad[axis] = (0, capacity - n)
     return np.pad(arr, pad)
+
+
+def host_validity(values: np.ndarray,
+                  validity: Optional[np.ndarray]) -> np.ndarray:
+    """A column's bool validity on the host, unpadded: the caller's
+    mask, else None-ness of object values, else all True (NaN is a
+    value, not null: Spark)."""
+    if validity is None:
+        if values.dtype == object:
+            return np.array([v is not None for v in values], bool)
+        return np.ones(len(values), bool)
+    return np.asarray(validity, bool)
+
+
+def host_storage(values: np.ndarray, dtype: T.DataType) -> np.ndarray:
+    """A fixed-width column's values in its storage dtype on the host,
+    unpadded: None -> 0, datetimes -> int64 micros; a view where the
+    dtype already matches."""
+    storage = dtype.storage_dtype
+    if values.dtype == object:
+        return np.array([v if v is not None else 0 for v in values],
+                        dtype=storage)
+    if values.dtype.kind == "M":
+        return values.astype("datetime64[us]").astype(np.int64)
+    return np.asarray(values).astype(storage, copy=False)
+
+
+def host_narrow(safe: np.ndarray, dtype: T.DataType
+                ) -> Optional[np.ndarray]:
+    """The 32-bit shadow of a host storage array (see
+    `ColumnVector.narrow`), or None: int32 for an INT64 array whose
+    every value fits, `_f32_shadow` for FLOAT64."""
+    if dtype.id == T.TypeId.INT64 and len(safe):
+        lo, hi = safe.min(), safe.max()
+        if np.iinfo(np.int32).min <= lo and hi <= np.iinfo(np.int32).max:
+            return safe.astype(np.int32)
+    elif dtype.id == T.TypeId.FLOAT64:
+        return _f32_shadow(safe)
+    return None
 
 
 @jax.tree_util.register_pytree_node_class
@@ -85,6 +125,15 @@ class ColumnVector:
     FLOAT64 columns (LOSSY — only used by paths that already carry
     variableFloatAgg-class tolerance).  Kernels check for it at trace
     time (it is part of the batch signature).
+
+    How the arrays reach the device: `from_numpy` pads on the host and
+    sends each array of one batch by itself.  A plan's in-memory source
+    (`plan/overrides._conv_source`) builds the same vectors through
+    `ColumnarBatch.chunks_from_numpy`: the host halves here
+    (`host_storage`, `host_validity`, `host_narrow`) run once a column
+    of a partition, the full chunks go to the device whole and are cut
+    there.  The INT64 shadow is then decided once for the partition's
+    run of chunks, not chunk by chunk.
     """
     dtype: T.DataType
     data: jnp.ndarray
@@ -108,6 +157,11 @@ class ColumnVector:
         return self.data.shape[0]
 
     @property
+    def device_arrays(self) -> int:
+        """How many device arrays the column is made of."""
+        return 2 + (self.lengths is not None) + (self.narrow is not None)
+
+    @property
     def char_cap(self) -> int:
         assert self.dtype.is_string
         return self.data.shape[1]
@@ -125,36 +179,16 @@ class ColumnVector:
             dtype = T.from_numpy_dtype(values.dtype)
         n = len(values)
         cap = capacity or bucket_capacity(n)
-        if validity is None:
-            if values.dtype == object:
-                validity = np.array([v is not None for v in values], bool)
-            elif np.issubdtype(values.dtype, np.floating):
-                validity = np.ones(n, bool)  # NaN is a value, not null (Spark)
-            else:
-                validity = np.ones(n, bool)
-        validity = _pad_to(np.asarray(validity, bool), cap)
+        validity = _pad_to(host_validity(values, validity), cap)
 
         if dtype.is_string:
             return _strings_from_host(values, validity, cap)
 
-        storage = dtype.storage_dtype
-        if values.dtype == object:
-            safe = np.array([v if v is not None else 0 for v in values],
-                            dtype=storage)
-        elif values.dtype.kind == "M":
-            safe = values.astype("datetime64[us]").astype(np.int64)
-        else:
-            safe = np.asarray(values).astype(storage, copy=False)
-        safe = _pad_to(safe, cap)
-        narrow = None
-        if dtype.id == T.TypeId.INT64 and len(safe):
-            lo, hi = safe.min(), safe.max()
-            if np.iinfo(np.int32).min <= lo and hi <= np.iinfo(np.int32).max:
-                narrow = jnp.asarray(safe.astype(np.int32))
-        elif dtype.id == T.TypeId.FLOAT64:
-            narrow = jnp.asarray(_f32_shadow(safe))
+        safe = _pad_to(host_storage(values, dtype), cap)
+        narrow = host_narrow(safe, dtype)
         return ColumnVector(dtype, jnp.asarray(safe), jnp.asarray(validity),
-                            None, narrow)
+                            None, None if narrow is None
+                            else jnp.asarray(narrow))
 
     @staticmethod
     def from_scalar(value: Any, dtype: T.DataType, capacity: int,

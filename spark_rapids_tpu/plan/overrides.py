@@ -299,40 +299,51 @@ def _conv_source(meta, kids) -> TpuExec:
     # partitions as one batch made an SF1 lineitem partition a 4M-row
     # kernel shape, and XLA:TPU compile time grows steeply with it (q6's
     # reduce kernel: past the 300 s task watchdog on the v5e's host).
+    #
+    # The batches are chunks; the TRANSFERS are not.  A host-to-device
+    # call costs about a quarter of a millisecond on the v5e whatever it
+    # carries, so a partition is converted once and its fixed-width
+    # columns go to the device whole, in one `device_put`; a jitted
+    # split program cuts them there into the batches a chunk-by-chunk
+    # `from_numpy` would give (`ColumnarBatch.chunks_from_numpy`: one
+    # chunk, and string columns, still go chunk by chunk).
     max_rows = meta.conf[C.MAX_BATCH_ROWS]
     schema = node.output_schema()
     # the upload is eager and most of a hot scan query's wall: one span
     # per source around all of it and, per partition, one around the
-    # host conversion of its chunks and one around their device_puts
+    # host conversion and one around the device_puts and the split
     # (the operator ranges of the reference's HostColumnarToGpu).  Per
     # partition, not per chunk: a trace reader that explains every idle
-    # gap of the device by the spans around it pays for each span
+    # gap of the device by the spans around it pays for each span.
+    # `transfers` counts the host-to-device arrays sent
     tr = P.tracer()
     label = "" if tr is None else \
         f"{P.SPAN_SOURCE_UPLOAD}[s{tr.ordinal(P.SPAN_SOURCE_UPLOAD)}]"
-    parts, nbytes = [], 0
+    parts, nbytes, transfers = [], 0, 0
     with P.span(label) as upload:
         for p, df in enumerate(node.partitions):
-            los = range(0, len(df), max_rows)
+            nchunks = -(-len(df) // max_rows)
             with P.span(P.SPAN_UPLOAD_CONVERT, partition=p,
-                        chunks=len(los), rows=len(df)):
-                host = [host_columns_from_df(df.iloc[lo:lo + max_rows],
-                                             schema) for lo in los]
+                        chunks=nchunks, rows=len(df)):
+                data, validity = host_columns_from_df(df, schema)
             with P.span(P.SPAN_UPLOAD_PUT, partition=p,
-                        chunks=len(los), rows=len(df)) as put:
-                chunks = [ColumnarBatch.from_numpy(data, schema, validity)
-                          for data, validity in host]
+                        chunks=nchunks, rows=len(df)) as put:
+                chunks, sent = ColumnarBatch.chunks_from_numpy(
+                    data, schema, validity, max_rows)
                 if put is not None:
                     put.args["device_bytes"] = b = sum(
                         MV.vector_device_bytes(col)
                         for batch in chunks for col in batch.columns)
+                    put.args["transfers"] = sent
                     nbytes += b
+                    transfers += sent
             parts.append(chunks)
         if upload is not None:
             upload.args = {"partitions": len(parts),
                            "batches": sum(map(len, parts)),
                            "rows": sum(map(len, node.partitions)),
-                           "device_bytes": nbytes}
+                           "device_bytes": nbytes,
+                           "transfers": transfers}
     src = B.LocalBatchSource(parts, node.output_schema())
     # stable identity across plan rebuilds: the uploaded device batches
     # are fresh per accelerate(), but the backing pandas partitions are

@@ -194,3 +194,18 @@ def test_planner_kernel_compiles_for_v5e(one_chip, planner_kernels, tag,
     args, kwargs = jax.tree_util.tree_map(place, (args, kwargs))
     compiled = fn.lower(*args, **kwargs).compile()
     assert compiled.memory_analysis() is not None
+
+
+def test_upload_split_compiles_for_v5e(one_chip):
+    """The source upload's split program at the largest shape a cell
+    runs: an SF1 lineitem partition's 45 full chunks of q6's four
+    columns (data, validity, float32 shadows: 11 arrays, 130 MB)."""
+    from spark_rapids_tpu.columnar.batch import _split_chunks_jit
+    rows = 45 * CAP
+    dtypes = [jnp.int32, jnp.bool_] + 3 * [jnp.float64, jnp.bool_,
+                                           jnp.float32]
+    compiled = _split_chunks_jit.lower(
+        [_spec(one_chip, (rows,), d) for d in dtypes], CAP).compile()
+    outs = jax.tree_util.tree_leaves(compiled.out_info)
+    assert len(outs) == 45 * 11 and {o.shape for o in outs} == {(CAP,)}
+    assert compiled.memory_analysis() is not None

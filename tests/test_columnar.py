@@ -119,3 +119,170 @@ def test_f32_shadow_overflow_boundaries():
     fin = [0, 1, 2, 3, 4, 8, 9, 10]
     order64 = np.argsort(vals[fin], kind="stable")
     assert (np.diff(n[fin][order64]) >= 0).all()
+
+
+# ---------------------------------------------------------------------------
+# grouped source upload: ColumnarBatch.chunks_from_numpy against the
+# chunk-by-chunk from_numpy it stands in for
+def _i64(rng, n):
+    return rng.integers(-5_000, 5_000, n).astype(np.int64)
+
+
+def _i64_wide(rng, n):
+    v = _i64(rng, n)
+    v[n // 2] = np.int64(1) << 40          # one chunk leaves int32
+    return v
+
+
+def _f64_wide(rng, n):
+    v = rng.normal(size=n) * 1e3
+    v[::97] = 1e300                        # past float32: shadow clamps
+    v[1::97] = -np.inf
+    v[2::97] = np.nan                      # a value, not a null
+    return v
+
+
+_GROUPED_COLUMNS = {
+    "int64": (T.INT64, _i64),
+    "int64-past-int32": (T.INT64, _i64_wide),
+    "int32": (T.INT32, lambda rng, n: rng.integers(0, 99, n)
+              .astype(np.int32)),
+    "date32": (T.DATE32, lambda rng, n: rng.integers(8000, 11000, n)
+               .astype(np.int32)),
+    "bool": (T.BOOL, lambda rng, n: rng.random(n) < 0.5),
+    "float64": (T.FLOAT64, lambda rng, n: rng.random(n)),
+    "float64-past-float32": (T.FLOAT64, _f64_wide),
+    "timestamp": (T.TIMESTAMP_US, lambda rng, n: rng.integers(
+        0, 1 << 50, n).astype(np.int64)),
+    "string": (T.STRING, lambda rng, n: np.array(
+        [None if i % 11 == 0 else "w" * (i % 23) for i in range(n)],
+        dtype=object)),
+}
+
+
+def _host_frame(kinds, n, nulls=True):
+    rng = np.random.default_rng(n)
+    fields, data, validity = [], {}, {}
+    for i, kind in enumerate(kinds):
+        dtype, make = _GROUPED_COLUMNS[kind]
+        name = f"c{i}"
+        fields.append(T.Field(name, dtype))
+        data[name] = make(rng, n)
+        if dtype.is_string:
+            validity[name] = np.array([v is not None for v in data[name]])
+        else:
+            validity[name] = rng.random(n) > 0.1 if nulls \
+                else np.ones(n, bool)
+    return data, T.Schema(tuple(fields)), validity
+
+
+def _per_chunk(data, schema, validity, max_rows):
+    n = len(next(iter(data.values())))
+    return [ColumnarBatch.from_numpy(
+        {k: v[lo:lo + max_rows] for k, v in data.items()}, schema,
+        validity and {k: v[lo:lo + max_rows] for k, v in validity.items()})
+        for lo in range(0, n, max_rows)]
+
+
+def _assert_same_batches(got, ref, withheld=()):
+    """Array-equal batches; a column in `withheld` may lack the INT64
+    shadow that only some of its chunks could carry."""
+    assert len(got) == len(ref)
+    for g, r in zip(got, ref):
+        assert g.num_rows == r.num_rows and g.capacity == r.capacity
+        assert g.schema == r.schema
+        for f, cg, cr in zip(g.schema.fields, g.columns, r.columns):
+            pairs = [(cg.data, cr.data), (cg.validity, cr.validity),
+                     (cg.lengths, cr.lengths)]
+            if not (f.name in withheld and cg.narrow is None):
+                pairs.append((cg.narrow, cr.narrow))
+            for x, y in pairs:
+                assert (x is None) == (y is None), f
+                if x is not None:
+                    assert x.dtype == y.dtype and x.shape == y.shape, f
+                    assert np.array_equal(np.asarray(x), np.asarray(y),
+                                          equal_nan=x.dtype.kind == "f"), f
+
+
+@pytest.mark.parametrize("n,max_rows", [(1000, 128), (1024, 128),
+                                        (1000, 100)],
+                         ids=["ragged-tail", "no-tail", "padded-chunks"])
+@pytest.mark.parametrize("kind", list(_GROUPED_COLUMNS))
+def test_grouped_upload_equals_per_chunk_from_numpy(kind, n, max_rows):
+    kinds = [kind, "int32"] if kind == "string" else [kind]
+    data, schema, validity = _host_frame(kinds, n)
+    got, sent = ColumnarBatch.chunks_from_numpy(data, schema, validity,
+                                                max_rows)
+    ref = _per_chunk(data, schema, validity, max_rows)
+    wide = kind == "int64-past-int32"
+    _assert_same_batches(got, ref, withheld={"c0"} if wide else ())
+    if wide:        # decided once a run: withheld everywhere
+        assert all(b.columns[0].narrow is None for b in got)
+        assert any(b.columns[0].narrow is not None for b in ref)
+    per_chunk = sum(c.device_arrays for b in ref for c in b.columns)
+    assert 0 < sent < per_chunk
+    if kind != "string":
+        arrays = got[0].columns[0].device_arrays
+        assert sent == arrays * (1 + (n % max_rows > 0))
+
+
+def test_grouped_upload_mixed_frame_without_validity():
+    """Every kind in one frame, and `validity=None`: all rows valid but
+    the None strings, as `from_numpy` reads them."""
+    data, schema, _ = _host_frame(list(_GROUPED_COLUMNS), 700)
+    got, _ = ColumnarBatch.chunks_from_numpy(data, schema, None, 64)
+    _assert_same_batches(got, _per_chunk(data, schema, None, 64),
+                         withheld={"c1"})
+
+
+@pytest.mark.parametrize("n", [0, 1, 100, 128, 200, 255])
+def test_fewer_than_two_full_chunks_build_no_split_program(n, monkeypatch):
+    from spark_rapids_tpu.columnar import batch as CB
+
+    def no_split(*_a, **_k):
+        raise AssertionError("split program built")
+    monkeypatch.setattr(CB, "_split_chunks_jit", no_split)
+    data, schema, validity = _host_frame(["int64", "float64"], n)
+    got, sent = ColumnarBatch.chunks_from_numpy(data, schema, validity, 128)
+    _assert_same_batches(got, _per_chunk(data, schema, validity, 128))
+    assert len(got) == -(-n // 128)
+    assert sent == 6 * len(got)
+
+
+def test_partition_over_the_byte_budget_goes_in_several_transfers(
+        monkeypatch):
+    from spark_rapids_tpu.columnar import batch as CB
+    data, schema, validity = _host_frame(
+        ["int64", "float64", "date32"], 1000, nulls=False)
+    one, sent_one = ColumnarBatch.chunks_from_numpy(data, schema, validity,
+                                                    64)
+    runs = []
+    split = CB._split_chunks_jit
+    monkeypatch.setattr(
+        CB, "_split_chunks_jit",
+        lambda arrays, max_rows: runs.append(arrays[0].shape[0])
+        or split(arrays, max_rows))
+    # 8+5, 8+5, 4+5 bytes a row: five chunks of 64 rows a transfer
+    monkeypatch.setattr(CB, "UPLOAD_TRANSFER_BYTES", 5 * 64 * 35)
+    got, sent = ColumnarBatch.chunks_from_numpy(data, schema, validity, 64)
+    assert runs == [320, 320, 320]          # 1000 = 3 x 320 + 40
+    assert sent_one == 2 * 8 and sent == 4 * 8
+    _assert_same_batches(got, one)
+    _assert_same_batches(got, _per_chunk(data, schema, validity, 64))
+
+
+def test_grouped_upload_keeps_no_whole_column_on_the_device():
+    """Once the batches are built nothing holds the whole columns: what
+    stays live on the device is the batches' own arrays."""
+    import gc
+    import jax
+    from spark_rapids_tpu.utils.movement import vector_device_bytes
+    data, schema, validity = _host_frame(["int64", "float64"], 5000)
+
+    def live():
+        gc.collect()
+        return sum(a.nbytes for a in jax.live_arrays())
+    before = live()
+    got, _ = ColumnarBatch.chunks_from_numpy(data, schema, validity, 512)
+    assert live() - before == sum(
+        vector_device_bytes(c) for b in got for c in b.columns)

@@ -356,7 +356,7 @@ def test_event_kind_registry_rejects_unregistered():
 # ---------------------------------------------------------------------------
 # the query's tracer starts at accelerate(): plan-phase spans, the
 # parked recording, the upload edge, kernel names
-CHUNK_ROWS = 128
+CHUNK_ROWS = 32       # 150-row partitions: four full chunks and a tail
 
 
 def _q6_plan(tables, conf):
@@ -495,6 +495,75 @@ def test_upload_edge_counts_the_source_batches(q6_from_accelerate):
     assert sum(s.args["device_bytes"]
                for s in _named(first, "exec:upload-put")) == nbytes
     assert second.movement["edges"][MV.EDGE_UPLOAD]["bytes"] == 0
+
+
+def test_upload_spans_count_their_transfers(q6_from_accelerate):
+    """`transfers`: host-to-device arrays sent.  A q6 partition's four
+    pruned columns are 11 arrays (4 data, 4 validity, 3 float32
+    shadows); its full chunks go whole and its ragged tail beside them,
+    whatever the chunk count."""
+    first, _, chunks, _ = q6_from_accelerate
+    (upload,) = _named(first, "exec:SourceUpload[s0]")
+    puts = _named(first, "exec:upload-put")
+    arrays = 11
+    for put in puts:
+        assert put.args["chunks"] > 2
+        runs = 1 + (put.args["rows"] % CHUNK_ROWS > 0)
+        assert put.args["transfers"] == arrays * runs
+    assert upload.args["transfers"] == sum(s.args["transfers"]
+                                           for s in puts)
+    assert upload.args["transfers"] <= arrays * 2 * len(puts) \
+        < arrays * chunks
+
+
+def test_repeated_q6_compiles_nothing_and_the_split_is_one_program(
+        tables, monkeypatch):
+    """Exact compile requests through accelerate() + collect(): against
+    the chunk-by-chunk upload (a byte budget of one chunk) the grouped
+    one asks for one program more, the split, and a repeat of the query
+    asks for none."""
+    import jax
+    import jax.monitoring
+    from spark_rapids_tpu.columnar import batch as CB
+    from spark_rapids_tpu.exec.base import clear_kernel_cache
+    from spark_rapids_tpu.models.tpch_data import sources
+    from spark_rapids_tpu.models.tpch_queries import QUERIES
+    from spark_rapids_tpu.plan.overrides import accelerate, collect
+    event = "/jax/compilation_cache/compile_requests_use_cache"
+    seen = []
+
+    def listen(name, **_kw):
+        if name == event:
+            seen.append(name)
+    jax.monitoring.register_event_listener(listen)
+    conf = _conf(spark__rapids__tpu__batchMaxRows=CHUNK_ROWS,
+                 spark__rapids__sql__profile__enabled=False)
+    # partitions of one length: one split shape
+    even = {k: v.iloc[:len(v) - len(v) % 2] for k, v in tables.items()}
+    src = sources(even, 2)
+
+    def requests():
+        before = len(seen)
+        answer = collect(accelerate(QUERIES[6](src, None), conf), conf)
+        return len(seen) - before, answer
+
+    def cold():
+        clear_kernel_cache()
+        jax.clear_caches()
+        return requests()
+
+    try:
+        grouped, answer = cold()
+        repeat, again = requests()
+        monkeypatch.setattr(CB, "UPLOAD_TRANSFER_BYTES", 1)
+        per_chunk, reference = cold()
+    finally:
+        jax.monitoring.unregister_event_listener(listen)
+    assert per_chunk > 0, "compile requests are not being counted"
+    assert grouped == per_chunk + 1
+    assert repeat == 0
+    pd.testing.assert_frame_equal(answer, again)
+    pd.testing.assert_frame_equal(answer, reference)
 
 
 def test_accelerate_without_collect_leaves_no_live_tracer(tables):
