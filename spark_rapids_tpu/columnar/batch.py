@@ -17,6 +17,9 @@ conversions verify them before results are trusted.
 """
 from __future__ import annotations
 
+import contextlib
+import functools
+import threading
 from typing import Iterable, Optional
 
 import jax
@@ -51,6 +54,101 @@ def _split_chunks(arrays, max_rows: int):
 
 _split_chunks.__name__ = "upload_split"       # its name on the device
 _split_chunks_jit = jax.jit(_split_chunks, static_argnums=1)
+
+
+# ---------------------------------------------------------------------------
+# The batch helpers' device programs (compact, slice, concat), named for
+# the operator that dispatches them: a device trace then shows
+# `jit_join_concat`, `jit_exchange_slice`, `jit_agg_concat` beside the
+# operators' own kernels, where a chain of eager `jit__take` /
+# `jit_concatenate` / `jit_less` operations told nothing about their
+# owner (and each compiled on its own).
+_OWNER = threading.local()
+
+
+@contextlib.contextmanager
+def programs_of(owner: str):
+    """While open, this thread's batch helpers run as `jit_<owner>_<helper>`
+    (`exec/base.kernel_name` spelling).  An operator opens it around
+    its OWN helper calls, not around its child's pulls."""
+    prev = getattr(_OWNER, "name", None)
+    _OWNER.name = owner
+    try:
+        yield
+    finally:
+        _OWNER.name = prev
+
+
+def _program(fn, **jit_kwargs):
+    """`fn` jitted under the current owner's name, built once a name."""
+    owner = getattr(_OWNER, "name", None) or "batch"
+    return _labelled(f"{owner}_{fn.__name__.strip('_')}", fn,
+                     tuple(sorted(jit_kwargs.items())))
+
+
+@functools.lru_cache(maxsize=None)
+def _labelled(label: str, fn, jit_kwargs: tuple):
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs)
+    program.__name__ = label                  # its name on the device
+    return jax.jit(program, **dict(jit_kwargs))
+
+
+def _dense(columns, sparse, n):
+    cap = sparse.shape[0]
+    (idx,) = jnp.nonzero(sparse, size=cap, fill_value=cap - 1)
+    valid = jnp.arange(cap) < n
+    return [c.gather(idx, valid) for c in columns]
+
+
+def _slice(columns, start, length, cap):
+    idx = jnp.arange(cap) + start
+    valid = jnp.arange(cap) < length
+    return [c.gather(jnp.where(valid, idx, 0), valid) for c in columns]
+
+
+def _concat(columns, rows, out_cap):
+    """`columns`: every input batch's (dense) columns; `rows`: their row
+    counts, int32[B].  Output row i maps to input batch
+    j = #(cumulative counts <= i) at local row i - start_j: all index
+    math on the device against the (small) count vector."""
+    caps = [cols[0].capacity for cols in columns]
+    cap_offsets = np.concatenate([[0], np.cumsum(caps)[:-1]])
+    cum = jnp.cumsum(rows)
+    starts = cum - rows
+    total = cum[-1]
+    i = jnp.arange(out_cap, dtype=jnp.int32)
+    bid = (i[:, None] >= cum[None, :]).sum(axis=1)  # cap x B compares
+    bid_c = jnp.minimum(bid, len(columns) - 1)
+    local = i - jnp.take(starts, bid_c)
+    jidx = jnp.take(jnp.asarray(cap_offsets, jnp.int32), bid_c) + local
+    valid = i < total
+    jidx = jnp.where(valid, jidx, 0)
+    cols = []
+    for data, validity, lengths, narrow, dtype in _stack_columns(columns):
+        cols.append(ColumnVector(
+            dtype,
+            jnp.take(data, jidx, axis=0, mode="clip"),
+            jnp.take(validity, jidx, mode="clip") & valid,
+            None if lengths is None else jnp.take(lengths, jidx, mode="clip"),
+            None if narrow is None else jnp.take(narrow, jidx, mode="clip")))
+    return cols, total
+
+
+def _concat_sparse(columns, masks, pad):
+    def pad_tail(arr, fill=0):
+        if not pad or arr is None:
+            return arr
+        tail_shape = (pad,) + arr.shape[1:]
+        return jnp.concatenate([arr, jnp.full(tail_shape, fill, arr.dtype)])
+
+    if pad:
+        masks = list(masks) + [jnp.zeros((pad,), bool)]
+    cols = [ColumnVector(dtype, pad_tail(data), pad_tail(validity, False),
+                         pad_tail(lengths), pad_tail(narrow))
+            for data, validity, lengths, narrow, dtype
+            in _stack_columns(columns)]
+    return cols, jnp.concatenate(masks)
 
 
 def _rows(arrays: Optional[dict], lo: int, hi: int) -> Optional[dict]:
@@ -164,11 +262,8 @@ class ColumnarBatch:
         exits and position-addressed ops should need it)."""
         if self.sparse is None:
             return self
-        cap = self.capacity
         n = self.num_rows_i32
-        (idx,) = jnp.nonzero(self.sparse, size=cap, fill_value=cap - 1)
-        valid = jnp.arange(cap) < n
-        cols = [c.gather(idx, valid) for c in self.columns]
+        cols = _program(_dense)(self.columns, self.sparse, n)
         rows = self._rows if isinstance(self._rows, int) else n
         return ColumnarBatch(self.schema, cols, rows, self.checks)
 
@@ -438,11 +533,8 @@ class ColumnarBatch:
         if self.sparse is not None:
             return self.dense().slice(start, length)
         length = max(0, min(length, self.num_rows - start))
-        cap = bucket_capacity(length)
-        idx = jnp.arange(cap) + start
-        valid = jnp.arange(cap) < length
-        cols = [c.gather(jnp.where(valid, idx, 0), valid)
-                for c in self.columns]
+        cols = _program(_slice, static_argnames=("cap",))(
+            self.columns, start, length, cap=bucket_capacity(length))
         return ColumnarBatch(self.schema, cols, length, self.checks)
 
     def take_head(self, n: int) -> "ColumnarBatch":
@@ -512,41 +604,45 @@ def concat_batches(batches: list[ColumnarBatch],
     if len(batches) == 1:
         return batches[0]
     if sparse_ok and any(b.sparse is not None for b in batches):
-        return _concat_sparse(batches)
+        return _concat_sparse_batches(batches)
     batches = [b.dense() for b in batches]
     schema = batches[0].schema
     checks = tuple(c for b in batches for c in b.checks)
-    lazy = not all(b.num_rows_known for b in batches)
-    if lazy:
-        return _concat_lazy(batches, schema, checks)
-    total = sum(b.num_rows for b in batches)
-    cap = bucket_capacity(total)
-    out_cols = _stack_columns(batches, schema)
-    # gather indices: for each batch, rows [0, num_rows) at its offset
-    idx_parts, off = [], 0
-    for b in batches:
-        idx_parts.append(np.arange(b.num_rows) + off)
-        off += b.capacity
-    idx = np.concatenate(idx_parts) if idx_parts else np.zeros(0, np.int64)
-    idx = np.pad(idx, (0, cap - len(idx)))
-    jidx = jnp.asarray(idx)
-    valid = jnp.arange(cap) < total
-    cols = []
-    for (data, validity, lengths, narrow), f in zip(out_cols, schema.fields):
-        cols.append(ColumnVector(
-            f.dtype,
-            jnp.take(data, jidx, axis=0, mode="clip"),
-            jnp.take(validity, jidx, mode="clip") & valid,
-            None if lengths is None else jnp.take(lengths, jidx, mode="clip"),
-            None if narrow is None else jnp.take(narrow, jidx, mode="clip")))
-    return ColumnarBatch(schema, cols, total, checks)
+    if len(batches) > 64:
+        # tree-chunked: the bucket-id search materializes an
+        # [out_cap, B] compare matrix, which at B=400 inputs of a
+        # 26M-row reduce partition reached a 12.8GB intermediate and
+        # OOMed HBM at compile time — chunks bound the matrix and
+        # recurse on the (few) chunk results
+        return concat_batches([concat_batches(batches[i:i + 64])
+                               for i in range(0, len(batches), 64)])
+    if all(b.num_rows_known for b in batches):
+        # tight: the bucket of the rows there are
+        total = sum(b.num_rows for b in batches)
+        out_cap = bucket_capacity(total)
+        rows = np.array([b.num_rows for b in batches], np.int32)
+    else:
+        # sync-free: the bucketed sum of input CAPACITIES (the static
+        # worst case); the output row count stays on the device
+        total = None
+        out_cap = bucket_capacity(sum(b.capacity for b in batches))
+        rows = jnp.stack([b.num_rows_i32 for b in batches])
+    if not schema.fields:
+        return ColumnarBatch(schema, [], total if total is not None
+                             else jnp.sum(rows), checks)
+    cols, lazy_total = _program(_concat, static_argnames=("out_cap",))(
+        [b.columns for b in batches], rows, out_cap=out_cap)
+    return ColumnarBatch(schema, cols,
+                         total if total is not None else lazy_total, checks)
 
 
-def _stack_columns(batches, schema):
+def _stack_columns(columns):
+    """[(data, validity, lengths, narrow, dtype)] of every column, the
+    input batches' stacked in order; `columns` is each batch's list."""
     out_cols = []
-    for ci, f in enumerate(schema.fields):
-        vecs = [b.columns[ci] for b in batches]
-        if f.dtype.is_string:
+    for vecs in zip(*columns):
+        dtype = vecs[0].dtype
+        if dtype.is_string:
             cc = max(v.char_cap for v in vecs)
             from spark_rapids_tpu.columnar.vector import _pad_chars
             vecs = [_pad_chars(v, cc) for v in vecs]
@@ -556,11 +652,11 @@ def _stack_columns(batches, schema):
                    if vecs[0].lengths is not None else None)
         narrow = (jnp.concatenate([v.narrow for v in vecs])
                   if all(v.narrow is not None for v in vecs) else None)
-        out_cols.append((data, validity, lengths, narrow))
+        out_cols.append((data, validity, lengths, narrow, dtype))
     return out_cols
 
 
-def _concat_sparse(batches) -> ColumnarBatch:
+def _concat_sparse_batches(batches) -> ColumnarBatch:
     """Gather-free concat: stack each input's padded columns and its
     selection mask; the output batch keeps capacity = bucketed sum of
     input capacities with selection still deferred.  Compaction, if a
@@ -569,68 +665,12 @@ def _concat_sparse(batches) -> ColumnarBatch:
     schema = batches[0].schema
     checks = tuple(c for b in batches for c in b.checks)
     scap = sum(b.capacity for b in batches)
-    cap = bucket_capacity(scap)
-    pad = cap - scap
     masks = [b.sparse if b.sparse is not None else b.row_mask()
              for b in batches]
-    if pad:
-        masks.append(jnp.zeros((pad,), bool))
-    mask = jnp.concatenate(masks)
     total = sum(b.num_rows for b in batches) \
         if all(b.num_rows_known for b in batches) else \
         jnp.sum(jnp.stack([b.num_rows_i32 for b in batches]))
-
-    def pad_tail(arr, fill=0):
-        if not pad or arr is None:
-            return arr
-        tail_shape = (pad,) + arr.shape[1:]
-        return jnp.concatenate(
-            [arr, jnp.full(tail_shape, fill, arr.dtype)])
-
-    out_cols = []
-    for (data, validity, lengths, narrow), f in zip(
-            _stack_columns(batches, schema), schema.fields):
-        out_cols.append(ColumnVector(
-            f.dtype, pad_tail(data), pad_tail(validity, False),
-            pad_tail(lengths), pad_tail(narrow)))
-    return ColumnarBatch(schema, out_cols, total, checks, sparse=mask)
-
-
-def _concat_lazy(batches, schema, checks):
-    """Sync-free concat: output row i maps to input batch
-    j = #(cumulative counts <= i) at local row i - start_j; all index
-    math runs on device against the (small) per-batch count vector.
-
-    Tree-chunked past 64 inputs: the bucket-id search materializes a
-    [out_cap, B] compare matrix, which at B=400 inputs of a 26M-row
-    reduce partition reached a 12.8GB intermediate and OOMed HBM at
-    compile time — chunks bound the matrix and recurse on the (few)
-    chunk results."""
-    if len(batches) > 64:
-        chunks = [concat_batches(batches[i:i + 64])
-                  for i in range(0, len(batches), 64)]
-        return concat_batches(chunks)
-    ns = jnp.stack([b.num_rows_i32 for b in batches])
-    cum = jnp.cumsum(ns)
-    starts = cum - ns
-    total = cum[-1]
-    cap_offsets = np.concatenate(
-        [[0], np.cumsum([b.capacity for b in batches])[:-1]])
-    cap = bucket_capacity(int(sum(b.capacity for b in batches)))
-    out_cols = _stack_columns(batches, schema)
-    i = jnp.arange(cap, dtype=jnp.int32)
-    bid = (i[:, None] >= cum[None, :]).sum(axis=1)  # cap x B compares
-    bid_c = jnp.minimum(bid, len(batches) - 1)
-    local = i - jnp.take(starts, bid_c)
-    jidx = jnp.take(jnp.asarray(cap_offsets, jnp.int32), bid_c) + local
-    valid = i < total
-    jidx = jnp.where(valid, jidx, 0)
-    cols = []
-    for (data, validity, lengths, narrow), f in zip(out_cols, schema.fields):
-        cols.append(ColumnVector(
-            f.dtype,
-            jnp.take(data, jidx, axis=0, mode="clip"),
-            jnp.take(validity, jidx, mode="clip") & valid,
-            None if lengths is None else jnp.take(lengths, jidx, mode="clip"),
-            None if narrow is None else jnp.take(narrow, jidx, mode="clip")))
-    return ColumnarBatch(schema, cols, total, checks)
+    cols, mask = _program(_concat_sparse, static_argnames=("pad",))(
+        [b.columns for b in batches], masks,
+        pad=bucket_capacity(scap) - scap)
+    return ColumnarBatch(schema, cols, total, checks, sparse=mask)

@@ -908,13 +908,17 @@ DICT_GROUPBY_ENABLED = conf(
     "spark.rapids.tpu.dictGroupby.enabled", True,
     "Planner-automatic sort-free grouped aggregation via the fused "
     "Pallas one-hot kernel when a single integral group key's runtime "
-    "range fits dictGroupby.maxGroups (Sum/Count/Average over floats, "
-    "Count over anything). The whole batch runs as ONE dispatch (window "
-    "slots + grouped sum + finalize); a first-batch probe sizes the "
-    "dictionary and per-batch overflow counts trigger fallback to the "
-    "sort path. Float Sum/Average additionally require "
-    "variableFloatAgg.enabled: sums accumulate in f32, a "
-    "variableFloatAgg-class tolerance. Count-only plans are exact.")
+    "range fits dictGroupby.maxGroups (Sum/Count/Average over FLOAT32 "
+    "or integral inputs, Count over anything). The whole batch runs as "
+    "ONE dispatch (window slots + grouped sum + finalize); a "
+    "first-batch probe sizes the dictionary and per-batch overflow "
+    "counts trigger fallback to the sort path. The switch chooses a "
+    "lane and never a precision: the kernel accumulates in f32, so a "
+    "Sum/Average of a FLOAT64 input never takes it (it sums in "
+    "float64 on the sort-segment lane whatever this says); FLOAT32 "
+    "Sum/Average additionally require variableFloatAgg.enabled (the "
+    "order of the additions varies); integral measures are "
+    "exact-or-deopt; Count-only plans are exact.")
 DICT_GROUPBY_MAX_GROUPS = conf(
     "spark.rapids.tpu.dictGroupby.maxGroups", 32768,
     "Max runtime key range for the dictionary group-by fast path. The "
@@ -929,10 +933,15 @@ BANDED_GROUPBY_ENABLED = conf(
     "per-block one-hot local tables merged by one small matmul, no "
     "serialized scatters, no positions/segmented-scan machinery — and "
     "group count is UNBOUNDED (no dictGroupby range budget). "
-    "Accumulation is f32: integral measures are exact-or-deopt via the "
-    "sum(|v|) certificate, float measures additionally require "
-    "variableFloatAgg.enabled. Group keys of any sortable type are "
-    "recovered through first-row-index limb measures + one gather.")
+    "The switch chooses a lane and never a precision: accumulation is "
+    "f32, so a Sum/Average whose input (update phase) or intermediate "
+    "(merge phase; an Average's always) is FLOAT64 never takes the "
+    "lane and sums in float64 on the sort-segment lane whatever this "
+    "says; integral measures are exact-or-deopt via the sum(|v|) "
+    "certificate, FLOAT32 measures additionally require "
+    "variableFloatAgg.enabled (the order of the additions varies). "
+    "Group keys of any sortable type are recovered through "
+    "first-row-index limb measures + one gather.")
 HASH_GROUPING_ENABLED = conf(
     "spark.rapids.tpu.hashGrouping.enabled", True,
     "Wide grouping key sets (aggregate GROUP BY, window PARTITION BY) "

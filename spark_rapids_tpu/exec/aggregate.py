@@ -23,7 +23,8 @@ import jax
 import jax.numpy as jnp
 
 from spark_rapids_tpu import types as T
-from spark_rapids_tpu.columnar.batch import ColumnarBatch, concat_batches
+from spark_rapids_tpu.columnar.batch import (
+    ColumnarBatch, concat_batches, programs_of)
 from spark_rapids_tpu.columnar.vector import (ColumnVector,
                                               bucket_capacity)
 from spark_rapids_tpu.exec.base import (
@@ -137,6 +138,9 @@ class HashAggregateExec(UnaryExecBase):
         # per-key pads for the composite multi-key path), sized from a
         # one-time first-batch range probe (None until probed)
         self._dict_gpad: Optional[object] = None
+        #: the lane the last batch took: "dict", "banded",
+        #: "sort-segment" or "reduce" (the group-by spans' `lane`)
+        self._lane: Optional[str] = None
 
     def output_schema(self) -> T.Schema:
         return self._schema
@@ -243,16 +247,23 @@ class HashAggregateExec(UnaryExecBase):
     #: f32-exact group counts need < 2^24 anyway
     BANDED_MAX_CAP = 1 << 22
 
-    def _banded_float_measures(self, phase: str) -> bool:
-        """True when this exec+phase would put FLOATING values through
-        the f32 banded accumulator (needs the variableFloatAgg
-        tolerance; integral measures ride the exact-or-deopt
-        certificate instead)."""
+    def _measure_types(self, phase: str) -> list:
+        """The type each Sum / Average accumulates from in this phase:
+        its input's in the update phase, its sum intermediate's in the
+        merge phase (an Average's is FLOAT64 whatever it averages)."""
+        sums = [i for i, f in enumerate(self._funcs)
+                if type(f).__name__ in ("Sum", "Average")]
         if phase == "merge":
-            return any(t.is_floating for ts in self._inter_types
-                       for t in ts)
-        return any(e.data_type(self._child_schema).is_floating
-                   for bins in self._bound_inputs for e in bins)
+            return [self._inter_types[i][0] for i in sums]
+        return [self._bound_inputs[i][0].data_type(self._child_schema)
+                for i in sums]
+
+    def _banded_float_measures(self, phase: str) -> bool:
+        """True when this exec+phase would put FLOAT32 values through
+        the f32 banded accumulator (needs the variableFloatAgg
+        tolerance: the order of the additions varies; integral
+        measures ride the exact-or-deopt certificate instead)."""
+        return any(t.is_floating for t in self._measure_types(phase))
 
     def _use_banded(self, batch: ColumnarBatch, phase: str) -> bool:
         if not self._banded_qual or \
@@ -261,6 +272,13 @@ class HashAggregateExec(UnaryExecBase):
         if CK.is_retrying():
             # the deopt retry must be guaranteed-valid; certificate
             # lanes cannot be the last resort
+            return False
+        if any(t == T.FLOAT64 for t in self._measure_types(phase)):
+            # the lane accumulates in float32 on the MXU: a FLOAT64
+            # measure takes the sort-segment lane, which sums in
+            # float64, whatever the lane switches say (SQL's answer is
+            # not a switch; variableFloatAgg licenses an order, not a
+            # precision)
             return False
         from spark_rapids_tpu import config as C
         conf = C.get_active_conf()
@@ -304,6 +322,7 @@ class HashAggregateExec(UnaryExecBase):
         escalate-and-retry contract as _compact_groups)."""
         use_hash = self._use_hash_grouping(batch)
         use_banded = self._use_banded(batch, phase)
+        self._lane = "banded" if use_banded else "sort-segment"
         key = ("agg", phase, use_hash, use_banded, wcap,
                batch_signature(batch))
         kp_members = (self._pre_stage.member_names()
@@ -595,9 +614,10 @@ class HashAggregateExec(UnaryExecBase):
     def _dict_plan(self):
         """Static qualification for the sort-free dictionary path:
         1..3 integral keys (multi-key folds into one composite slot id),
-        Sum/Count/Average over float inputs (variableFloatAgg-gated f32
-        accumulation) or INTEGRAL inputs (exact-or-deopt: an in-kernel
-        f32-exactness certificate, no conf gate).
+        Sum/Count/Average over FLOAT32 inputs (variableFloatAgg-gated
+        f32 accumulation; never FLOAT64) or INTEGRAL inputs
+        (exact-or-deopt: an in-kernel f32-exactness certificate, no
+        conf gate).
         Returns (plan, measures) or None."""
         if self.mode == AggMode.FINAL or \
                 not 1 <= len(self._bound_groups) <= 3:
@@ -616,6 +636,11 @@ class HashAggregateExec(UnaryExecBase):
                     plan.append(("count_star", None))
             elif name in ("Sum", "Average"):
                 dt = bins[0].data_type(self._child_schema)
+                if dt == T.FLOAT64:
+                    # the kernel accumulates in float32: a FLOAT64
+                    # measure never qualifies (it sums in float64 on
+                    # the sort-segment lane), whatever the switches say
+                    return None
                 if dt.is_floating:
                     self._dict_float = True
                     plan.append((name.lower(), len(measures)))
@@ -744,6 +769,7 @@ class HashAggregateExec(UnaryExecBase):
         check = CK.register(CK.BatchCheck(
             excess, f"dictGroupby[exec {self.exec_id}]",
             self._disable_dict_path))
+        self._lane = "dict"
         return ColumnarBatch(self._partial_schema(), list(cols), n,
                              batch.checks + (check,))
 
@@ -1137,47 +1163,67 @@ class HashAggregateExec(UnaryExecBase):
             partials = []
             pending_bytes = 0
 
-        for batch in batches:
-            if not batch.maybe_nonempty():
-                continue
-            with self.metrics.timed(M.TOTAL_TIME):
-                # per-batch grouping is row-local, so halves from a
-                # split-and-retry simply land as extra partials for the
-                # merge below (this phase is a known OOM hotspot)
-                pieces = list(self.oom_retry_batches(
-                    batch, self._groupby_one,
-                    label=f"{self.name()}.groupBatch"))
-            partials.extend(pieces)
-            pending_bytes += sum(R.estimate_batch_bytes(p)
-                                 for p in pieces)
-            if not external and OC.should_go_external(pending_bytes,
-                                                      conf):
-                external = True
-                P.event(P.EV_OOCORE_DEGRADE, op=self.name(),
-                        est_bytes=pending_bytes, algo="agg-spill")
-            if external and pending_bytes > run_target:
-                flush_state()
+        with P.span(P.SPAN_GROUPBY_UPDATE) as sp:
+            n_in = rows_in = 0
+            for batch in batches:
+                if not batch.maybe_nonempty():
+                    continue
+                if sp is not None:
+                    n_in += 1
+                    rows_in += P.known_rows([batch])
+                with self.metrics.timed(M.TOTAL_TIME):
+                    # per-batch grouping is row-local, so halves from a
+                    # split-and-retry simply land as extra partials for
+                    # the merge below (this phase is a known OOM hotspot)
+                    pieces = list(self.oom_retry_batches(
+                        batch, self._groupby_one,
+                        label=f"{self.name()}.groupBatch"))
+                partials.extend(pieces)
+                pending_bytes += sum(R.estimate_batch_bytes(p)
+                                     for p in pieces)
+                if not external and OC.should_go_external(pending_bytes,
+                                                          conf):
+                    external = True
+                    P.event(P.EV_OOCORE_DEGRADE, op=self.name(),
+                            est_bytes=pending_bytes, algo="agg-spill")
+                if external and pending_bytes > run_target:
+                    flush_state()
+            if sp is not None:
+                sp.args = {
+                    "lane": self._lane, "batches": n_in, "rows_in": rows_in,
+                    "phase": "merge" if self.mode == AggMode.FINAL
+                    else "update"}
 
         if not partials and not runs:
             return
-        if runs:
-            flush_state()
-            merged = self._merge_spilled_state(runs, inter_fields, conf)
-        else:
-            # concat + re-merge loop until one batch of groups remains
-            merged = partials[0] if len(partials) == 1 else \
-                self._merge_partials(partials, inter_fields)
+        with P.span(P.SPAN_GROUPBY_MERGE) as sp:
+            if runs:
+                flush_state()
+                merged = self._merge_spilled_state(runs, inter_fields,
+                                                   conf)
+            else:
+                # concat + re-merge loop until one batch of groups
+                # remains
+                merged = partials[0] if len(partials) == 1 else \
+                    self._merge_partials(partials, inter_fields)
 
-        if self.mode == AggMode.PARTIAL:
-            out = merged
-        else:
-            with self.metrics.timed(M.TOTAL_TIME):
-                # the final projection reads one merged group batch —
-                # no input to subdivide, so pressure spills + retries
-                # in place (no-split lane)
-                (out,) = tuple(self.oom_retry_batches(
-                    merged, self._evaluate_one, split=False,
-                    label=f"{self.name()}.evaluate"))
+            if self.mode == AggMode.PARTIAL:
+                out = merged
+            else:
+                with self.metrics.timed(M.TOTAL_TIME):
+                    # the final projection reads one merged group batch
+                    # — no input to subdivide, so pressure spills +
+                    # retries in place (no-split lane)
+                    (out,) = tuple(self.oom_retry_batches(
+                        merged, self._evaluate_one, split=False,
+                        label=f"{self.name()}.evaluate"))
+            if sp is not None:
+                me = getattr(self, "_merge_exec", None)
+                sp.args = {"lane": me._lane if me is not None else None,
+                           "partials": len(partials),
+                           # None: the count is still on the device
+                           "groups": out._rows if out.num_rows_known
+                           else None}
         if out.num_rows_known:
             out = out.with_capacity(bucket_capacity(out.num_rows))
         self.update_output_metrics(out)
@@ -1296,7 +1342,8 @@ class HashAggregateExec(UnaryExecBase):
     def _merge_partials(self, partials, inter_schema) -> ColumnarBatch:
         # sparse_ok: the merge kernel takes a deferred-selection mask,
         # so the concat can stay gather-free
-        merged = concat_batches(partials, sparse_ok=True)
+        with programs_of("agg"):
+            merged = concat_batches(partials, sparse_ok=True)
         merge_exec = self._get_merge_exec(inter_schema)
         # the merge phase is the aggregate's known OOM hotspot: under
         # reservation failure the concatenated partials split in half
@@ -1322,7 +1369,8 @@ class HashAggregateExec(UnaryExecBase):
                 "(%d rows -> %d across %d outputs); merging unreserved",
                 self.name(), merged.num_rows,
                 sum(o.num_rows for o in outs), len(outs))
-            whole = concat_batches(outs, sparse_ok=True)
+            with programs_of("agg"):
+                whole = concat_batches(outs, sparse_ok=True)
             return self._merge_one(merge_exec, whole, inter_schema)
         return self._merge_partials(outs, inter_schema)
 
@@ -1372,6 +1420,10 @@ class HashAggregateExec(UnaryExecBase):
             self._charge_pre_stage(t0)
             return ColumnarBatch(inter_schema, list(cols), 1, b.checks)
 
+        # no group-by span here: there are no groups, and every span a
+        # trace reader keeps costs its reduction (q6's 13 kept spans a
+        # query became 21 with them: +29 / +36 s of a traced run's wall)
+        self._lane = "reduce"
         for batch in batches:
             with self.metrics.timed(M.TOTAL_TIME):
                 # whole-batch reductions are row-local too: split halves

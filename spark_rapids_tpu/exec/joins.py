@@ -31,7 +31,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from spark_rapids_tpu import types as T
-from spark_rapids_tpu.columnar.batch import ColumnarBatch, concat_batches
+from spark_rapids_tpu.columnar.batch import (
+    ColumnarBatch, concat_batches, programs_of)
 from spark_rapids_tpu.columnar.vector import ColumnVector, bucket_capacity
 from spark_rapids_tpu.exec.base import (
     KernelCache, RequireSingleBatch, TpuExec, batch_signature,
@@ -59,6 +60,22 @@ class JoinType(enum.Enum):
 
 
 _PROBE_ONLY = (JoinType.LEFT_SEMI, JoinType.LEFT_ANTI)
+
+
+#: what an `exec:join-probe` span counts, from zero (`capacity_rows`:
+#: the sum of the match and expand kernels' capacities; `expand_syncs`:
+#: the blocking `join.expand` readbacks)
+_PROBE_COUNTS = dict(probe_batches=0, rows_in=0, rows_out=0,
+                     capacity_rows=0, expand_syncs=0)
+
+
+def _owned_dense(b: ColumnarBatch) -> ColumnarBatch:
+    """`b.dense()` with its compaction named for the join on the device
+    (`jit_join_dense`)."""
+    if b.sparse is None:
+        return b
+    with programs_of("join"):
+        return b.dense()
 
 
 class HashJoinExec(TpuExec):
@@ -161,9 +178,17 @@ class HashJoinExec(TpuExec):
                             T.common_type(b.dtype, p.dtype)
                         from spark_rapids_tpu.exprs.base import promote
                         b, p = promote(b, dt), promote(p, dt)
+                        # both sides' int32 shadows ride along: the key
+                        # then sorts as one 32-bit word, not a 64-bit
+                        # one, which is more than half of this kernel's
+                        # compile for the chip (and of its sort network)
+                        narrow = (jnp.concatenate([b.narrow, p.narrow])
+                                  if b.narrow is not None
+                                  and p.narrow is not None else None)
                         comb.append(ColumnVector(
                             dt, jnp.concatenate([b.data, p.data]),
-                            jnp.concatenate([b.validity, p.validity])))
+                            jnp.concatenate([b.validity, p.validity]),
+                            None, narrow))
                 side = jnp.concatenate([jnp.zeros(bcap, jnp.uint8),
                                         jnp.ones(pcap, jnp.uint8)])
                 row_mask = jnp.concatenate([bctx.row_mask, pctx.row_mask])
@@ -515,18 +540,30 @@ class HashJoinExec(TpuExec):
                                                  pb.sparse, pb.checks,
                                                  rows=pb._rows)
 
-        for it in self._probe.execute_partitions():
-            for pb in it:
-                if not pb.maybe_nonempty():
-                    continue
-                # probe rows are independent given a fixed build table,
-                # so the probe side is fully split-and-retry-able
-                for out in self.oom_retry_batches(
-                        pb, probe_one,
-                        label=f"{self.name()}.denseProbe"):
-                    if out.maybe_nonempty():
-                        self.update_output_metrics(out)
-                        yield out
+        from spark_rapids_tpu.utils import profile as P
+        ph = P.phase(P.SPAN_JOIN_PROBE, lane="dense", **_PROBE_COUNTS)
+        try:
+            for it in self._probe.execute_partitions():
+                for pb in it:
+                    if not pb.maybe_nonempty():
+                        continue
+                    if ph is not None:
+                        ph.add(probe_batches=1, rows_in=P.known_rows([pb]),
+                               capacity_rows=pb.capacity)
+                    # probe rows are independent given a fixed build
+                    # table, so the probe side is fully
+                    # split-and-retry-able
+                    for out in self.oom_retry_batches(
+                            pb, probe_one,
+                            label=f"{self.name()}.denseProbe"):
+                        if out.maybe_nonempty():
+                            if ph is not None:
+                                ph.add(rows_out=P.known_rows([out]))
+                            self.update_output_metrics(out)
+                            yield out
+        finally:
+            if ph is not None:
+                ph.close()
 
     def _assemble_sparse(self, pcols, bcols, sparse, checks, rows=None):
         if self._flip:
@@ -545,7 +582,7 @@ class HashJoinExec(TpuExec):
             [RequireSingleBatch(), None]
 
     def _collect_build_batches(self) -> list[ColumnarBatch]:
-        return [b.dense() for it in self._build.execute_partitions()
+        return [_owned_dense(b) for it in self._build.execute_partitions()
                 for b in it if b.maybe_nonempty()]
 
     def _concat_build(self, batches: list[ColumnarBatch]) -> ColumnarBatch:
@@ -559,9 +596,10 @@ class HashJoinExec(TpuExec):
         # so pressure here spills + retries in place — no split
         from spark_rapids_tpu.memory import retry as R
         nbytes = 2 * sum(b.device_size_bytes() for b in batches)
-        return R.with_retry(lambda: concat_batches(batches),
-                            out_bytes=nbytes, metrics=self.metrics,
-                            label=f"{self.name()}.buildSide")
+        return R.with_retry(
+            programs_of("join")(lambda: concat_batches(batches)),
+            out_bytes=nbytes, metrics=self.metrics,
+            label=f"{self.name()}.buildSide")
 
     def _build_batch(self) -> ColumnarBatch:
         return self._concat_build(self._collect_build_batches())
@@ -584,22 +622,27 @@ class HashJoinExec(TpuExec):
     def execute_columnar(self) -> Iterator[ColumnarBatch]:
         from spark_rapids_tpu import config as C
         from spark_rapids_tpu.memory import oocore as OC
-        batches = self._grace_candidate_batches()
-        if batches is not None:
-            conf = C.get_active_conf()
-            est = 2 * sum(b.device_size_bytes() for b in batches)
-            if OC.should_go_external(est, conf):
-                from spark_rapids_tpu.utils import profile as P
-                P.event(P.EV_OOCORE_DEGRADE, op=self.name(),
-                        est_bytes=est, algo="grace-hash")
-                probe_src = (pb for it in self._probe.execute_partitions()
-                             for pb in it if pb.maybe_nonempty())
-                yield from self._grace_join(iter(batches), probe_src,
-                                            0, conf)
-                return
-            build = self._concat_build(batches)
-        else:
-            build = self._build_batch()
+        from spark_rapids_tpu.utils import profile as P
+        with P.span(P.SPAN_JOIN_BUILD) as sp:
+            batches = self._grace_candidate_batches()
+            if batches is None:
+                build = self._build_batch()
+            else:
+                conf = C.get_active_conf()
+                est = 2 * sum(b.device_size_bytes() for b in batches)
+                build = None if OC.should_go_external(est, conf) \
+                    else self._concat_build(batches)
+            if sp is not None and build is not None:
+                sp.args = {"rows": P.known_rows([build]),
+                           "capacity_rows": build.capacity}
+        if build is None:
+            P.event(P.EV_OOCORE_DEGRADE, op=self.name(),
+                    est_bytes=est, algo="grace-hash")
+            probe_src = (pb for it in self._probe.execute_partitions()
+                         for pb in it if pb.maybe_nonempty())
+            yield from self._grace_join(iter(batches), probe_src,
+                                        0, conf)
+            return
         if self._dense_qual:
             tab = self._try_dense_table(build)
             if tab is not None:
@@ -620,9 +663,15 @@ class HashJoinExec(TpuExec):
         outer_probe = jt in (JoinType.LEFT_OUTER, JoinType.RIGHT_OUTER,
                              JoinType.FULL_OUTER)
         bmatched_total = np.zeros(build.capacity, bool)
+        from spark_rapids_tpu.utils import profile as P
+        ph = P.phase(P.SPAN_JOIN_PROBE, lane="sort", **_PROBE_COUNTS)
 
         def probe_one(pb: ColumnarBatch) -> ColumnarBatch:
-            pb = pb.dense()
+            pb = _owned_dense(pb)
+            if ph is not None:
+                ph.add(probe_batches=1, rows_in=P.known_rows([pb]),
+                       capacity_rows=build.capacity + pb.capacity,
+                       expand_syncs=1)
             with self.metrics.timed(M.TOTAL_TIME):
                 mk = self._match_kernel(build, pb)
                 counts_p, start_p, perm, bmatched, total_inner = mk(
@@ -648,6 +697,8 @@ class HashJoinExec(TpuExec):
                 if outer_probe:
                     total = total + pb.num_rows  # upper bound
                 out_cap = bucket_capacity(max(total, 1))
+                if ph is not None:
+                    ph.add(capacity_rows=out_cap)
                 ek = self._expand_kernel(build, pb, out_cap, outer_probe)
                 pout, bout, tot = ek(build.columns, pb.columns,
                                      counts_p, start_p, perm,
@@ -657,23 +708,31 @@ class HashJoinExec(TpuExec):
                     out = self._apply_condition(out)
                 return out
 
-        for pb in probe_batches:
-            if not pb.maybe_nonempty():
-                continue
-            # probe rows are independent given the fixed build side
-            # (FULL_OUTER's unmatched-build flags OR across pieces),
-            # so probe batches split-and-retry freely while the pair
-            # expansion's out_cap shrinks with each piece
-            for out in self.oom_retry_batches(
-                    pb, probe_one, label=f"{self.name()}.probe"):
-                if out.num_rows > 0:
-                    self.update_output_metrics(out)
-                    yield out
-        if jt == JoinType.FULL_OUTER:
-            un = self._unmatched_build(build, bmatched_total)
-            if un is not None and un.num_rows > 0:
-                self.update_output_metrics(un)
-                yield un
+        try:
+            for pb in probe_batches:
+                if not pb.maybe_nonempty():
+                    continue
+                # probe rows are independent given the fixed build side
+                # (FULL_OUTER's unmatched-build flags OR across pieces),
+                # so probe batches split-and-retry freely while the pair
+                # expansion's out_cap shrinks with each piece
+                for out in self.oom_retry_batches(
+                        pb, probe_one, label=f"{self.name()}.probe"):
+                    if out.num_rows > 0:
+                        if ph is not None:
+                            ph.add(rows_out=out.num_rows)
+                        self.update_output_metrics(out)
+                        yield out
+            if jt == JoinType.FULL_OUTER:
+                un = self._unmatched_build(build, bmatched_total)
+                if un is not None and un.num_rows > 0:
+                    if ph is not None:
+                        ph.add(rows_out=un.num_rows)
+                    self.update_output_metrics(un)
+                    yield un
+        finally:
+            if ph is not None:
+                ph.close()
 
     # -- grace-hash out-of-core lane ---------------------------------------
     #: base seed for grace partition hashing — deliberately NOT Spark's

@@ -18,7 +18,8 @@ import threading
 from typing import Iterator, Optional
 
 from spark_rapids_tpu import types as T
-from spark_rapids_tpu.columnar.batch import ColumnarBatch, concat_batches
+from spark_rapids_tpu.columnar.batch import (
+    ColumnarBatch, concat_batches, programs_of)
 from spark_rapids_tpu.exec.base import TpuExec, UnaryExecBase
 from spark_rapids_tpu.shuffle.partitioning import (
     RangePartitioning, TpuPartitioning)
@@ -225,7 +226,15 @@ class ShuffleExchangeExec(UnaryExecBase):
         if C.get_active_conf()[C.RAPIDS_SHUFFLE_ENABLED]:
             return self._execute_via_manager()
         from spark_rapids_tpu.exec.pipeline import maybe_prefetch
-        buckets = self._materialize()
+        with P.span(P.SPAN_EXCHANGE_WRITE) as sp:
+            buckets = self._materialize()
+            if sp is not None:
+                slices = [s for bs in buckets for s in bs]
+                sp.args = {
+                    "partitions": len(buckets), "slices": len(slices),
+                    "rows": P.known_rows(slices),
+                    "capacity_rows": sum(s.capacity for s in slices),
+                    "bytes": sum(s.device_size_bytes() for s in slices)}
         # reduce side of the exchange pipeline break: each partition's
         # merge/consolidation dispatches run ahead of its consumer
         return [maybe_prefetch(self._merged_reader(bs),
@@ -253,7 +262,16 @@ class ShuffleExchangeExec(UnaryExecBase):
             int(C.get_active_conf()[C.MAX_BATCH_ROWS])))
         group: list[ColumnarBatch] = []
         cap_sum = 0
+        ph = P.phase(P.SPAN_EXCHANGE_READ, slices=len(bs), batches=0,
+                     rows=0, capacity_rows=0)
 
+        def done(m: ColumnarBatch) -> ColumnarBatch:
+            if ph is not None:
+                ph.add(batches=1, rows=P.known_rows([m]),
+                       capacity_rows=m.capacity)
+            return m
+
+        @programs_of("exchange")    # the consolidation's device programs
         def flush():
             if len(group) == 1:
                 m = group[0]
@@ -293,14 +311,18 @@ class ShuffleExchangeExec(UnaryExecBase):
             self.metrics.add(M.NUM_OUTPUT_BATCHES, 1)
             return m
 
-        for b in bs:
-            if group and cap_sum + b.capacity > target_cap:
-                yield flush()
-                group, cap_sum = [], 0
-            group.append(b)
-            cap_sum += b.capacity
-        if group:
-            yield flush()
+        try:
+            for b in bs:
+                if group and cap_sum + b.capacity > target_cap:
+                    yield done(flush())
+                    group, cap_sum = [], 0
+                group.append(b)
+                cap_sum += b.capacity
+            if group:
+                yield done(flush())
+        finally:
+            if ph is not None:
+                ph.close()
 
     def _mesh_routable(self):
         """The accelerated ICI lane applies when: the conf enables it, a

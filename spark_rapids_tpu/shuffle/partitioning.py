@@ -167,12 +167,14 @@ def _slice_partitions(batch_cols, counts, schema: T.Schema,
     offsets = np.concatenate([[0], np.cumsum(counts)])
     reordered = ColumnarBatch(schema, list(batch_cols), int(offsets[-1]),
                               checks)
-    for p in range(len(counts)):
-        n = int(counts[p])
-        if n == 0:
-            out.append(None)
-            continue
-        out.append(reordered.slice(int(offsets[p]), n))
+    from spark_rapids_tpu.columnar.batch import programs_of
+    with programs_of("exchange"):       # jit_exchange_slice
+        for p in range(len(counts)):
+            n = int(counts[p])
+            if n == 0:
+                out.append(None)
+                continue
+            out.append(reordered.slice(int(offsets[p]), n))
     return out
 
 

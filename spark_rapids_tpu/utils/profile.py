@@ -74,6 +74,14 @@ SPAN_SOURCE_UPLOAD = "SourceUpload"       # exec:SourceUpload[s<k>]
 SPAN_UPLOAD_CONVERT = "upload-convert"    # per partition: pandas -> numpy
 SPAN_UPLOAD_PUT = "upload-put"            # per partition: pad + device_put
 SPAN_READBACK = "Readback"                # the device-to-host half
+#: one span per exec, partition and phase (never per batch): what a
+#: trace reader splits a join / exchange / group-by query's host time by
+SPAN_JOIN_BUILD = "join-build"            # build side drained + concatenated
+SPAN_JOIN_PROBE = "join-probe"            # match + expand over the stream
+SPAN_EXCHANGE_WRITE = "exchange-write"    # map side: split + cut
+SPAN_EXCHANGE_READ = "exchange-read"      # one reduce partition's slices
+SPAN_GROUPBY_UPDATE = "groupby-update"    # every input batch grouped
+SPAN_GROUPBY_MERGE = "groupby-merge"      # partials merged + evaluated
 
 #: ring-buffer bounds — big enough for a deep TPC-DS plan's batch spans,
 #: small enough that a runaway loop cannot eat the heap
@@ -385,6 +393,55 @@ def span(name: str, cat: str = CAT_EXEC, **args):
     if tr is None:
         return _NULL_SPAN
     return _SpanCtx(tr, name, cat, args or None)
+
+
+class PhaseSpan:
+    """A span a generator holds open across its yields (a join's probe
+    stream, an exchange's reader): one interval from the phase's first
+    work to its last, whatever ran in between (the child's pulls and
+    the consumer's work between two yields are inside it).  It is not
+    installed as the thread's innermost span, so it parents nothing and
+    may outlive spans opened after it.  Its numeric args are counters:
+    `add(rows_in=n)` adds to what `phase(..., rows_in=0)` started.
+    Closing twice is closing once."""
+
+    __slots__ = ("_tr", "_span", "_ann")
+
+    def __init__(self, tr: QueryTracer, name: str, args: dict):
+        self._tr = tr
+        self._span = tr.open_span(name, CAT_EXEC, _tls_ctx(tr), args)
+        from spark_rapids_tpu.utils.tracing import annotation
+        self._ann = annotation(f"{CAT_EXEC}:{name}")
+        self._ann.__enter__()
+
+    def add(self, **counts) -> None:
+        args = self._span.args
+        for k, v in counts.items():
+            args[k] += v
+
+    def close(self) -> None:
+        s, self._span = self._span, None
+        if s is None:
+            return
+        try:
+            self._ann.__exit__(None, None, None)
+        finally:
+            self._tr.close_span(s)
+
+
+def phase(name: str, **args) -> Optional[PhaseSpan]:
+    """Open a `PhaseSpan`, or None when this thread's query is not
+    being profiled (call sites guard `if ph is not None`)."""
+    tr = tracer()
+    if tr is None:
+        return None
+    return PhaseSpan(tr, name, args)
+
+
+def known_rows(batches) -> int:
+    """Rows of the batches whose count is on the host already: a span's
+    args never make a device-to-host read of their own."""
+    return sum(b._rows for b in batches if b.num_rows_known)
 
 
 def event(kind: str, **fields) -> None:

@@ -160,8 +160,9 @@ def planner_kernels():
     return rec.calls
 
 
-def _largest(calls, tag):
-    """The recorded call of kernel family `tag` with the most rows."""
+def _largest(calls, tag, smallest=False):
+    """The recorded call of kernel family `tag` with the most rows (or
+    the fewest: a sort network's compile time grows with its rows)."""
     def rows(call):
         leaves = [l for l in jax.tree_util.tree_leaves(call[2:])
                   if isinstance(l, jax.ShapeDtypeStruct) and l.shape]
@@ -169,7 +170,7 @@ def _largest(calls, tag):
     hits = [c for c in calls if tag in repr(c[0]) or
             tag in getattr(c[1], "__qualname__", "")]
     assert hits, f"the planner run built no {tag!r} kernel"
-    best = max(hits, key=rows)
+    best = (min if smallest else max)(hits, key=rows)
     return best, rows(best)
 
 
@@ -179,11 +180,19 @@ def _largest(calls, tag):
     ("_split_kernel_for", CAP),   # q3: exchange split of lineitem
     ("_build_dense_probe", CAP),  # q3: join probe
     ("SortExec._kernel", 1),      # q3: TopN
+    # q3's sort-path join, the dearest compile of a cold q3 on the chip
+    # (PERF.md, PR 29: 83 s + 75 s of 248 s at SF0.25): its match (key
+    # words + iota in one sort; the smaller of q3's two, since the
+    # compile time grows with the rows: 133 s here at 262,144) and its
+    # pair expansion
+    ("_match_kernel", -(1 << 14)),
+    ("_expand_kernel", CAP),
 ])
 def test_planner_kernel_compiles_for_v5e(one_chip, planner_kernels, tag,
                                          min_rows):
-    (key, fn, args, kwargs), rows = _largest(planner_kernels, tag)
-    assert rows >= min_rows, (tag, rows)
+    (key, fn, args, kwargs), rows = _largest(planner_kernels, tag,
+                                             smallest=min_rows < 0)
+    assert rows >= abs(min_rows), (tag, rows)
 
     def place(x):
         if isinstance(x, jax.ShapeDtypeStruct):

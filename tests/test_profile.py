@@ -733,3 +733,178 @@ def test_a_fused_stage_names_its_members_and_no_shape():
     name = EB.kernel_name(stage.kernel_label)
     assert name.startswith("fused_40_project_") and \
         len(name) == EB.KERNEL_NAME_MAX
+
+
+# -- one span per exec, partition and phase: join, exchange, group-by --------
+Q3_SCALE = 20_000
+PHASES = (P.SPAN_JOIN_BUILD, P.SPAN_JOIN_PROBE, P.SPAN_EXCHANGE_WRITE,
+          P.SPAN_EXCHANGE_READ, P.SPAN_GROUPBY_UPDATE, P.SPAN_GROUPBY_MERGE)
+
+
+def _q3_tables(seed):
+    from benchmark.gen import tpch
+    return tpch.generate(seed, Q3_SCALE,
+                         ["customer", "orders", "lineitem"])
+
+
+def _q3(tables, conf):
+    from spark_rapids_tpu.models.tpch_data import sources
+    from spark_rapids_tpu.models.tpch_queries import QUERIES
+    from spark_rapids_tpu.plan.overrides import accelerate, collect
+    plan = accelerate(QUERIES[3](sources(tables, 2), None), conf)
+    return plan, collect(plan, conf)
+
+
+def _nodes(plan, suffix):
+    todo, out = [plan], []
+    while todo:
+        node = todo.pop()
+        out += [node] if type(node).__name__.endswith(suffix) else []
+        todo += node.children
+    return out
+
+
+@pytest.fixture(scope="module")
+def q3_profiled():
+    """A small q3 over two partitions under the default lanes, run once
+    unprofiled (so no span holds a compile) and once profiled."""
+    P.clear_history()
+    tables = _q3_tables(11)
+    _q3(tables, _conf(spark__rapids__sql__profile__enabled=False))
+    assert P.last_profile() is None
+    plan, answer = _q3(tables, _conf())
+    assert len(answer) == 10
+    return plan, P.last_profile()
+
+
+def test_phase_spans_exist_once_per_exec_partition_and_phase(q3_profiled):
+    plan, prof = q3_profiled
+    joins = _nodes(plan, "JoinExec")
+    exchanges = _nodes(plan, "ShuffleExchangeExec")
+    aggs = _nodes(plan, "HashAggregateExec")
+    assert len(joins) == 2 and len(exchanges) == 4 and len(aggs) == 1
+    count = {name: len(_named(prof, f"exec:{name}")) for name in PHASES}
+    assert count == {
+        P.SPAN_JOIN_BUILD: len(joins), P.SPAN_JOIN_PROBE: len(joins),
+        P.SPAN_EXCHANGE_WRITE: len(exchanges),
+        # a reader per reduce partition of every exchange
+        P.SPAN_EXCHANGE_READ: 2 * len(exchanges),
+        # the join hands the aggregate one partition
+        P.SPAN_GROUPBY_UPDATE: 1, P.SPAN_GROUPBY_MERGE: 1}
+    assert prof.dropped_spans == 0
+
+
+def test_phase_spans_lie_inside_operator_spans_in_time(q3_profiled):
+    _, prof = q3_profiled
+    import re
+    pulls = [s for s in prof.spans
+             if s.cat == P.CAT_EXEC and re.search(r"\[p\d+\]$", s.name)]
+    assert pulls
+    for name in PHASES:
+        for s in _named(prof, f"exec:{name}"):
+            assert s.dur_ns > 0
+            assert any(o.t0 <= s.t0 and
+                       s.t0 + s.dur_ns <= o.t0 + o.dur_ns for o in pulls), \
+                name
+    # a join builds before it probes, a group-by updates before it merges
+    for first, then in ((P.SPAN_JOIN_BUILD, P.SPAN_JOIN_PROBE),
+                        (P.SPAN_GROUPBY_UPDATE, P.SPAN_GROUPBY_MERGE)):
+        a = sorted(_named(prof, f"exec:{first}"), key=lambda s: s.t0)
+        b = sorted(_named(prof, f"exec:{then}"), key=lambda s: s.t0)
+        assert a[0].t0 + a[0].dur_ns <= b[-1].t0 + b[-1].dur_ns
+
+
+def test_phase_spans_carry_their_args(q3_profiled):
+    plan, prof = q3_profiled
+    probes = sorted(_named(prof, "exec:join-probe"),
+                    key=lambda s: s.t0 + s.dur_ns)
+    for s in probes:
+        assert set(s.args) == {"lane", "probe_batches", "rows_in",
+                               "rows_out", "capacity_rows", "expand_syncs"}
+        assert s.args["lane"] == "sort"
+        assert s.args["probe_batches"] == s.args["expand_syncs"] == 2
+        assert s.args["capacity_rows"] >= s.args["rows_in"] > 0
+    # the first join's rows are the second's probe rows, and the second's
+    # are the group-by's
+    assert probes[0].args["rows_out"] == probes[1].args["rows_in"]
+    (update,) = _named(prof, "exec:groupby-update")
+    (merge,) = _named(prof, "exec:groupby-merge")
+    assert update.args["rows_in"] == probes[1].args["rows_out"]
+    assert update.args["phase"] == "update"
+    # q3 sums a FLOAT64 expression: float64 on the sort-segment lane,
+    # whatever the (default-on) lane switches say
+    assert update.args["lane"] == merge.args["lane"] == "sort-segment"
+    (agg,) = _nodes(plan, "HashAggregateExec")
+    # the group count is on the host only where a sync already brought it
+    assert merge.args["groups"] in (None,
+                                    agg.metrics.value(M.NUM_OUTPUT_ROWS))
+    assert merge.args["partials"] == update.args["batches"] == 2
+    for s in _named(prof, "exec:join-build"):
+        assert s.args["capacity_rows"] >= s.args["rows"] > 0
+    writes = _named(prof, "exec:exchange-write")
+    reads = _named(prof, "exec:exchange-read")
+    assert all(s.args["partitions"] == 2 and s.args["bytes"] > 0
+               and s.args["capacity_rows"] > 0 for s in writes)
+    assert sum(s.args["slices"] for s in reads) == \
+        sum(s.args["slices"] for s in writes)
+    assert sum(s.args["rows"] for s in reads) == sum(
+        x.metrics.value(M.NUM_OUTPUT_ROWS)
+        for x in _nodes(plan, "ShuffleExchangeExec"))
+
+
+def test_profiling_off_opens_no_phase_span(monkeypatch):
+    opened = []
+    real = P.PhaseSpan.__init__
+    monkeypatch.setattr(P.PhaseSpan, "__init__",
+                        lambda self, *a, **k: (opened.append(a),
+                                               real(self, *a, **k))[1])
+    monkeypatch.setattr(P._SpanCtx, "__enter__",
+                        lambda self: opened.append(self._name))
+    _, answer = _q3(_q3_tables(11),
+                    _conf(spark__rapids__sql__profile__enabled=False))
+    assert len(answer) == 10 and not opened
+    assert P.last_profile() is None and P._ACTIVE == 0
+
+
+def test_what_a_new_seed_asks_the_compiler_for():
+    """Exact compile requests of a small q3 through accelerate() +
+    collect(): a repeat asks for nothing; another seed asks only for
+    the shapes whose data-dependent capacity bucket (a build side, a
+    join's `out_cap`, an exchange's tight cut, the group count) fell in
+    another power of two.  At 20,000 lineitem rows the counts sit near
+    their buckets' edges and move with the seed; at the benchmark's
+    1,500,000 three seeds asked for nothing new (PERF.md, PR 29).  The
+    batch helpers run as one named program each (`jit_join_concat`,
+    `jit_exchange_slice`, ...), not as chains of eager operations that
+    each compile: a cold q3 asked for 131 programs before, 48 now."""
+    import jax
+    import jax.monitoring
+    from spark_rapids_tpu.exec.base import clear_kernel_cache
+    event = "/jax/compilation_cache/compile_requests_use_cache"
+    seen = []
+
+    def listen(name, **_kw):
+        if name == event:
+            seen.append(name)
+    conf = _conf(spark__rapids__sql__profile__enabled=False)
+
+    def requests(seed):
+        tables = _q3_tables(seed)
+        before = len(seen)
+        _, answer = _q3(tables, conf)
+        assert len(answer) == 10
+        return len(seen) - before
+
+    clear_kernel_cache()
+    jax.clear_caches()
+    jax.monitoring.register_event_listener(listen)
+    try:
+        first = requests(11)
+        counts = [requests(11), requests(12), requests(13), requests(13)]
+    finally:
+        jax.monitoring.unregister_event_listener(listen)
+    # (two of a cold process's programs outlive `jax.clear_caches()`)
+    assert first in (48, 50), first
+    # seed 12 lands in seed 11's buckets; seed 13's build sides and
+    # group count do not (512 / 256 where 11 had 1024 / 128)
+    assert counts == [0, 0, 18, 0]

@@ -443,14 +443,15 @@ class TestDictFastPathDeopt:
 
         rng = np.random.default_rng(11)
         k1 = rng.integers(0, 8, 512).astype(np.int64)
-        v1 = rng.uniform(0, 10, 512)
+        # FLOAT32 values: a FLOAT64 measure never takes this lane
+        v1 = rng.uniform(0, 10, 512).astype(np.float32)
         # batch 2: window anchored at its own kmin=0 with g_pad sized
         # from batch 1 (8 -> padded) — thousands of distinct overflow
         # keys blow the inline budget
         k2 = np.concatenate([rng.integers(0, 8, 100),
                              rng.integers(10_000, 90_000, 3000)]
                             ).astype(np.int64)
-        v2 = rng.uniform(0, 10, 3100)
+        v2 = rng.uniform(0, 10, 3100).astype(np.float32)
         b1 = ColumnarBatch.from_numpy({"k": k1, "v": v1})
         b2 = ColumnarBatch.from_numpy({"k": k2, "v": v2})
         src = LocalBatchSource([[b1, b2]])
