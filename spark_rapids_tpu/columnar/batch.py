@@ -582,16 +582,43 @@ def empty_batch(schema: T.Schema) -> ColumnarBatch:
     return ColumnarBatch(schema, cols, 0)
 
 
+def rows_made_known(batches: list[ColumnarBatch], site: str,
+                    beyond: int = 0) -> int:
+    """Bring the row counts of `batches` that are still device scalars
+    to the host in ONE stacked device-to-host read, counted as one host
+    sync at `site`, and keep them on the batches (what reading each
+    `.num_rows` does, one round trip a batch).  `concat_batches` then
+    sizes its output by the rows there are.  Asks nothing when every
+    count is known, or when the lazy concat's capacity (the bucketed sum
+    of the inputs' capacities) is within `beyond`: at a barrier, padding
+    of up to one batch costs less than the question.  Returns the reads
+    made, 0 or 1."""
+    unknown = [b for b in batches if not b.num_rows_known]
+    if not unknown or bucket_capacity(
+            sum(b.capacity for b in batches)) <= beyond:
+        return 0
+    from spark_rapids_tpu.utils import checks as CK
+    CK.note_host_sync(site, nbytes=4 * len(unknown))
+    counts = np.asarray(jnp.stack([b.num_rows_i32 for b in unknown]))
+    for b, n in zip(unknown, counts.tolist()):
+        b.num_rows = n
+    return 1
+
+
 def concat_batches(batches: list[ColumnarBatch],
                    sparse_ok: bool = False) -> ColumnarBatch:
     """Device-side concat (reference `Table.concatenate`,
     `GpuCoalesceBatches.scala:53`): stack padded columns then gather the
     valid rows of each input into a fresh bucketed batch.
 
-    When any input's row count is still a device scalar, the gather
-    indices are computed DEVICE-SIDE (no sync): output capacity is then
-    the bucketed sum of input CAPACITIES (the static worst case) and the
-    output row count stays lazy.
+    With every input's row count on the host the output gets the bucket
+    of the rows there are.  While any count is still a device scalar,
+    the gather indices are computed DEVICE-SIDE (no sync): output
+    capacity is then the bucketed sum of input CAPACITIES (the static
+    worst case: 4,194,304 slots for q3's 807,274 build rows at SF0.25)
+    and the output row count stays lazy; every kernel downstream runs
+    at that capacity.  A barrier that holds many such inputs (a join's
+    build side, a single-batch coalesce) calls `rows_made_known` first.
 
     `sparse_ok=True` (callers whose consumer takes deferred-selection
     batches — the aggregate merge kernel, collect's final dense):

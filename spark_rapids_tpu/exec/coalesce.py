@@ -8,7 +8,8 @@ from __future__ import annotations
 from typing import Iterator, Optional
 
 from spark_rapids_tpu import types as T
-from spark_rapids_tpu.columnar.batch import ColumnarBatch, concat_batches
+from spark_rapids_tpu.columnar.batch import (
+    ColumnarBatch, concat_batches, rows_made_known)
 from spark_rapids_tpu.columnar.vector import bucket_capacity
 from spark_rapids_tpu.exec.base import (
     CoalesceGoal, RequireSingleBatch, TargetSize, TpuExec, UnaryExecBase)
@@ -41,12 +42,24 @@ def coalesce_iterator(batches: Iterator[ColumnarBatch],
     batch CAPACITY as the bound for lazy batches; the exchange's
     oversized-batch shard guard (shuffle/exchange.py) does exactly
     that so an up-to-8x lazy batch cannot land whole on one chip."""
+    if max_rows is None:
+        from spark_rapids_tpu import config as C
+        max_rows = C.get_active_conf()[C.MAX_BATCH_ROWS]
     if isinstance(goal, RequireSingleBatch):
         got = [b for b in batches if b.maybe_nonempty()]
         if not got:
             from spark_rapids_tpu.columnar.batch import empty_batch
             yield empty_batch(schema)
             return
+        if len(got) > 1:
+            # a barrier (a join's build side under AQE, a global sort, a
+            # window): its consumer's kernels run at this batch's
+            # capacity, so past one batch of padding the inputs' counts
+            # come to the host in one read and the concat is tight —
+            # the rule of `HashJoinExec._concat_build`
+            rows_made_known(got, "coalesce.single",
+                            beyond=bucket_capacity(int(max_rows)))
+            got = [b for b in got if b.maybe_nonempty()] or got[:1]
         out = concat_batches(got) if len(got) > 1 else _rebucket(got[0])
         metrics.add(M.NUM_OUTPUT_BATCHES, 1)
         metrics.add(M.NUM_OUTPUT_ROWS, out._rows)
@@ -54,9 +67,6 @@ def coalesce_iterator(batches: Iterator[ColumnarBatch],
         return
 
     target = goal.bytes if isinstance(goal, TargetSize) else 1 << 31
-    if max_rows is None:
-        from spark_rapids_tpu import config as C
-        max_rows = C.get_active_conf()[C.MAX_BATCH_ROWS]
     pending: list[ColumnarBatch] = []
     pending_bytes = 0
     pending_rows = 0
@@ -125,7 +135,9 @@ def _rebucket(b: ColumnarBatch) -> ColumnarBatch:
     """Shrink an over-padded batch into its tight bucket (e.g. after a
     selective filter) so downstream kernels compile for a smaller shape."""
     if not b.num_rows_known:
-        return b  # tightening needs the count; not worth a ~150ms sync
+        # tightening needs the count: a blocking read (0.4 ms on an idle
+        # v5e, PERF.md PR 30; on a busy one it waits for the queue)
+        return b
     tight = bucket_capacity(b.num_rows)
     if tight < b.capacity:
         return b.with_capacity(tight)

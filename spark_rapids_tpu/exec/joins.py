@@ -32,7 +32,7 @@ import numpy as np
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import (
-    ColumnarBatch, concat_batches, programs_of)
+    ColumnarBatch, concat_batches, programs_of, rows_made_known)
 from spark_rapids_tpu.columnar.vector import ColumnVector, bucket_capacity
 from spark_rapids_tpu.exec.base import (
     KernelCache, RequireSingleBatch, TpuExec, batch_signature,
@@ -585,12 +585,27 @@ class HashJoinExec(TpuExec):
         return [_owned_dense(b) for it in self._build.execute_partitions()
                 for b in it if b.maybe_nonempty()]
 
-    def _concat_build(self, batches: list[ColumnarBatch]) -> ColumnarBatch:
+    def _concat_build(self, batches: list[ColumnarBatch]
+                      ) -> tuple[ColumnarBatch, int]:
+        """The build side made whole, at the capacity of its ROWS, and
+        the count reads that took (0 or 1).  Slices that arrive with
+        their counts on the device (an exchange's full-capacity cuts)
+        would concatenate to the bucketed sum of their capacities, and
+        the dense-table attempt and every probe batch's sort would run
+        at that: past one batch of padding the counts come to the host
+        in one stacked read (`rows_made_known`; the build is a barrier,
+        the host blocks on the device right after it anyway) and the
+        concat takes its tight branch."""
+        from spark_rapids_tpu import config as C
+        reads = rows_made_known(
+            batches, "join.build", beyond=bucket_capacity(
+                int(C.get_active_conf()[C.MAX_BATCH_ROWS])))
+        batches = [b for b in batches if b.maybe_nonempty()]
         if not batches:
             from spark_rapids_tpu.columnar.batch import empty_batch
-            return empty_batch(self._build.output_schema())
+            return empty_batch(self._build.output_schema()), reads
         if len(batches) == 1:
-            return batches[0]
+            return batches[0], reads
         # the build-side concat is the join's known OOM hotspot, and a
         # hash join needs the build side WHOLE (single-batch contract),
         # so pressure here spills + retries in place — no split
@@ -599,10 +614,7 @@ class HashJoinExec(TpuExec):
         return R.with_retry(
             programs_of("join")(lambda: concat_batches(batches)),
             out_bytes=nbytes, metrics=self.metrics,
-            label=f"{self.name()}.buildSide")
-
-    def _build_batch(self) -> ColumnarBatch:
-        return self._concat_build(self._collect_build_batches())
+            label=f"{self.name()}.buildSide"), reads
 
     def _grace_candidate_batches(self) -> Optional[list[ColumnarBatch]]:
         """Raw build batches when the grace-hash lane may apply, None
@@ -625,16 +637,19 @@ class HashJoinExec(TpuExec):
         from spark_rapids_tpu.utils import profile as P
         with P.span(P.SPAN_JOIN_BUILD) as sp:
             batches = self._grace_candidate_batches()
+            build = None
             if batches is None:
-                build = self._build_batch()
+                batches = self._collect_build_batches()
+                build, reads = self._concat_build(batches)
             else:
                 conf = C.get_active_conf()
                 est = 2 * sum(b.device_size_bytes() for b in batches)
-                build = None if OC.should_go_external(est, conf) \
-                    else self._concat_build(batches)
+                if not OC.should_go_external(est, conf):
+                    build, reads = self._concat_build(batches)
             if sp is not None and build is not None:
                 sp.args = {"rows": P.known_rows([build]),
-                           "capacity_rows": build.capacity}
+                           "capacity_rows": build.capacity,
+                           "slices": len(batches), "count_reads": reads}
         if build is None:
             P.event(P.EV_OOCORE_DEGRADE, op=self.name(),
                     est_bytes=est, algo="grace-hash")
@@ -842,8 +857,7 @@ class HashJoinExec(TpuExec):
                     depth + 1, conf)
                 continue
             build_batches = [b.dense() for b in self._read_runs(bruns)]
-            build = self._concat_build(
-                [b for b in build_batches if b.maybe_nonempty()])
+            build, _ = self._concat_build(build_batches)
             yield from self._join_stream(build, self._read_runs(pruns))
 
     def _apply_condition(self, batch: ColumnarBatch) -> ColumnarBatch:
@@ -892,11 +906,11 @@ class BroadcastHashJoinExec(HashJoinExec):
     so every probe partition reuses one broadcast batch (reference
     GpuBroadcastHashJoinExec)."""
 
-    def _build_batch(self) -> ColumnarBatch:
+    def _collect_build_batches(self) -> list[ColumnarBatch]:
         from spark_rapids_tpu.shuffle.exchange import BroadcastExchangeExec
         if isinstance(self._build, BroadcastExchangeExec):
-            return self._build.broadcast_batch()
-        return super()._build_batch()
+            return [self._build.broadcast_batch()]
+        return super()._collect_build_batches()
 
     def _grace_candidate_batches(self) -> Optional[list[ColumnarBatch]]:
         # a broadcast build side is already materialized whole (and

@@ -19,7 +19,7 @@ from typing import Iterator, Optional
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import (
-    ColumnarBatch, concat_batches, programs_of)
+    ColumnarBatch, concat_batches, programs_of, rows_made_known)
 from spark_rapids_tpu.exec.base import TpuExec, UnaryExecBase
 from spark_rapids_tpu.shuffle.partitioning import (
     RangePartitioning, TpuPartitioning)
@@ -290,21 +290,8 @@ class ShuffleExchangeExec(UnaryExecBase):
                 # the summed worst-case capacity, and across a deep
                 # exchange chain that re-inflates every hop to the
                 # merge target no matter how few real rows flow
-                import jax.numpy as jnp
-                import numpy as np
                 dense = [b.dense() for b in group]
-                unknown = [b for b in dense if not b.num_rows_known]
-                if unknown:
-                    from spark_rapids_tpu.utils import checks as CK
-                    CK.note_host_sync("exchange.merge",
-                                      nbytes=4 * len(unknown))
-                    vals = np.asarray(jnp.stack(
-                        [b.num_rows_i32 for b in unknown])).tolist()
-                    it = iter(vals)
-                    dense = [b if b.num_rows_known else
-                             ColumnarBatch(b.schema, list(b.columns),
-                                           int(next(it)), b.checks)
-                             for b in dense]
+                rows_made_known(dense, "exchange.merge")
                 m = concat_batches([b for b in dense if b.num_rows > 0]
                                    or dense[:1])
             self.metrics.add(M.NUM_OUTPUT_ROWS, m._rows)

@@ -105,8 +105,11 @@ def _gather_reordered(columns, order, valid, packed_bits=None):
 
 #: lazy slicing keeps slices at the INPUT batch's capacity (the count is
 #: still on device), so it only pays off when that capacity is small;
-#: past this cap the ~150ms count sync amortizes over real compute and
-#: tightly-bucketed slices matter more than the round trip.
+#: past this cap the count sync (0.4 ms on an idle v5e, PERF.md PR 30;
+#: on a busy one the wait for everything queued before it) amortizes
+#: over real compute and tightly-bucketed slices matter more than the
+#: round trip.  A consumer that needs its input whole (a join's build
+#: side) asks for the counts of many such slices in one read.
 LAZY_SLICE_MAX_CAP = 1 << 16
 
 

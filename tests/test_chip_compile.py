@@ -183,8 +183,9 @@ def _largest(calls, tag, smallest=False):
     # q3's sort-path join, the dearest compile of a cold q3 on the chip
     # (PERF.md, PR 29: 83 s + 75 s of 248 s at SF0.25): its match (key
     # words + iota in one sort; the smaller of q3's two, since the
-    # compile time grows with the rows: 133 s here at 262,144) and its
-    # pair expansion
+    # compile time grows with the rows: 133 s here at the 262,144 its
+    # larger one had before PR 30 sized the build side by its rows) and
+    # its pair expansion
     ("_match_kernel", -(1 << 14)),
     ("_expand_kernel", CAP),
 ])

@@ -840,7 +840,12 @@ def test_phase_spans_carry_their_args(q3_profiled):
                                     agg.metrics.value(M.NUM_OUTPUT_ROWS))
     assert merge.args["partials"] == update.args["batches"] == 2
     for s in _named(prof, "exec:join-build"):
+        assert set(s.args) == {"rows", "capacity_rows", "slices",
+                               "count_reads"}
         assert s.args["capacity_rows"] >= s.args["rows"] > 0
+        # one tight batch a partition from each merged exchange reader,
+        # counts known: the build asks the device nothing at this scale
+        assert s.args["slices"] == 2 and s.args["count_reads"] == 0
     writes = _named(prof, "exec:exchange-write")
     reads = _named(prof, "exec:exchange-read")
     assert all(s.args["partitions"] == 2 and s.args["bytes"] > 0
