@@ -1,8 +1,8 @@
 #!/usr/bin/env bash
 # CI suite runner (reference jenkins/spark-tests.sh analog): runs the
-# fast unit tier, the scale ("slow") tier, a shim version matrix over
-# the version-sensitive suites, and a bench smoke. Usage:
-#   scripts/run_suite.sh [fast|slow|shims|bench|all]
+# fast unit tier, the scale ("slow") tier and a shim version matrix over
+# the version-sensitive suites. Usage:
+#   scripts/run_suite.sh [fast|slow|shims|all]
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -20,10 +20,6 @@ run_lint() {
   # JSON on stdout for tooling; the summary line rides stderr
   python scripts/lint.py --format json > /dev/null
   python scripts/gen_configs_doc.py --check
-  # bench-round drift gate: the differ's synthetic-round behavior
-  # checks (regression detected -> non-zero exit, improvement passes,
-  # missing phase tolerated)
-  python scripts/bench_diff.py --selftest
 }
 
 run_fast() {
@@ -93,11 +89,11 @@ PYEOF
 run_kernelprof() {
   # kernel-attribution lane: the kernelprof suite (disabled-path
   # parity, sampling, per-query isolation, catalog/cost capture,
-  # roofline single-source) + bench_diff units, then one profiled q1
+  # roofline single-source), then one profiled q1
   # whose '-- kernels --' section must attribute the compute bucket —
   # the summary line carries coverage, top kernel, and roofline %.
   echo "== kernelprof lane (per-kernel device timing, cost/roofline attribution) =="
-  "${PYTEST[@]}" tests/test_kernelprof.py tests/test_bench_diff.py
+  "${PYTEST[@]}" tests/test_kernelprof.py
   python - <<'PYEOF'
 import jax
 jax.config.update("jax_platforms", "cpu")
@@ -683,17 +679,11 @@ run_shims() {
   "${PYTEST[@]}" tests/test_shims.py tests/test_plan_overrides.py
 }
 
-run_bench() {
-  echo "== bench smoke (one JSON line per metric; real chip if present) =="
-  python bench.py
-}
-
 case "$TIER" in
   lint)     run_lint ;;
   fast)     run_fast ;;
   slow)     run_slow ;;
   shims)    run_shims ;;
-  bench)    run_bench ;;
   oom)      run_oom_soak ;;
   pipeline) run_pipeline ;;
   recovery) run_recovery ;;
@@ -708,7 +698,7 @@ case "$TIER" in
   kernelprof) run_kernelprof ;;
   residency) run_residency ;;
   oocore)   run_oocore ;;
-  all)      run_fast; run_slow; run_shims; run_bench ;;
-  *) echo "usage: $0 [lint|fast|slow|shims|bench|oom|pipeline|recovery|watchdog|profile|movement|concurrency|fusion|spmd|speculation|telemetry|kernelprof|residency|oocore|all]" >&2
+  all)      run_fast; run_slow; run_shims ;;
+  *) echo "usage: $0 [lint|fast|slow|shims|oom|pipeline|recovery|watchdog|profile|movement|concurrency|fusion|spmd|speculation|telemetry|kernelprof|residency|oocore|all]" >&2
      exit 2 ;;
 esac

@@ -15,10 +15,12 @@ itself enforced (`bad-suppress`).
 from __future__ import annotations
 
 import ast
+import os
 import re
 from typing import Optional
 
-from spark_rapids_tpu.analysis.core import FileContext, Finding
+from spark_rapids_tpu.analysis.core import (FileContext, Finding,
+                                            _iter_py_files)
 
 
 def dotted(node: ast.AST) -> Optional[str]:
@@ -390,6 +392,80 @@ class ConfDisciplineRule(Rule):
 
 
 # ---------------------------------------------------------------------------
+class ConfUnreadRule(Rule):
+    """Rule 4's reverse: `conf-discipline` proves that a key that is
+    USED is registered; nothing proved that a key that is registered
+    is used, and five entries stayed in config.py and docs/configs.md
+    for thirty PRs promising behaviour no module had.  A `ConfEntry`
+    bound to a module-level name in a `config.py` must be referenced
+    — by that name, or by its key as a string literal — somewhere in
+    config.py's own package (its directory, recursively) outside the
+    binding itself.  An option nothing reads is deleted, not
+    documented."""
+
+    rule_id = "conf-unread"
+    doc = ("a ConfEntry bound to a module-level name in config.py must "
+           "be read by some module of the package")
+
+    def check(self, ctx: FileContext) -> list[Finding]:
+        if ctx.components[-1] != "config.py":
+            return []
+        entries = self.entries(ctx.tree)
+        if not entries:
+            return []
+        skip = {id(n) for _, _, stmt in entries
+                for n in (stmt.targets[0], stmt.value.args[0])}
+        seen = self._mentions(ctx.tree, skip)
+        for path in _iter_py_files([os.path.dirname(ctx.path)]):
+            if path == ctx.path:
+                continue
+            try:
+                with open(path) as f:
+                    seen |= self._mentions(ast.parse(f.read()), ())
+            except (OSError, SyntaxError):
+                continue        # run_lint reports it as parse-error
+        return [self.finding(
+                    ctx, stmt,
+                    f"conf entry {name} ('{key}') is read by no module "
+                    "of the package — delete it (and regenerate "
+                    "docs/configs.md) or make something read it")
+                for name, key, stmt in entries
+                if name not in seen and key not in seen]
+
+    @staticmethod
+    def entries(tree: ast.Module) -> list:
+        """(name, key, binding statement) of every module-level
+        `NAME = conf("key", ...)`."""
+        return [(stmt.targets[0].id, stmt.value.args[0].value, stmt)
+                for stmt in tree.body
+                if isinstance(stmt, ast.Assign) and len(stmt.targets) == 1
+                and isinstance(stmt.targets[0], ast.Name)
+                and isinstance(stmt.value, ast.Call)
+                and dotted(stmt.value.func) == "conf"
+                and stmt.value.args
+                and isinstance(stmt.value.args[0], ast.Constant)]
+
+    @staticmethod
+    def _mentions(tree: ast.AST, skip) -> set:
+        """Every identifier, attribute name and string literal of a
+        module, but for the nodes in `skip`."""
+        out = set()
+        for node in ast.walk(tree):
+            if id(node) in skip:
+                continue
+            if isinstance(node, ast.Name):
+                out.add(node.id)
+            elif isinstance(node, ast.Attribute):
+                out.add(node.attr)
+            elif isinstance(node, ast.alias):
+                out.add(node.name)
+            elif (isinstance(node, ast.Constant)
+                  and isinstance(node.value, str)):
+                out.add(node.value)
+        return out
+
+
+# ---------------------------------------------------------------------------
 class CompileUnderLockRule(Rule):
     """Rule 5 (PR 2/7): XLA trace/compile runs seconds-to-minutes, so
     it must never happen inside a `with <lock>:` body — KernelCache's
@@ -538,7 +614,7 @@ def leaf_name(call: ast.Call) -> str:
 
 
 ALL_RULES = [HostSyncRule(), BlockingWhileHoldingRule(),
-             UnboundedWaitRule(), ConfDisciplineRule(),
+             UnboundedWaitRule(), ConfDisciplineRule(), ConfUnreadRule(),
              CompileUnderLockRule(), CollectiveDisciplineRule()]
 
 

@@ -50,10 +50,6 @@ EXPLAIN = conf("spark.rapids.sql.explain", "NONE",
 INCOMPATIBLE_OPS = conf("spark.rapids.sql.incompatibleOps.enabled", False,
                         "Enable operators producing results that differ "
                         "slightly from Spark (e.g. float aggregation order).")
-IMPROVED_FLOAT = conf("spark.rapids.sql.improvedFloatOps.enabled", False,
-                      "Enable improved-precision float transcendental ops.")
-HAS_NANS = conf("spark.rapids.sql.hasNans", True,
-                "Assume floating point data may contain NaNs.")
 VARIABLE_FLOAT_AGG = conf("spark.rapids.sql.variableFloatAgg.enabled", False,
                           "Allow float aggregations whose result can vary "
                           "with evaluation order.")
@@ -123,10 +119,6 @@ HBM_BUDGET_BYTES = conf(
     "memory.")
 HOST_SPILL_STORAGE = conf("spark.rapids.memory.host.spillStorageSize",
                           1073741824, "Host memory for spilled device data.")
-PINNED_POOL_SIZE = conf("spark.rapids.memory.pinnedPool.size", 0,
-                        "Pinned host staging pool bytes (0 = disabled).")
-HBM_DEBUG = conf("spark.rapids.memory.gpu.debug", "NONE",
-                 "Arena allocation debug logging: NONE, STDOUT, STDERR.")
 RETRY_MIN_SPLIT_ROWS = conf(
     "spark.rapids.memory.retry.minSplitRows", 1024,
     "Floor for OOM split-and-retry: a batch at or below this many rows "
@@ -354,8 +346,8 @@ MOVEMENT_ROOFLINE_GBPS = conf(
     "per-edge ceilings through the shared roofline table "
     "(spark.rapids.sql.profile.roofline.*, utils/roofline.py — the "
     "same source kernelprof judges kernels against); a non-zero "
-    "value overrides ALL edges at once, e.g. with a probed number "
-    "(bench.py's probe_hbm_bandwidth) to judge every edge against "
+    "value overrides ALL edges at once, e.g. with a number probed on "
+    "the operator's own hardware, to judge every edge against "
     "measured hardware instead.")
 MOVEMENT_MIN_EVENT_BYTES = conf(
     "spark.rapids.sql.profile.movement.minEventBytes", 65536,
@@ -894,9 +886,6 @@ PYTHON_MEM_LIMIT = conf(
     "python/rapids/worker.py:34-50).")
 UDF_COMPILER_ENABLED = conf("spark.rapids.sql.udfCompiler.enabled", True,
                             "Compile Python UDF bytecode to expressions.")
-
-METRICS_LEVEL = conf("spark.rapids.sql.metrics.level", "MODERATE",
-                     "Operator metric detail: ESSENTIAL, MODERATE, DEBUG.")
 
 PALLAS_Q1_ENABLED = conf(
     "spark.rapids.tpu.pallas.q1.enabled", False,

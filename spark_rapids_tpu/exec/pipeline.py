@@ -68,8 +68,8 @@ _POLL_S = 0.05
 #: it leaked (module-level so the watchdog suite can shrink it)
 _JOIN_TIMEOUT_S = 10.0
 
-# process-wide stats (bench.py records these alongside wall clock so the
-# perf trajectory captures overlap, not just totals; leaked_producers
+# process-wide stats (read beside wall clock they show overlap, not
+# just totals; leaked_producers
 # counts threads that survived the close() join — surfaced in the
 # watchdog dump, because a leaked producer is exactly the kind of
 # wedged activity the watchdog exists to name)

@@ -4,7 +4,7 @@ mesh, not the partition.
 PR 7's `FusedStageExec` made a stage ONE XLA program — but Python
 still dispatched it once per partition batch, and on a pod that is the
 multichip scaling wall: O(partitions) host round-trips per stage while
-the mesh sits idle between them (the 1-3% HBM story of BENCH_r05/r06).
+the mesh sits idle between them.
 Theseus (PAPERS.md) argues the runtime must own data movement
 end-to-end; the pjit/GDA pattern (SNIPPETS.md [1][2], PartitionSpec
 layouts [3]) is the JAX-native form of that for stage compute:

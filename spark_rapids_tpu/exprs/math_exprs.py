@@ -1,9 +1,9 @@
 """Math expressions (reference `mathExpressions.scala`).
 
-All unary transcendentals produce float64 like Spark.  The reference gates
-"improved" float ops behind `spark.rapids.sql.improvedFloatOps.enabled`
-(GpuOverrides.scala:648-672); on TPU, XLA's libm lowering is already
-correctly rounded enough that both paths share one implementation.
+All unary transcendentals produce float64 like Spark.  The reference
+keeps a second, "improved" implementation of some float ops behind a
+switch (GpuOverrides.scala:648-672); here there is one implementation,
+XLA's libm lowering, and no switch.
 """
 from __future__ import annotations
 

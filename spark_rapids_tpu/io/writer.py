@@ -61,7 +61,8 @@ def _writer_factory(file_format: str, options):
 class WriteJob:
     """Job-level commit protocol (reference GpuFileFormatWriter.write +
     GpuInsertIntoHadoopFsRelationCommand).  FileOutputCommitter-v1
-    shape, with a real TASK-attempt level (VERDICT r4 missing #2):
+    shape, with a real TASK-attempt level (a failed or duplicate
+    attempt leaves nothing a reader can see):
 
       task attempt writes under  _temporary/<job>/_attempt_<task>_<uuid>/
       task commit                one atomic rename -> _temporary/<job>/task_<task>/

@@ -3,7 +3,7 @@
 
 Queries are built as physical plans over the engine; `build_q1_kernel`
 additionally exposes Q1's compute as ONE pure jittable function — the
-"flagship forward step" used by __graft_entry__ and bench.py.
+"flagship forward step" used by __graft_entry__.
 
 Q1 (pricing summary report):
   select returnflag, linestatus, sum(qty), sum(extprice),

@@ -1,16 +1,14 @@
-"""TPC-H bench driver (reference `TpcxbbLikeBench.runBench`
-`TpcxbbLikeBench.scala:26-40` / `TpcdsLikeBench.scala`): cold runs
-(compile) + hot runs, per-query wall-clock, CPU-engine baseline ratio.
+"""TPC-H query runner (reference `TpcxbbLikeBench.runBench`
+`TpcxbbLikeBench.scala:26-40` / `TpcdsLikeBench.scala`): one query on
+the accelerated path or on the CPU engine, under the conf the reference
+runs its TPC suites with.
 """
 from __future__ import annotations
 
-import time
-from typing import Optional, Sequence
-
-import numpy as np
+from typing import Optional
 
 from spark_rapids_tpu import config as C
-from spark_rapids_tpu.models.tpch_data import gen_tables, sources
+from spark_rapids_tpu.models.tpch_data import sources
 from spark_rapids_tpu.models.tpch_queries import QUERIES
 
 
@@ -45,43 +43,3 @@ def run_query(n: int, tables, engine: str = "tpu",
     conf = conf or C.RapidsConf(dict(BENCH_CONF))
     run = _tpu_runner(conf)
     return run(QUERIES[n](t, run))
-
-
-def run_bench(queries: Sequence[int] = tuple(QUERIES),
-              scale: int = 100_000, num_cold_runs: int = 1,
-              num_hot_runs: int = 3, engine: str = "tpu",
-              conf: Optional[C.RapidsConf] = None) -> dict:
-    """Cold+hot timing per query; returns {query: {cold_s, hot_s}}."""
-    rng = np.random.default_rng(0)
-    tables = gen_tables(rng, scale)
-    results = {}
-    for n in queries:
-        cold = []
-        for _ in range(num_cold_runs):
-            t0 = time.perf_counter()
-            run_query(n, tables, engine, conf)
-            cold.append(time.perf_counter() - t0)
-        hot = []
-        for _ in range(num_hot_runs):
-            t0 = time.perf_counter()
-            run_query(n, tables, engine, conf)
-            hot.append(time.perf_counter() - t0)
-        results[n] = {"cold_s": min(cold) if cold else None,
-                      "hot_s": min(hot) if hot else None}
-        fmt = lambda v: "-" if v is None else f"{v:.3f}s"
-        print(f"q{n}: cold={fmt(results[n]['cold_s'])} "
-              f"hot={fmt(results[n]['hot_s'])}")
-    return results
-
-
-if __name__ == "__main__":
-    import argparse
-    import json
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--queries", type=str, default="1,3,5,6")
-    ap.add_argument("--scale", type=int, default=100_000)
-    ap.add_argument("--engine", type=str, default="tpu")
-    args = ap.parse_args()
-    qs = [int(x) for x in args.queries.split(",")]
-    out = run_bench(qs, scale=args.scale, engine=args.engine)
-    print(json.dumps(out))

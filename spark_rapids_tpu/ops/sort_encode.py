@@ -184,10 +184,11 @@ def _pack_words_width(keys_msf: list, max_bits: int) -> list:
 
 
 def _pack_words(keys_msf: list) -> list:
-    """Pack keys into sort words, PREFERRING 32-bit words: a variadic
-    sort over two u32 operands runs ~40% faster than over one u64 word
-    on this chip (measured 85ms vs 118-142ms at 2M rows — 64-bit
-    compare-exchange is the bitonic network's dominant cost).  The
+    """Pack keys into sort words, PREFERRING 32-bit words: 64-bit
+    compare-exchange is the sort network's dominant cost on the chip (it
+    has no native 64-bit integer compare), so two u32 operands sort
+    faster than one u64 word (by how much is not measured on the
+    current machine: ROADMAP Queue 1 #9).  The
     32-bit split only applies while the total word count stays within
     the variadic-network budget; past it, wide 64-bit words keep the
     word count (and the LSD chain length) down."""

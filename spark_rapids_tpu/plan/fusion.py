@@ -1,10 +1,10 @@
 """Whole-stage XLA fusion: compile operator chains into one program
 per stage.
 
-BENCH_r05 put the cost of NOT doing this at ~30% on the engine's best
-query: the hand-fused q1 batch lane runs 2.63B rows/s against 1.99B for
-the pipelined per-operator engine, the difference being per-operator
-dispatch plus intermediate ColumnarBatch materialization in HBM.  Eiger
+What NOT doing this costs is per-operator dispatch plus an intermediate
+ColumnarBatch materialized in HBM between every two operators (the
+hand-fused q1 kernel of models/tpch.py is the same query without
+either; the gap is not measured on the current machine).  Eiger
 (PAPERS.md) makes the general case: relational operator pipelines
 should compile into single kernels, with the pipeline breaks as the
 only boundaries.

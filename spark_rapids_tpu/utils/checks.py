@@ -29,9 +29,9 @@ import numpy as np
 # debug host-sync counter (the pipelining PR's audit instrument): every
 # device->host readback on the hot path calls note_host_sync(site), so
 # "how many times per partition does the host block on the device" is a
-# measurable number — bench.py records it and regressions show up as a
-# counter diff, not a mystery slowdown.  Counting is always on: a sync
-# costs a blocking device round trip, so
+# measurable number — the benchmark reports it as `host_syncs` and a
+# regression shows up as a counter diff, not a mystery slowdown.
+# Counting is always on: a sync costs a blocking device round trip, so
 # one guarded dict increment per sync is noise.
 _SYNC_LOCK = threading.Lock()
 _SYNC_SITES: "collections.Counter" = collections.Counter()

@@ -1,9 +1,8 @@
 """Per-query data-movement ledger: bytes on every edge.
 
 Theseus (PAPERS.md) argues that distributed accelerator query engines
-win or lose on data-movement accounting; BENCH_r05 showed this engine's
-hardware mostly idle (1-3% HBM utilization) with sub-1x lanes nobody
-could diagnose because the profiler measured only *time*.  This module
+win or lose on data-movement accounting; a profiler that measures only
+*time* cannot say why the hardware sits idle.  This module
 is the missing half of the instrument: every site where bytes cross a
 boundary records (edge, site, bytes, duration) into the query's
 DataMovementLedger, and the QueryProfile renders the result as a
@@ -72,8 +71,6 @@ EDGES = (EDGE_UPLOAD, EDGE_READBACK, EDGE_SPILL, EDGE_WIRE,
 #: spark.rapids.sql.profile.roofline.* and the SAME source feeds the
 #: per-kernel roofline join (utils/kernelprof.py) — two diverging
 #: nominal tables was the bug class the shared module replaces.
-#: bench.py reports utilization against the PROBED HBM ceiling as well
-#: (probe_hbm_bandwidth / V5E_HBM_GBPS).
 from spark_rapids_tpu.utils.roofline import \
     DEFAULT_EDGE_GBPS as NOMINAL_GBPS
 

@@ -5,8 +5,8 @@ PRs 5 and 8 gave each *query* eyes (span trees, event logs, the
 data-movement ledger) and PR 6 made the engine multi-tenant — but
 nothing answered the operator's questions while an 8-session storm is
 running: how full is HBM, who holds the semaphore, how deep is the
-admission queue, and WHY does BENCH_r05 show 1-3% HBM utilization on
-nearly every engine-mode metric.  Theseus (PAPERS.md) argues accelerator
+admission queue, and why is the device idle while queries wait.
+Theseus (PAPERS.md) argues accelerator
 query engines live or die on knowing where bytes and time go
 fleet-wide; the Presto-on-GPU work frames the always-on multi-tenant
 telemetry surface.  This module is that surface, built on the existing

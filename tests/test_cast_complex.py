@@ -1,5 +1,5 @@
-"""Gated cast directions + complex-type extractors (VERDICT r1 items
-#5-#7): float<->string casts behind per-direction flags (reference
+"""Gated cast directions + complex-type extractors:
+float<->string casts behind per-direction flags (reference
 GpuCast.scala:31), string->timestamp/bool, StringSplit consumed by
 GetArrayItem (stringFunctions.scala:812), GetArrayItem/GetMapValue over
 inline constructors (complexTypeExtractors.scala:88).  Every gated

@@ -95,8 +95,8 @@ def test_slice():
 
 
 def test_f32_shadow_overflow_boundaries():
-    """The FLOAT64 narrow shadow's overflow semantics are explicit
-    (VERDICT r4): finite f64 past the f32 range clamps to +-f32max
+    """The FLOAT64 narrow shadow's overflow semantics are explicit:
+    finite f64 past the f32 range clamps to +-f32max
     (monotone, finiteness-preserving), infinities and NaN pass
     through, signs (incl. -0.0) are kept — and no RuntimeWarning."""
     import warnings

@@ -74,7 +74,7 @@ def test_normalize_nan_zero():
     assert got[1] == 0.0 and math.isnan(got[2]) and got[3] == 1.5
 
 
-# -- planner-level Expand/Generate (VERDICT r1 item #4) ---------------------
+# -- planner-level Expand/Generate ------------------------------------------
 def test_cpu_expand_rollup_through_accelerate():
     """Rollup-shaped expand (grouping sets) planned via accelerate():
     projections (a,b,gid=0),(a,null,1),(null,null,3) then aggregate —

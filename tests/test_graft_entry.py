@@ -103,7 +103,7 @@ def test_dryrun_multichip_after_backend_init():
 
 @pytest.mark.slowish
 def test_dryrun_multichip_host_count_set_but_default_backend_not_cpu():
-    # The MULTICHIP_r03 crash shape: the driver sets
+    # A crash shape seen on a driver's multichip run: the driver sets
     # --xla_force_host_platform_device_count=8 but NOT JAX_PLATFORMS, and
     # initializes backends first.  CPU can seat the mesh, but the DEFAULT
     # backend is the (possibly broken, libtpu-skewed) accelerator plugin:

@@ -522,7 +522,7 @@ def test_exception_mode_accepts_1582_to_1900_timestamps():
     assert RB.arrow_table_needs_rebase(tbl2)
 
 
-# -- task-commit protocol (VERDICT r4: GpuFileFormatWriter.scala:338 /
+# -- task-commit protocol (GpuFileFormatWriter.scala:338 /
 # -- GpuInsertIntoHadoopFsRelationCommand semantics) -------------------------
 def _wb(df):
     from spark_rapids_tpu.columnar.batch import ColumnarBatch

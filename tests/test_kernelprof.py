@@ -169,8 +169,7 @@ def test_coverage_vs_compute_bucket(tables):
     the single-thread compute bucket.  Needs a kernel-DOMINATED scale
     — at the module fixture's tiny SCALE the query is fixed Python
     orchestration and legitimately low-coverage — so this test runs
-    its own q1 at 20k rows (generous CI band; bench.py records the
-    tight number at 200k)."""
+    its own q1 at 20k rows (generous CI band)."""
     from spark_rapids_tpu.models.tpch_data import gen_tables
     big = gen_tables(np.random.default_rng(11), 20_000)
     conf = _kconf(**{"spark.rapids.sql.pipeline.enabled": False})

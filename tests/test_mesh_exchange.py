@@ -3,8 +3,7 @@
 The accelerated exchange lane must be reachable from accelerate(), not
 just from unit harnesses: a TPC-H join+groupby query planned normally,
 with a mesh active, must route its hash exchanges through the collective
-and still match the CPU golden engine (VERDICT r1 item #2; reference
-analog: UCX-inside-the-shuffle-manager,
+and still match the CPU golden engine (reference analog: UCX-inside-the-shuffle-manager,
 RapidsShuffleInternalManager.scala:199)."""
 import jax
 import numpy as np
@@ -116,7 +115,7 @@ def tpch_tables():
 def test_tpch_mesh_exchange_parity(tpch_tables, mesh8, query):
     """End-to-end: q3/q5 planned via accelerate() with an active mesh
     executes its hash exchanges over the 8-device mesh with parity vs
-    the CPU golden engine (the VERDICT r1 #2 done-criterion)."""
+    the CPU golden engine."""
     from spark_rapids_tpu.models.tpch_bench import run_query
     expected = run_query(query, tpch_tables, engine="cpu")
     ShuffleExchangeExec._MESH_EXCHANGES_RUN = 0
@@ -130,8 +129,8 @@ def test_tpch_mesh_exchange_parity(tpch_tables, mesh8, query):
 def test_oversized_single_batch_shards_across_mesh(mesh8):
     """SURVEY §5 long-context analog: ONE batch beyond the per-chip
     budget is split over the mesh devices before the all-to-all, and
-    the exchanged result stays exact (planner + mesh halves of the
-    VERDICT r2 #9 done-criterion)."""
+    the exchanged result stays exact (the planner's half and the mesh's
+    half together)."""
     import pandas as pd
     from spark_rapids_tpu import config as C
     from spark_rapids_tpu.exec.basic import LocalBatchSource

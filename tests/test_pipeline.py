@@ -100,16 +100,16 @@ def test_prefetch_close_cancels_producer():
 
 def test_prefetch_propagates_session_conf_to_producer():
     seen = []
-    conf = C.RapidsConf({"spark.rapids.sql.hasNans": False})
+    conf = C.RapidsConf({"spark.rapids.sql.variableFloatAgg.enabled": True})
 
     def src():
-        seen.append(C.get_active_conf()[C.HAS_NANS])
+        seen.append(C.get_active_conf()[C.VARIABLE_FLOAT_AGG])
         yield 1
 
     with C.session(conf):
         it = PrefetchIterator(src(), depth=1)
     assert list(it) == [1]
-    assert seen == [False]
+    assert seen == [True]
 
 
 def test_prefetch_propagates_retry_flag_to_producer():

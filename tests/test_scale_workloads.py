@@ -1,4 +1,4 @@
-"""Scale-tier workload evidence (VERDICT r2 #6): parity runs big enough
+"""Scale-tier workload evidence: parity runs big enough
 to force MULTIPLE coalesce-target batches per partition (multi-batch
 aggregation re-merge, batch slicing) plus at least one device->host
 spill through the shuffle manager's spillable catalog, with the spill

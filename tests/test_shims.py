@@ -327,9 +327,9 @@ def test_aqe_respects_pinned_partition_count():
 
 
 def test_unknown_version_fails_with_supported_list():
-    """A NEW Spark version arriving has defined behavior (VERDICT r4
-    weak #6): exact-match miss fails loudly like the reference
-    ShimLoader, naming the supported versions and the escape hatch."""
+    """A NEW Spark version arriving has defined behavior: an exact-match
+    miss fails loudly like the reference ShimLoader, naming the
+    supported versions and the escape hatch."""
     import pytest
     from spark_rapids_tpu.shims.loader import get_spark_shims
     with pytest.raises(RuntimeError) as ei:

@@ -1,7 +1,7 @@
 """Two-PROCESS shuffle: a real second executor process fetches map
 outputs over the TCP lane, address exchange via MapStatus — no shared
-memory (VERDICT r1 item #9; one level more real than the reference's
-mocked-transport suites, SURVEY.md §4 tier 2)."""
+memory (one level more real than the reference's mocked-transport
+suites, SURVEY.md §4 tier 2)."""
 import json
 import os
 import subprocess

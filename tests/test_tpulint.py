@@ -30,6 +30,7 @@ RULE_FIXTURES = {
     "unbounded-wait": os.path.join(FIXTURES, "shuffle",
                                    "fx_unbounded_wait.py"),
     "conf-discipline": os.path.join(FIXTURES, "plan", "fx_conf.py"),
+    "conf-unread": os.path.join(FIXTURES, "conf_unread", "config.py"),
     "compile-under-lock": os.path.join(FIXTURES, "exec",
                                        "fx_compile_lock.py"),
     "collective-discipline": os.path.join(FIXTURES, "parallel",
@@ -146,8 +147,8 @@ def test_real_tree_lints_clean():
     # the baseline stays empty (repo policy: fix, don't grandfather)
     assert all(f.reason for f in res.suppressed)
     assert not res.baselined
-    assert len(res.rules) == 6
-    assert "rules=6" in summary_line(res)
+    assert len(res.rules) == 7
+    assert "rules=7" in summary_line(res)
 
 
 def test_conf_registry_parse_matches_runtime():
@@ -159,6 +160,20 @@ def test_conf_registry_parse_matches_runtime():
         os.path.join(REPO, "spark_rapids_tpu", "config.py"))
     runtime = {k for k in C._REGISTRY if k.startswith("spark.rapids.")}
     assert runtime <= parsed
+
+
+def test_conf_unread_sees_every_registered_entry():
+    """`conf-unread` judges the entries it can see: module-level
+    `NAME = conf(...)` bindings.  An entry registered any other way
+    would escape it, so the bindings it parses must be the whole live
+    registry."""
+    import ast
+    from spark_rapids_tpu import config as C
+    from spark_rapids_tpu.analysis.rules import ConfUnreadRule
+    with open(os.path.join(REPO, "spark_rapids_tpu", "config.py")) as f:
+        entries = ConfUnreadRule.entries(ast.parse(f.read()))
+    assert {key for _, key, _ in entries} == set(C._REGISTRY)
+    assert all(getattr(C, name).key == key for name, key, _ in entries)
 
 
 # ---------------------------------------------------------------------------

@@ -1,8 +1,11 @@
 """Shared by the per-suite workload parity files (test_workloads_*.py):
 the CPU-vs-accelerated runners and the table generators.  One file per
-suite, so `--dist loadfile` can spread the suites over workers (each
-workload query compiles tens of small kernels; 136 cases in one file
-pinned them all to one worker)."""
+suite, TPC-DS in `TPCDS_PARTS`, so `--dist loadfile` can spread them
+over workers (each workload query compiles tens of small kernels; 136
+cases in one file pinned them all to one worker)."""
+import glob
+import os
+
 import numpy as np
 
 from spark_rapids_tpu import config as C
@@ -31,8 +34,13 @@ def run_tpu(build_plan, t, conf=None):
 
 # -- TPC-DS -----------------------------------------------------------------
 #: the TPC-DS suite runs as this many files; file i holds every
-#: TPCDS_PARTS-th query name starting at i
-TPCDS_PARTS = 6
+#: TPCDS_PARTS-th query name starting at i.  The driver hands whole
+#: files to six workers, so the run cannot end before its longest file
+#: does: at 6 parts one file was 977 s of a 1,205 s cold wall, at 18 the
+#: longest is q66 (363 s cold by itself) and five others (the table is
+#: in docs/dev-guide.md, "The tier-1 suite's clock").  A part costs one
+#: `tpcds_tables()`, under a second.
+TPCDS_PARTS = 18
 
 # safety valve for ultra-selective queries (5+ independent predicate
 # chains, e.g. q91's demographics x buy-potential x gmt chain): at the
@@ -43,6 +51,13 @@ ALLOW_EMPTY = {"q91"}
 
 
 def tpcds_names(part: int) -> list:
+    # a part without its file would drop its queries from the suite
+    # without a sound: fail every part's collection instead
+    files = glob.glob(os.path.join(os.path.dirname(__file__),
+                                   "test_workloads_tpcds_*.py"))
+    assert len(files) == TPCDS_PARTS and 0 <= part < TPCDS_PARTS, \
+        f"{len(files)} test_workloads_tpcds_*.py files, part {part}, " \
+        f"TPCDS_PARTS = {TPCDS_PARTS}"
     return sorted(tpcds_queries.QUERIES)[part::TPCDS_PARTS]
 
 
