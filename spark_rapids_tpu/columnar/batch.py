@@ -287,7 +287,14 @@ class ColumnarBatch:
 
     @property
     def num_rows_i32(self):
-        """Row count as an int32 operand for kernels — never syncs."""
+        """Row count as an int32 operand for kernels — never syncs.  A
+        known count is a host `np.int32` that a jitted call carries with
+        its other arguments (a device scalar made of it first is an
+        eager dispatch and a 4-byte transfer of its own, 0.54 ms on the
+        chip: PERF.md, PR 32); an unknown one is the device scalar.
+        Same abstract value either way, so the same program."""
+        if isinstance(self._rows, int):
+            return np.int32(self._rows)
         return jnp.asarray(self._rows, jnp.int32)
 
     def maybe_nonempty(self) -> bool:

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import (
     ColumnarBatch, concat_batches, programs_of)
-from spark_rapids_tpu.columnar.vector import (ColumnVector,
+from spark_rapids_tpu.columnar.vector import (MIN_CAPACITY, ColumnVector,
                                               bucket_capacity)
 from spark_rapids_tpu.exec.base import (
     SchemaOnlyExec as _SchemaOnly, TpuExec, UnaryExecBase,
@@ -1455,10 +1455,13 @@ class HashAggregateExec(UnaryExecBase):
             @named_jit(f"agg-reduce-{phase}")
             def kernel(columns, num_rows, mask=None):
                 ctx = self._make_ctx(columns, cap, num_rows, mask)
-                seg_ids = jnp.zeros(cap, jnp.int32)
-                actx = AggContext(seg_ids, cap, ctx.row_mask,
-                                  bounds=jnp.arange(cap) == 0,
-                                  ends=jnp.full(cap, cap - 1, jnp.int32))
+                # no groups: one segment over every row, so each scan
+                # operand is plainly reduced and the partial is the one
+                # row it is, in the smallest batch there is
+                actx = AggContext(jnp.zeros(cap, jnp.int32), cap,
+                                  ctx.row_mask,
+                                  out_capacity=MIN_CAPACITY,
+                                  single_segment=True)
                 if phase == "update":
                     inputs_per_f = [[e.eval(ctx) for e in bins]
                                     for bins in self._bound_inputs]

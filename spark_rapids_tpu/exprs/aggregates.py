@@ -14,6 +14,12 @@ exactly like Spark's:
 
 All stages are static-shape: `num_segments == capacity`, with invalid rows
 routed to segment id == capacity (dropped by XLA scatter semantics).
+
+The UNGROUPED aggregate (no group keys: `exec/aggregate._reduce_kernel`)
+is the one case that is not a segment op: its context says
+`single_segment`, and every operand a function registers is reduced over
+the whole batch with the plain reduction of its op (a total needs no
+scan), the partial emitted as the one row it is.
 """
 from __future__ import annotations
 
@@ -99,6 +105,24 @@ _SCAN_OPS = {
     "max": jnp.maximum,
 }
 
+#: the whole-batch reduction of each scan op (single-segment contexts).
+#: A sum keeps its operand's dtype, as the scan does: `jnp.sum` alone
+#: would widen an int32 count to int64 before it adds (64-bit
+#: elementwise is 50-100x slower on this chip)
+_REDUCE_OPS = {
+    "add": lambda a: jnp.sum(a, axis=0, dtype=a.dtype),
+    "min": lambda a: jnp.min(a, axis=0),
+    "max": lambda a: jnp.max(a, axis=0),
+}
+
+
+def _reduce_all(op: str, arr, out_capacity: int):
+    """One segment covering every row: `arr` reduced over axis 0 in its
+    own dtype (a FLOAT64 sum stays float64; only the order of the
+    additions differs from the scan's), broadcast to the group side."""
+    total = _REDUCE_OPS[op](arr)
+    return jnp.broadcast_to(total, (out_capacity,) + total.shape)
+
 
 class ScanBatch:
     """Cross-function segmented-scan batcher.
@@ -142,17 +166,23 @@ class ScanBatch:
     def run_round(self) -> None:
         if not self._pend:
             return
-        idxs = [h for h, _ in self._pend]
-        arrs = [a for _, a in self._pend]
-        ops = [_SCAN_OPS[self._ops[h]] for h in idxs]
+        if self._ctx.single_segment:
+            # the ungrouped aggregate: a total needs no scan
+            for h, a in self._pend:
+                self._results[h] = _reduce_all(
+                    self._ops[h], a, self._ctx.out_capacity)
+        else:
+            idxs = [h for h, _ in self._pend]
+            arrs = [a for _, a in self._pend]
+            ops = [_SCAN_OPS[self._ops[h]] for h in idxs]
 
-        def combine(a, b):
-            return tuple(op(x, y) for op, x, y in zip(ops, a, b))
+            def combine(a, b):
+                return tuple(op(x, y) for op, x, y in zip(ops, a, b))
 
-        runs = _segscan(combine, self._ctx.bounds, *arrs)
-        ends = self._ctx.ends
-        for h, r in zip(idxs, runs):
-            self._results[h] = jnp.take(r, ends)
+            runs = _segscan(combine, self._ctx.bounds, *arrs)
+            ends = self._ctx.ends
+            for h, r in zip(idxs, runs):
+                self._results[h] = jnp.take(r, ends)
         self._pend = []
 
     def result(self, h: int):
@@ -215,6 +245,8 @@ def _sorted_seg_sums(ctx: "AggContext", *vals):
     deterministic as a hash groupby's, and integer wraparound matches
     Spark's non-ANSI sum.  Invalid rows must already be value-zeroed
     (they share the last group's segment id)."""
+    if ctx.single_segment:
+        return tuple(_reduce_all("add", v, ctx.out_capacity) for v in vals)
     runs = _segscan(lambda a, b: tuple(x + y for x, y in zip(a, b)),
                     ctx.bounds, *vals)
     return tuple(jnp.take(r, ctx.ends) for r in runs)
@@ -231,18 +263,25 @@ class AggContext:
     row_valid: jnp.ndarray   # sorted row mask
     #: True at each sorted row that STARTS a group (invalid rows never
     #: start one — they ride the last group's segment id)
-    bounds: jnp.ndarray
+    bounds: Optional[jnp.ndarray] = None
     #: per-SEGMENT index of its last sorted row (out_capacity-length;
     #: entries at or past the group count are arbitrary, must be masked)
-    ends: jnp.ndarray
+    ends: Optional[jnp.ndarray] = None
     #: GROUP-side output length.  The exec compacts groups INSIDE the
     #: kernel (ends/outputs at the compact width) so per-group gathers
     #: and output stores never run at full row capacity — a 2M-row
     #: batch with 1K groups paid ~1/3 of its kernel time materializing
     #: full-capacity group outputs before this existed.
     out_capacity: Optional[int] = None
+    #: a STATIC fact, set by the ungrouped `_reduce_kernel` and nobody
+    #: else: exactly one segment covers every row (`seg_ids` all zero),
+    #: so scan operands are plainly reduced and `bounds` / `ends` unused
+    single_segment: bool = False
 
     def __post_init__(self):
+        assert self.single_segment or (
+            self.bounds is not None and self.ends is not None), \
+            "a grouped AggContext needs its bounds and ends"
         if self.out_capacity is None:
             self.out_capacity = self.capacity
 
