@@ -165,57 +165,89 @@ def _record_upload(cols: list[ColumnVector], rows: int) -> None:
                   site="batch.from_numpy", rows=rows)
 
 
+def _upload_strings(data: dict, schema: T.Schema, validity: dict,
+                    max_rows: int) -> dict:
+    """The string columns of one run, every chunk of each in one pass:
+    {name: [the `ColumnVector.from_numpy` of each `max_rows` rows]}
+    (`char_cap` bucketed per chunk, three arrays a chunk).  One
+    `exec:upload-strings` span holds them all; a schema without a string
+    column opens none."""
+    fields = [f for f in schema.fields if f.dtype.is_string]
+    if not fields:
+        return {}
+    from spark_rapids_tpu.utils import movement as MV
+    from spark_rapids_tpu.utils import profile as P
+    n = len(data[fields[0].name])
+    with P.span(P.SPAN_UPLOAD_STRINGS) as sp:
+        out = {}
+        for f in fields:
+            values, valid = np.asarray(data[f.name]), validity.get(f.name)
+            out[f.name] = [ColumnVector.from_numpy(
+                values[lo:lo + max_rows], f.dtype,
+                None if valid is None else valid[lo:lo + max_rows])
+                for lo in range(0, n, max_rows)]
+        if sp is not None:
+            cols = [c for chunks in out.values() for c in chunks]
+            sp.args = {"columns": len(fields),
+                       "chunks": -(-n // max_rows), "rows": n,
+                       "device_bytes": sum(MV.vector_device_bytes(c)
+                                           for c in cols),
+                       "transfers": sum(c.device_arrays for c in cols)}
+    return out
+
+
 def _upload_run(data: dict, schema: T.Schema, validity: Optional[dict],
                 max_rows: int, fixed: list) -> tuple[list, int]:
     """One run of `ColumnarBatch.chunks_from_numpy`: its full chunks as
     views of the whole columns, cut on the device, and its ragged tail
-    padded on the host as `from_numpy` pads it, all in one `device_put`."""
+    padded on the host as `from_numpy` pads it, all in one `device_put`;
+    then its string columns chunk by chunk in one pass, while the device
+    takes the transfer and runs the split."""
     n = len(next(iter(data.values())))
     body = n - n % max_rows
-    if body < 2 * max_rows or not fixed:
-        batches = [ColumnarBatch.from_numpy(
-            _rows(data, lo, lo + max_rows), schema,
-            _rows(validity, lo, lo + max_rows))
-            for lo in range(0, n, max_rows)]
-        return batches, sum(c.device_arrays
-                            for b in batches for c in b.columns)
     validity = validity or {}
-    host, narrowed = [], set()
-    for f in fixed:
-        values = np.asarray(data[f.name])
-        safe = host_storage(values, f.dtype)
-        host += [safe, host_validity(values, validity.get(f.name))]
-        narrow = host_narrow(safe, f.dtype)
-        if narrow is not None:
-            narrowed.add(f.name)
-            host.append(narrow)
-    tail_cap = bucket_capacity(n - body)
-    sent = jax.device_put([a[:body] for a in host] + (
-        [_pad_to(a[body:], tail_cap) for a in host] if body < n else []))
-    transfers = len(sent)
-    whole, tail = sent[:len(host)], sent[len(host):]
-    chunks = _split_chunks_jit(whole, max_rows)
-    # the whole columns leave the device once the split has run: the
-    # source is never held twice for longer than that
-    del sent, whole
-    if tail:
-        chunks.append(tail)
+    grouped = body >= 2 * max_rows and bool(fixed)
+    chunks, narrowed, transfers = [], set(), 0
+    if grouped:
+        host = []
+        for f in fixed:
+            values = np.asarray(data[f.name])
+            safe = host_storage(values, f.dtype)
+            host += [safe, host_validity(values, validity.get(f.name))]
+            narrow = host_narrow(safe, f.dtype)
+            if narrow is not None:
+                narrowed.add(f.name)
+                host.append(narrow)
+        tail_cap = bucket_capacity(n - body)
+        sent = jax.device_put([a[:body] for a in host] + (
+            [_pad_to(a[body:], tail_cap) for a in host] if body < n else []))
+        transfers = len(sent)
+        whole, tail = sent[:len(host)], sent[len(host):]
+        chunks = _split_chunks_jit(whole, max_rows)
+        # the whole columns leave the device once the split has run: the
+        # source is never held twice for longer than that
+        del sent, whole
+        if tail:
+            chunks.append(tail)
+    strings = _upload_strings(data, schema, validity, max_rows)
+    transfers += sum(c.device_arrays for cs in strings.values() for c in cs)
     batches = []
-    for lo, arrays in zip(range(0, n, max_rows), chunks):
+    for i, lo in enumerate(range(0, n, max_rows)):
         rows = min(max_rows, n - lo)
-        arrays, cols = iter(arrays), []
+        arrays, cols = iter(chunks[i] if grouped else ()), []
         for f in schema.fields:
             if f.dtype.is_string:
-                valid = validity.get(f.name)
-                cols.append(ColumnVector.from_numpy(
-                    np.asarray(data[f.name][lo:lo + rows]), f.dtype,
-                    None if valid is None else valid[lo:lo + rows],
-                    bucket_capacity(rows)))
-                transfers += cols[-1].device_arrays
-            else:
+                cols.append(strings[f.name][i])
+            elif grouped:
                 cols.append(ColumnVector(
                     f.dtype, next(arrays), next(arrays), None,
                     next(arrays) if f.name in narrowed else None))
+            else:
+                valid = validity.get(f.name)
+                cols.append(ColumnVector.from_numpy(
+                    np.asarray(data[f.name][lo:lo + rows]), f.dtype,
+                    None if valid is None else valid[lo:lo + rows]))
+                transfers += cols[-1].device_arrays
         _record_upload(cols, rows)
         batches.append(ColumnarBatch(schema, cols, rows))
     return batches, transfers
@@ -373,9 +405,11 @@ class ColumnarBatch:
         host as `from_numpy` pads it.  What is grouped follows what the
         call sees, no conf: a run with fewer than two full chunks takes
         `from_numpy`'s path, and so does every string column chunk by
-        chunk (its `char_cap` is bucketed per chunk); a run holds whole
-        chunks up to `UPLOAD_TRANSFER_BYTES`.  An INT64 `narrow` shadow
-        is decided once a run: there when the whole run fits int32."""
+        chunk (its `char_cap` is bucketed per chunk), a run's string
+        columns in one pass after its fixed-width ones are sent; a run holds
+        whole chunks up to `UPLOAD_TRANSFER_BYTES`.  An INT64 `narrow`
+        shadow is decided once a run: there when the whole run fits
+        int32."""
         n = len(next(iter(data.values()))) if data else 0
         fixed = [f for f in schema.fields if not f.dtype.is_string]
         # storage + validity + at most a 4-byte shadow

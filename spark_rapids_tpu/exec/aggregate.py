@@ -24,7 +24,7 @@ import jax.numpy as jnp
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import (
-    ColumnarBatch, concat_batches, programs_of)
+    ColumnarBatch, concat_batches, programs_of, rows_made_known)
 from spark_rapids_tpu.columnar.vector import (MIN_CAPACITY, ColumnVector,
                                               bucket_capacity)
 from spark_rapids_tpu.exec.base import (
@@ -1147,6 +1147,9 @@ class HashAggregateExec(UnaryExecBase):
         runs: list = []
         external = False
         run_target = max(1, OC.window_bytes(conf) // OC.MERGE_FAN_IN)
+        # the capacity every merge kernel call was given (`_merge_one`),
+        # for the merge span's args (host-known; profiled queries only)
+        given = None if P.tracer() is None else []
 
         def flush_state():
             """Compact the pending partials to one batch of groups and
@@ -1157,7 +1160,7 @@ class HashAggregateExec(UnaryExecBase):
             if not partials:
                 return
             merged = partials[0] if len(partials) == 1 else \
-                self._merge_partials(partials, inter_fields)
+                self._merge_partials(partials, inter_fields, given)
             runs.append(OC.spill_run(merged.dense(), label=self.name(),
                                      metrics=self.metrics, conf=conf))
             partials = []
@@ -1200,12 +1203,12 @@ class HashAggregateExec(UnaryExecBase):
             if runs:
                 flush_state()
                 merged = self._merge_spilled_state(runs, inter_fields,
-                                                   conf)
+                                                   conf, given)
             else:
                 # concat + re-merge loop until one batch of groups
                 # remains
                 merged = partials[0] if len(partials) == 1 else \
-                    self._merge_partials(partials, inter_fields)
+                    self._merge_partials(partials, inter_fields, given)
 
             if self.mode == AggMode.PARTIAL:
                 out = merged
@@ -1221,6 +1224,8 @@ class HashAggregateExec(UnaryExecBase):
                 me = getattr(self, "_merge_exec", None)
                 sp.args = {"lane": me._lane if me is not None else None,
                            "partials": len(partials),
+                           "capacity_rows": max(given or (), default=0),
+                           "rounds": len(given or ()),
                            # None: the count is still on the device
                            "groups": out._rows if out.num_rows_known
                            else None}
@@ -1277,7 +1282,7 @@ class HashAggregateExec(UnaryExecBase):
         return me
 
     def _merge_spilled_state(self, runs: list, inter_schema,
-                             conf) -> ColumnarBatch:
+                             conf, given=None) -> ColumnarBatch:
         """Windowed re-merge of spilled partial-aggregation state: each
         pass reads back window-sized groups of runs, merges each to one
         compacted batch of groups, and re-spills until a single block
@@ -1324,7 +1329,7 @@ class HashAggregateExec(UnaryExecBase):
                     W.maybe_hang("oocore-merge", conf)
                     batches = [r.read(self.metrics) for r in group]
                     merged = batches[0] if len(batches) == 1 else \
-                        self._merge_partials(batches, inter_schema)
+                        self._merge_partials(batches, inter_schema, given)
                     for r in group:
                         r.free()
                     hb.beat()
@@ -1339,10 +1344,25 @@ class HashAggregateExec(UnaryExecBase):
         final.free()
         return batch
 
-    def _merge_partials(self, partials, inter_schema) -> ColumnarBatch:
+    def _merge_partials(self, partials, inter_schema,
+                        given: Optional[list] = None) -> ColumnarBatch:
+        """The partials of a partition made one batch of groups.  Each
+        partial is compacted to `_compact_cap` slots with its group
+        count on the device, so their lazy concat has the bucketed SUM
+        of those capacities (46 partials of TPC-H q1 at SF1: 2^20 slots
+        for 184 rows, and concat + merge at that size were 42% of the
+        device's time and 203 s of a cold compile).  The merge is a
+        barrier, so past one batch of padding the counts come to the
+        host in one stacked read and the concat is tight: the rule of
+        `HashJoinExec._concat_build`.  `given`, where the caller keeps
+        one, collects the capacity every merge kernel call was handed
+        (`_merge_one`)."""
+        from spark_rapids_tpu import config as C
         # sparse_ok: the merge kernel takes a deferred-selection mask,
         # so the concat can stay gather-free
         with programs_of("agg"):
+            rows_made_known(partials, "agg.merge", beyond=bucket_capacity(
+                int(C.get_active_conf()[C.MAX_BATCH_ROWS])))
             merged = concat_batches(partials, sparse_ok=True)
         merge_exec = self._get_merge_exec(inter_schema)
         # the merge phase is the aggregate's known OOM hotspot: under
@@ -1353,7 +1373,7 @@ class HashAggregateExec(UnaryExecBase):
         # bounds the recursion)
         outs = list(self.oom_retry_batches(
             merged,
-            lambda b: self._merge_one(merge_exec, b, inter_schema),
+            lambda b: self._merge_one(merge_exec, b, inter_schema, given),
             label=f"{self.name()}.mergePartials"))
         if len(outs) == 1:
             return outs[0]
@@ -1371,11 +1391,13 @@ class HashAggregateExec(UnaryExecBase):
                 sum(o.num_rows for o in outs), len(outs))
             with programs_of("agg"):
                 whole = concat_batches(outs, sparse_ok=True)
-            return self._merge_one(merge_exec, whole, inter_schema)
-        return self._merge_partials(outs, inter_schema)
+            return self._merge_one(merge_exec, whole, inter_schema, given)
+        return self._merge_partials(outs, inter_schema, given)
 
-    def _merge_one(self, merge_exec, merged, inter_schema
-                   ) -> ColumnarBatch:
+    def _merge_one(self, merge_exec, merged, inter_schema,
+                   given: Optional[list] = None) -> ColumnarBatch:
+        if given is not None:
+            given.append(merged.capacity)
         wcap = self._kernel_compact_cap(merged)
         with self.metrics.timed(M.TOTAL_TIME):
             kern = merge_exec._groupby_kernel(merged, "merge", wcap)

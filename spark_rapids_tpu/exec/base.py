@@ -179,6 +179,31 @@ def kernel_cache_compile_ms() -> float:
         return _COMPILE_NS_TOTAL / 1e6
 
 
+class _ColdKernel:
+    """A freshly built kernel until its first dispatch has returned:
+    `jax.jit` compiles there and not in the builder, so that one call
+    runs under `watchdog.compiling`.  Afterwards a plain forward.
+    Attribute reads fall through to the kernel (`lower`, site-attached
+    labels), as `kernelprof.WatchedKernel`'s do."""
+
+    def __init__(self, label: str, fn):
+        self._ck_label = label
+        self._ck_fn = fn
+        self._ck_cold = True
+
+    def __call__(self, *args, **kwargs):
+        if not self._ck_cold:
+            return self._ck_fn(*args, **kwargs)
+        from spark_rapids_tpu.utils import watchdog as W
+        with W.compiling(self._ck_label):
+            out = self._ck_fn(*args, **kwargs)
+        self._ck_cold = False
+        return out
+
+    def __getattr__(self, name):
+        return getattr(self._ck_fn, name)
+
+
 class KernelCache:
     """Caches jitted executables per (scope, key, signature).
 
@@ -195,25 +220,26 @@ class KernelCache:
     @staticmethod
     def _build_watched(key, builder: Callable[[], Callable],
                        kp_entry=None):
-        """Run the (seconds-to-minutes) trace/compile under a
-        compile-class watchdog heartbeat, with the compile hang-
-        injection site in front so a wedged XLA compile is testable.
-        A profiled query additionally records the compile as a span
-        (cat 'compile'), so cold-start cost is attributable in the
+        """Run the builder, and later the kernel's first dispatch (where
+        a lazy `jax.jit` traces and compiles: seconds to minutes),
+        under a compile-class watchdog heartbeat with the enclosing
+        task's heartbeats paused (`watchdog.compiling`); the compile
+        hang-injection site is in front so a wedged XLA compile is
+        testable.  A profiled query additionally records the build as a
+        span (cat 'compile'), so cold-start cost is attributable in the
         wall-clock breakdown; with kernel attribution on, the builder
         wall time also lands on the kernel's catalog entry
-        (utils/kernelprof.py — the first DISPATCH, where a lazy jit
-        actually compiles, is timed there separately)."""
+        (utils/kernelprof.py — the first DISPATCH is timed there
+        separately)."""
         from spark_rapids_tpu.utils import profile as P
         from spark_rapids_tpu.utils import watchdog as W
         label = f"compile:{key!r:.120}"
-        with W.heartbeat(label, kind="compile"), \
-                P.span(label, cat=P.CAT_COMPILE):
+        with W.compiling(label), P.span(label, cat=P.CAT_COMPILE):
             W.maybe_hang("compile")
             import time as _time
             t0 = _time.perf_counter_ns()
             try:
-                return builder()
+                fn = builder()
             finally:
                 global _COMPILE_NS_TOTAL, _COMPILE_COUNT
                 dt = _time.perf_counter_ns() - t0
@@ -222,6 +248,7 @@ class KernelCache:
                     _COMPILE_COUNT += 1
                 if kp_entry is not None:
                     kp_entry.note_build(dt)
+        return _ColdKernel(label, fn) if callable(fn) else fn
 
     def _kp_identity(self, key: tuple) -> tuple:
         """Catalog identity for a kernel of this cache: the structural

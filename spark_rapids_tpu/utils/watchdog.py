@@ -20,7 +20,9 @@ Three pieces:
   (`spark.rapids.sql.watchdog.taskTimeout` / `.collectiveTimeout` /
   `.compileTimeout`).  `beat()` on every unit of progress; `pause()`
   around waits attributable to a *different* watched party (a producer
-  parked on a full queue is the consumer's problem, not a hang).
+  parked on a full queue is the consumer's problem, not a hang; a
+  task whose thread sits in the XLA compiler is the compile
+  heartbeat's: `compiling()`).
 * **Scanner** — a daemon thread polls registered heartbeats every
   `watchdog.pollInterval` seconds.  No progress past the deadline
   emits ONE diagnostic dump (all thread stacks, TpuSemaphore holders,
@@ -47,7 +49,7 @@ import sys
 import threading
 import time
 import traceback
-from contextlib import contextmanager
+from contextlib import ExitStack, contextmanager
 from typing import Callable, Optional
 
 from spark_rapids_tpu import config as C
@@ -496,6 +498,24 @@ def heartbeat(name: str, kind: str = "task",
 def active_heartbeats() -> list[Heartbeat]:
     with _HB_LOCK:
         return list(_HEARTBEATS.values())
+
+
+@contextmanager
+def compiling(label: str, conf: Optional[C.RapidsConf] = None):
+    """An XLA compile on this thread: watched by a compile-class
+    heartbeat of its own, while every heartbeat this thread registered
+    before it pauses.  The compiler's minutes are the compiler's
+    staleness, not the enclosing task's: a producer task that compiles
+    three kernels back to back on an empty cache made no progress the
+    task deadline should count (a cold TPC-H q1 on the chip: 74 + 203 +
+    12 s inside one exchange-map task against a 300 s deadline)."""
+    me = threading.get_ident()
+    mine = [hb for hb in active_heartbeats() if hb.thread_id == me]
+    with ExitStack() as held:
+        for hb in mine:
+            held.enter_context(hb.pause())
+        with heartbeat(label, kind="compile", conf=conf):
+            yield
 
 
 # ---------------------------------------------------------------------------

@@ -226,6 +226,56 @@ def test_grouped_upload_equals_per_chunk_from_numpy(kind, n, max_rows):
         assert sent == arrays * (1 + (n % max_rows > 0))
 
 
+def _flags(n, long_at):
+    """One-character flags with nulls, as q1's two keys are, and one
+    value in the chunk of row `long_at` that is past the smallest
+    `char_cap` bucket."""
+    v = np.array([None if i % 13 == 5 else "ANR"[i % 3] for i in range(n)],
+                 dtype=object)
+    v[long_at] = "RETURNED-" * 3
+    return v
+
+
+@pytest.mark.parametrize("fixed_kinds,n,max_rows", [
+    (["float64", "date32"], 1000, 128),     # grouped: 7 chunks and a tail
+    (["float64", "date32"], 1024, 128),     # grouped, no tail
+    (["int64"], 200, 128),                  # one full chunk: per chunk
+    ([], 1000, 128),                        # string columns alone
+], ids=["grouped-ragged-tail", "grouped-no-tail", "under-two-chunks",
+        "strings-alone"])
+def test_string_columns_of_a_run_equal_per_chunk_from_numpy(
+        fixed_kinds, n, max_rows):
+    """A run's string columns are built in one pass, after its
+    fixed-width ones are sent: the batches are still those of a chunk-by-chunk
+    `from_numpy` (rows, capacities, `char_cap` per chunk, contents) and
+    the strings add the transfers they added chunk by chunk."""
+    data, schema, validity = _host_frame(fixed_kinds, n)
+    fields = list(schema.fields)
+    for name, long_at in (("flag", n // 2), ("status", n - 1)):
+        fields.insert(1 if fixed_kinds else 0, T.Field(name, T.STRING))
+        data[name] = _flags(n, long_at)
+        validity[name] = np.array([v is not None for v in data[name]])
+    schema = T.Schema(tuple(fields))
+    data = {f.name: data[f.name] for f in fields}
+    got, sent = ColumnarBatch.chunks_from_numpy(data, schema, validity,
+                                                max_rows)
+    ref = _per_chunk(data, schema, validity, max_rows)
+    _assert_same_batches(got, ref)
+    chunks = -(-n // max_rows)
+    assert [b.num_rows for b in got] == [max_rows] * (n // max_rows) + (
+        [n % max_rows] if n % max_rows else [])
+    for name, long_at in (("flag", n // 2), ("status", n - 1)):
+        caps = [b.column(name).char_cap for b in got]
+        assert caps == [b.column(name).char_cap for b in ref]
+        assert caps[long_at // max_rows] > min(caps) and len(set(caps)) == 2
+    grouped = n // max_rows >= 2 and fixed_kinds
+    fixed_arrays = sum(c.device_arrays for f, c in
+                       zip(schema.fields, got[0].columns)
+                       if not f.dtype.is_string)
+    assert sent == 2 * 3 * chunks + fixed_arrays * (
+        1 + (n % max_rows > 0) if grouped else chunks)
+
+
 def test_grouped_upload_mixed_frame_without_validity():
     """Every kind in one frame, and `validity=None`: all rows valid but
     the None strings, as `from_numpy` reads them."""

@@ -207,6 +207,87 @@ def test_kernel_single_flight_waiter_timeout_builds_itself():
     clear_kernel_cache()
 
 
+def _slow_first_dispatch(seconds: float):
+    """A builder whose kernel spends `seconds` in its FIRST call and
+    none after: what a lazy `jax.jit` does on an empty compile cache."""
+    calls = []
+
+    def kernel(x):
+        calls.append(x)
+        if len(calls) == 1:
+            time.sleep(seconds)
+        return x + 1
+    return lambda: kernel
+
+
+@pytest.mark.parametrize("private", [True, False],
+                         ids=["exec-private", "process-global"])
+def test_first_dispatch_compile_is_not_the_tasks_staleness(private):
+    """A task whose thread sits in the compiler is the compile
+    heartbeat's: the first dispatch of a freshly built KernelCache
+    kernel runs past the task deadline without the task firing (a cold
+    TPC-H q1 compiled 74 + 203 + 12 s inside one exchange-map task and
+    was cancelled at 300 s), the task's clock restarts when the compile
+    ends, and a warm dispatch pauses nothing."""
+    clear_kernel_cache()
+    tok = W.begin_query()
+    kc = KernelCache() if private else KernelCache(scope=("wd-cold",))
+    with C.session(C.RapidsConf(_wd(deadline=0.3, **{
+            "spark.rapids.sql.watchdog.compileTimeout": 30.0}))):
+        with W.heartbeat("cold-task") as hb:
+            fn = kc.get_or_build(("k",), _slow_first_dispatch(1.0))
+            assert fn(1) == 2
+            assert not tok.cancelled and not hb.fired
+            assert time.monotonic() - hb.last_beat < 0.25
+            # warm: the same entry, no compile heartbeat, nothing paused
+            again = kc.get_or_build(("k",), _slow_first_dispatch(9.0))
+            before = {h.name for h in W.active_heartbeats()}
+            assert again(2) == 3
+            assert {h.name for h in W.active_heartbeats()} == before
+            # and the task's own silence still fires it
+            assert tok.wait(2 * 0.3 + 1.0), "watchdog never fired"
+            assert "cold-task" in tok.reason
+    clear_kernel_cache()
+
+
+def test_compiling_is_watched_and_pauses_this_thread_only():
+    """`watchdog.compiling`: a compile-class heartbeat of its own (a
+    wedged compiler still times out), this thread's heartbeats paused,
+    another thread's left to their own deadline."""
+    tok = W.begin_query()
+    other = {}
+    made = threading.Event()
+    done = threading.Event()
+
+    def elsewhere():
+        with C.session(C.RapidsConf(_wd(deadline=60.0))):
+            with W.heartbeat("other-thread") as hb:
+                other["hb"] = hb
+                made.set()
+                done.wait(10.0)
+
+    t = threading.Thread(target=elsewhere, daemon=True)
+    t.start()
+    assert made.wait(5.0)
+    try:
+        with C.session(C.RapidsConf(_wd(deadline=0.3))):
+            with W.heartbeat("compiling-task") as hb:
+                with W.compiling("compile:probe"):
+                    assert hb._paused == 1
+                    assert other["hb"]._paused == 0
+                    kinds = {h.name: h.kind
+                             for h in W.active_heartbeats()}
+                    assert kinds["compile:probe"] == "compile"
+                    # the compile deadline (0.3 s here) is the one
+                    # that fires on a compiler that never returns
+                    assert tok.wait(2 * 0.3 + 1.0)
+                    assert "compile:probe" in tok.reason
+                assert hb._paused == 0 and not hb.fired
+    finally:
+        done.set()
+        t.join(5.0)
+
+
 # ---------------------------------------------------------------------------
 # satellite: leaked producer accounting
 def test_leaked_producer_counted_and_stack_logged(monkeypatch, caplog):
