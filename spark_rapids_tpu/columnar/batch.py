@@ -19,17 +19,21 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import itertools
 import threading
 from typing import Iterable, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pyarrow as pa
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.vector import (
-    ColumnVector, _pad_to, align_char_caps, bucket_capacity, host_narrow,
-    host_storage, host_validity)
+    ColumnVector, _pad_to, _strings_from_host, align_char_caps,
+    bucket_capacity, bucket_char_cap, encode_strings, host_narrow,
+    host_storage, host_strings, host_validity, string_buffers,
+    string_lengths)
 
 #: most host bytes one grouped upload (`ColumnarBatch.chunks_from_numpy`)
 #: sends at once.  A whole SF1 lineitem partition (3 M rows of q6's four
@@ -39,21 +43,34 @@ from spark_rapids_tpu.columnar.vector import (
 UPLOAD_TRANSFER_BYTES = 256 << 20
 
 
-def _split_chunks(arrays, max_rows: int):
+def _split_chunks(arrays, max_rows: int, char_caps: tuple = ()):
     """The device half of a grouped upload: whole columns, a whole
     number of chunks long, cut into chunks of `max_rows` rows, each
     zero-padded to its bucket capacity as `from_numpy` pads on the host.
-    One compiled program per (chunk count, dtypes, max_rows): the ragged
-    tail of a partition does not pass through here, so partitions of any
-    length share it."""
+    An array that `char_caps` gives a tuple of buckets (None or nothing
+    for the others) is a string column's byte matrices laid flat, one
+    after another (`encode_strings`): chunk i is the next
+    `max_rows x char_caps[i]` bytes.  One compiled program per (chunk
+    count, dtypes, max_rows, buckets): the ragged tail of a partition
+    does not pass through here, so partitions of any length share it."""
     pad = bucket_capacity(max_rows) - max_rows
-    return [[jnp.pad(a[lo:lo + max_rows], (0, pad)) if pad
-             else a[lo:lo + max_rows] for a in arrays]
-            for lo in range(0, arrays[0].shape[0], max_rows)]
+    columns = list(itertools.zip_longest(arrays, char_caps))
+    rows = next(a.shape[0] for a, caps in columns if not caps)
+
+    def cut(a, caps, i):
+        if caps:
+            lo = max_rows * sum(caps[:i])
+            part = a[lo:lo + max_rows * caps[i]].reshape(max_rows, caps[i])
+        else:
+            part = a[i * max_rows:(i + 1) * max_rows]
+        return jnp.pad(part, [(0, pad)] + [(0, 0)] * (part.ndim - 1)) \
+            if pad else part
+    return [[cut(a, caps, i) for a, caps in columns]
+            for i in range(rows // max_rows)]
 
 
 _split_chunks.__name__ = "upload_split"       # its name on the device
-_split_chunks_jit = jax.jit(_split_chunks, static_argnums=1)
+_split_chunks_jit = jax.jit(_split_chunks, static_argnums=(1, 2))
 
 
 # ---------------------------------------------------------------------------
@@ -166,34 +183,85 @@ def _record_upload(cols: list[ColumnVector], rows: int) -> None:
 
 
 def _upload_strings(data: dict, schema: T.Schema, validity: dict,
-                    max_rows: int) -> dict:
-    """The string columns of one run, every chunk of each in one pass:
-    {name: [the `ColumnVector.from_numpy` of each `max_rows` rows]}
-    (`char_cap` bucketed per chunk, three arrays a chunk).  One
-    `exec:upload-strings` span holds them all; a schema without a string
-    column opens none."""
+                    max_rows: int) -> tuple[dict, int]:
+    """The string columns of one run: {name: [the
+    `ColumnVector.from_numpy` of each `max_rows` rows]} (`char_cap`
+    bucketed per chunk) and the host-to-device arrays sent.  Two full
+    chunks or more: each column is encoded once from its Arrow buffers
+    (`encode_strings`), all of them go in ONE `device_put` (the body's
+    byte matrices laid flat, validity, lengths; the ragged tail padded
+    on the host) and the split program cuts the body on the device.
+    Fewer: chunk by chunk.  A column that is no Arrow array (Arrow
+    refused it: `chunks_from_numpy`) goes value by value, chunk by
+    chunk, and is counted in `per_value`.
+    One `exec:upload-strings` span holds them all; a schema without a
+    string column opens none."""
     fields = [f for f in schema.fields if f.dtype.is_string]
     if not fields:
-        return {}
+        return {}, 0
     from spark_rapids_tpu.utils import movement as MV
     from spark_rapids_tpu.utils import profile as P
     n = len(data[fields[0].name])
+    body = n - n % max_rows
+    tail_cap = bucket_capacity(n - body)
     with P.span(P.SPAN_UPLOAD_STRINGS) as sp:
-        out = {}
+        out, grouped, host, tails, caps, per_value = {}, [], [], [], [], 0
         for f in fields:
-            values, valid = np.asarray(data[f.name]), validity.get(f.name)
-            out[f.name] = [ColumnVector.from_numpy(
-                values[lo:lo + max_rows], f.dtype,
-                None if valid is None else valid[lo:lo + max_rows])
-                for lo in range(0, n, max_rows)]
+            valid, strings = validity.get(f.name), data[f.name]
+            if not isinstance(strings, pa.Array):   # Arrow refused it
+                per_value += n
+                values = np.asarray(strings)
+                out[f.name] = [_strings_from_host(
+                    values[lo:hi], _pad_to(host_validity(
+                        values[lo:hi], _cut(valid, lo, hi)), cap), cap)
+                    for lo, hi, cap in _chunk_bounds(n, max_rows)]
+            elif body < 2 * max_rows:
+                out[f.name] = [ColumnVector.from_numpy(
+                    strings[lo:hi], f.dtype, _cut(valid, lo, hi))
+                    for lo, hi, _ in _chunk_bounds(n, max_rows)]
+            else:
+                offsets, raw = string_buffers(strings)
+                valid = host_validity(strings, valid)
+                flat, cc, v, lengths = encode_strings(
+                    offsets[:body + 1], raw, valid[:body], max_rows)
+                host += [flat, v, lengths]
+                caps += [cc, None, None]
+                if body < n:
+                    flat, (cc,), v, lengths = encode_strings(
+                        offsets[body:], raw, valid[body:], tail_cap)
+                    tails += [flat.reshape(tail_cap, cc), v, lengths]
+                grouped.append(f.name)
+        transfers = sum(c.device_arrays for cs in out.values()
+                        for c in cs) + len(host) + len(tails)
+        if host:
+            sent = jax.device_put(host + tails)
+            chunks = _split_chunks_jit(sent[:len(host)], max_rows,
+                                       tuple(caps))
+            if tails:
+                chunks.append(sent[len(host):])
+            del sent
+            for k, name in enumerate(grouped):
+                out[name] = [ColumnVector(T.STRING, *chunk[3 * k:3 * k + 3])
+                             for chunk in chunks]
         if sp is not None:
             cols = [c for chunks in out.values() for c in chunks]
             sp.args = {"columns": len(fields),
                        "chunks": -(-n // max_rows), "rows": n,
                        "device_bytes": sum(MV.vector_device_bytes(c)
                                            for c in cols),
-                       "transfers": sum(c.device_arrays for c in cols)}
-    return out
+                       "transfers": transfers, "per_value": per_value}
+    return out, transfers
+
+
+def _cut(mask: Optional[np.ndarray], lo: int, hi: int):
+    return None if mask is None else mask[lo:hi]
+
+
+def _chunk_bounds(n: int, max_rows: int):
+    """(lo, hi, capacity) of each `max_rows` rows of n."""
+    return [(lo, min(lo + max_rows, n),
+             bucket_capacity(min(max_rows, n - lo)))
+            for lo in range(0, n, max_rows)]
 
 
 def _upload_run(data: dict, schema: T.Schema, validity: Optional[dict],
@@ -201,8 +269,9 @@ def _upload_run(data: dict, schema: T.Schema, validity: Optional[dict],
     """One run of `ColumnarBatch.chunks_from_numpy`: its full chunks as
     views of the whole columns, cut on the device, and its ragged tail
     padded on the host as `from_numpy` pads it, all in one `device_put`;
-    then its string columns chunk by chunk in one pass, while the device
-    takes the transfer and runs the split."""
+    then its string columns the same way in a second one
+    (`_upload_strings`), encoded while the device takes the first
+    transfer and runs its split."""
     n = len(next(iter(data.values())))
     body = n - n % max_rows
     validity = validity or {}
@@ -229,8 +298,8 @@ def _upload_run(data: dict, schema: T.Schema, validity: Optional[dict],
         del sent, whole
         if tail:
             chunks.append(tail)
-    strings = _upload_strings(data, schema, validity, max_rows)
-    transfers += sum(c.device_arrays for cs in strings.values() for c in cs)
+    strings, sent = _upload_strings(data, schema, validity, max_rows)
+    transfers += sent
     batches = []
     for i, lo in enumerate(range(0, n, max_rows)):
         rows = min(max_rows, n - lo)
@@ -381,7 +450,10 @@ class ColumnarBatch:
         for name in names:
             dt = schema.field(name).dtype if schema else None
             v = validity.get(name) if validity else None
-            col = ColumnVector.from_numpy(np.asarray(data[name]), dt, v, cap)
+            values = data[name]
+            if not isinstance(values, pa.Array):
+                values = np.asarray(values)
+            col = ColumnVector.from_numpy(values, dt, v, cap)
             cols.append(col)
             fields.append(T.Field(name, col.dtype))
         # from_arrow / from_pandas funnel through here
@@ -398,24 +470,36 @@ class ColumnarBatch:
         few large transfers; also returns how many host-to-device arrays
         went.
 
-        The fixed-width columns of a run of chunks go to the device in
-        one `device_put`: the full chunks whole (data, validity and
-        `narrow` once a column, views of the host columns) for one
-        jitted program to cut there, and the ragged tail padded on the
-        host as `from_numpy` pads it.  What is grouped follows what the
-        call sees, no conf: a run with fewer than two full chunks takes
-        `from_numpy`'s path, and so does every string column chunk by
-        chunk (its `char_cap` is bucketed per chunk), a run's string
-        columns in one pass after its fixed-width ones are sent; a run holds
-        whole chunks up to `UPLOAD_TRANSFER_BYTES`.  An INT64 `narrow`
-        shadow is decided once a run: there when the whole run fits
-        int32."""
+        The columns of a run of chunks go to the device in two
+        `device_put`s, the fixed-width ones first: the full chunks whole
+        (data, validity and `narrow` once a column, views of the host
+        columns; a string column's byte matrices laid flat, its validity
+        and lengths, encoded once from its Arrow buffers) for one jitted
+        program to cut there, and the ragged tail padded on the host as
+        `from_numpy` pads it.  A string column's `char_cap` stays
+        bucketed per chunk.  What is grouped follows what the call sees,
+        no conf: a run with fewer than two full chunks takes
+        `from_numpy`'s path, and so does a string column that Arrow
+        refuses, value by value (`host_strings`); a run holds whole
+        chunks up to `UPLOAD_TRANSFER_BYTES`.  An INT64 `narrow` shadow
+        is decided once a run: there when the whole run fits int32."""
         n = len(next(iter(data.values()))) if data else 0
         fixed = [f for f in schema.fields if not f.dtype.is_string]
         # storage + validity + at most a 4-byte shadow
-        chunk_bytes = max_rows * sum(f.dtype.storage_dtype.itemsize + 5
-                                     for f in fixed)
-        run = max_rows * max(1, UPLOAD_TRANSFER_BYTES // max(1, chunk_bytes))
+        row_bytes = sum(f.dtype.storage_dtype.itemsize + 5 for f in fixed)
+        data = dict(data)
+        for f in schema.fields:
+            if not f.dtype.is_string:
+                continue
+            strings = host_strings(data[f.name])
+            if strings is not None:
+                # the byte matrix at the longest value's bucket +
+                # validity + lengths
+                data[f.name] = strings
+                row_bytes += 5 + bucket_char_cap(int(string_lengths(
+                    string_buffers(strings)[0]).max(initial=0)))
+        run = max_rows * max(1, UPLOAD_TRANSFER_BYTES // max(
+            1, max_rows * row_bytes))
         batches, transfers = [], 0
         for lo in range(0, n, run):
             got, sent = _upload_run(_rows(data, lo, lo + run), schema,
@@ -457,8 +541,7 @@ class ColumnarBatch:
             fields.append(T.Field(name, dt))
             np_valid = ~np.asarray(col.is_null())
             if dt.is_string:
-                data[name] = np.array(
-                    [v.as_py() for v in col], dtype=object)
+                data[name] = col        # its buffers are the host form
             elif dt.id == T.TypeId.TIMESTAMP_US:
                 import pyarrow.compute as pc
                 import pyarrow as pa

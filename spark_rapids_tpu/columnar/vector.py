@@ -20,6 +20,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pyarrow as pa
 
 from spark_rapids_tpu import types as T
 
@@ -71,12 +72,15 @@ def _pad_to(arr: np.ndarray, capacity: int, axis: int = 0) -> np.ndarray:
     return np.pad(arr, pad)
 
 
-def host_validity(values: np.ndarray,
-                  validity: Optional[np.ndarray]) -> np.ndarray:
+def host_validity(values, validity: Optional[np.ndarray]) -> np.ndarray:
     """A column's bool validity on the host, unpadded: the caller's
-    mask, else None-ness of object values, else all True (NaN is a
-    value, not null: Spark)."""
+    mask, else an Arrow array's bitmap, else None-ness of object
+    values, else all True (NaN is a value, not null: Spark)."""
     if validity is None:
+        if isinstance(values, pa.Array):
+            if not values.null_count:
+                return np.ones(len(values), bool)
+            return values.is_valid().to_numpy(zero_copy_only=False)
         if values.dtype == object:
             return np.array([v is not None for v in values], bool)
         return np.ones(len(values), bool)
@@ -130,8 +134,9 @@ class ColumnVector:
     sends each array of one batch by itself.  A plan's in-memory source
     (`plan/overrides._conv_source`) builds the same vectors through
     `ColumnarBatch.chunks_from_numpy`: the host halves here
-    (`host_storage`, `host_validity`, `host_narrow`) run once a column
-    of a partition, the full chunks go to the device whole and are cut
+    (`host_storage`, `host_validity`, `host_narrow`; `host_strings` and
+    `encode_strings` for a STRING column) run once a column of a
+    partition, the full chunks go to the device whole and are cut
     there.  The INT64 shadow is then decided once for the partition's
     run of chunks, not chunk by chunk.
     """
@@ -176,14 +181,23 @@ class ColumnVector:
                    validity: Optional[np.ndarray] = None,
                    capacity: Optional[int] = None) -> "ColumnVector":
         if dtype is None:
-            dtype = T.from_numpy_dtype(values.dtype)
+            dtype = T.STRING if isinstance(values, pa.Array) \
+                else T.from_numpy_dtype(values.dtype)
         n = len(values)
         cap = capacity or bucket_capacity(n)
-        validity = _pad_to(host_validity(values, validity), cap)
+        validity = host_validity(values, validity)
 
         if dtype.is_string:
-            return _strings_from_host(values, validity, cap)
+            strings = host_strings(values)
+            if strings is None:     # Arrow refused the values: one by one
+                return _strings_from_host(values, _pad_to(validity, cap),
+                                          cap)
+            flat, (cc,), validity, lengths = encode_strings(
+                *string_buffers(strings), validity, cap)
+            return ColumnVector(dtype, jnp.asarray(flat.reshape(cap, cc)),
+                                jnp.asarray(validity), jnp.asarray(lengths))
 
+        validity = _pad_to(validity, cap)
         safe = _pad_to(host_storage(values, dtype), cap)
         narrow = host_narrow(safe, dtype)
         return ColumnVector(dtype, jnp.asarray(safe), jnp.asarray(validity),
@@ -419,8 +433,117 @@ def gather_narrowest(c: ColumnVector, indices: jnp.ndarray,
     return ColumnVector(c.dtype, data, valid, None, narrow)
 
 
+def host_strings(values, nan_is_null: bool = False
+                 ) -> Optional[pa.LargeStringArray]:
+    """A string column's host form, its Arrow buffers (offsets, UTF-8
+    bytes, validity bitmap) and never a Python object per value: the
+    `large_string` array a pandas `str` column or a scan already holds
+    (no copy; a partition's slice keeps its offset), or one C pass over
+    an object array of `str` / None (NaN too where the caller's mask
+    says so: pandas).  None where Arrow refuses: a column holding
+    numbers, other objects or bytes that are no UTF-8, which
+    `_strings_from_host` stringifies value by value."""
+    try:
+        if isinstance(values, np.ndarray):
+            if values.dtype.kind == "T":
+                values = values.astype(object)
+            if values.dtype.kind not in "OU":
+                return None
+            return pa.array(values, pa.large_string(),
+                            from_pandas=nan_is_null)
+        if not isinstance(values, (pa.Array, pa.ChunkedArray)):
+            values = pa.array(values)       # pandas: `__arrow_array__`
+        if isinstance(values, pa.ChunkedArray):
+            values = values.combine_chunks()
+        if pa.types.is_string(values.type):
+            values = values.cast(pa.large_string())
+        return values if pa.types.is_large_string(values.type) else None
+    except (pa.ArrowInvalid, pa.ArrowTypeError, pa.ArrowNotImplementedError):
+        return None
+
+
+def string_buffers(strings: pa.LargeStringArray
+                   ) -> tuple[np.ndarray, np.ndarray]:
+    """(offsets int64[n + 1], bytes uint8[...]) of a `large_string`
+    array as numpy views; value i is `bytes[offsets[i]:offsets[i + 1]]`
+    (a slice's offsets do not start at 0)."""
+    _, offsets, raw = strings.buffers()
+    offsets = np.frombuffer(offsets, np.int64)[
+        strings.offset:strings.offset + len(strings) + 1]
+    return offsets, (np.frombuffer(raw, np.uint8) if raw is not None
+                     else np.zeros(0, np.uint8))
+
+
+def string_lengths(offsets: np.ndarray) -> np.ndarray:
+    """Byte lengths int32[n] of the values between n + 1 offsets."""
+    lens = np.empty(len(offsets) - 1, np.int32)
+    np.subtract(offsets[1:], offsets[:-1], out=lens, casting="unsafe")
+    return lens
+
+
+def encode_strings(offsets: np.ndarray, raw: np.ndarray,
+                   validity: np.ndarray, chunk_rows: int
+                   ) -> tuple[np.ndarray, tuple, np.ndarray, np.ndarray]:
+    """Arrow string buffers -> the padded host arrays string vectors are
+    made of, with no Python call per value: the n values laid out in
+    chunks of `chunk_rows` rows (one chunk at least, the last
+    zero-padded), chunk i a row-major `u8[chunk_rows, char_caps[i]]`
+    byte matrix at the bucket of ITS longest value, the matrices one
+    after another in `flat`.  Returns (flat, char_caps, validity,
+    lengths), the last two padded to the chunks' rows; a null's length
+    is 0 and its row zeros, whatever bytes its offsets span.  One chunk
+    (`chunk_rows` = the capacity) is one vector's arrays:
+    `flat.reshape(capacity, char_caps[0])`."""
+    n = len(offsets) - 1
+    chunks = max(1, -(-n // chunk_rows))
+    lens = string_lengths(offsets)
+    if not validity.all():
+        lens[~validity] = 0
+    starts = np.arange(0, n, chunk_rows)
+    caps = tuple(bucket_char_cap(int(m)) for m in
+                 np.maximum.reduceat(lens, starts)) if n \
+        else (MIN_CHAR_CAP,)
+    flat = np.zeros(chunk_rows * sum(caps), np.uint8)
+    longest = int(lens.max()) if n else 0
+    if longest and int(lens.min()) == longest:
+        # equal lengths (no null among them): the bytes ARE the matrix,
+        # but for the padding of each row
+        flat.reshape(-1, caps[0])[:n, :longest] = raw[
+            offsets[0]:offsets[0] + n * longest].reshape(n, longest)
+    elif longest:
+        # ragged: byte k of the valid values' run lands at k + (its
+        # row's place in `flat` - the bytes before its row): one repeat,
+        # one scatter
+        total = int(lens.sum(dtype=np.int64))
+        before = np.cumsum(lens, dtype=np.int64) - lens
+        row = np.arange(n, dtype=np.int64)
+        if len(set(caps)) == 1:
+            place = row * caps[0]
+        else:
+            cc = np.asarray(caps, np.int64)
+            base = chunk_rows * (np.cumsum(cc) - cc)
+            chunk = row // chunk_rows
+            place = base[chunk] + (row - chunk * chunk_rows) * cc[chunk]
+        span = int(offsets[-1] - offsets[0])
+        idx = np.int32 if max(flat.size, span) < 2 ** 31 and span == total \
+            else np.int64
+        k = np.arange(total, dtype=idx)
+        if span == total:
+            src = raw[offsets[0]:offsets[-1]]
+        else:                   # a null's offsets span bytes: skip them
+            src = raw[k + np.repeat(offsets[:-1] - before, lens)]
+        flat[k + np.repeat((place - before).astype(idx), lens)] = src
+    rows = chunks * chunk_rows
+    lengths = np.zeros(rows, np.int32)
+    lengths[:n] = lens
+    return flat, caps, _pad_to(validity, rows), lengths
+
+
 def _strings_from_host(values: np.ndarray, validity_padded: np.ndarray,
                        cap: int) -> ColumnVector:
+    """The per-value path: every value encoded (or stringified) by a
+    Python call.  What `host_strings` refuses takes it, and the tests
+    hold `encode_strings` against it."""
     enc = [(v.encode("utf-8") if isinstance(v, str)
             else (v if isinstance(v, (bytes, bytearray)) else
                   (str(v).encode("utf-8") if v is not None else b"")))
@@ -431,9 +554,6 @@ def _strings_from_host(values: np.ndarray, validity_padded: np.ndarray,
     cc = bucket_char_cap(max_len)
     data = np.zeros((cap, cc), np.uint8)
     if n and lens.any():
-        # one pass: scatter the concatenated bytes into the padded
-        # matrix at vectorized flat offsets (the per-row copy loop was
-        # the hot spot of every host->device string upload)
         flat = np.frombuffer(b"".join(enc), np.uint8)
         starts = np.zeros(n, np.int64)
         np.cumsum(lens[:-1], out=starts[1:])

@@ -303,10 +303,12 @@ def _conv_source(meta, kids) -> TpuExec:
     # The batches are chunks; the TRANSFERS are not.  A host-to-device
     # call costs about a quarter of a millisecond on the v5e whatever it
     # carries, so a partition is converted once and its fixed-width
-    # columns go to the device whole, in one `device_put`; a jitted
+    # columns go to the device whole, the fixed-width ones in one
+    # `device_put` and the string ones (encoded from their Arrow
+    # buffers, never a Python object a value) in a second; a jitted
     # split program cuts them there into the batches a chunk-by-chunk
-    # `from_numpy` would give (`ColumnarBatch.chunks_from_numpy`: one
-    # chunk, and string columns, still go chunk by chunk).
+    # `from_numpy` would give (`ColumnarBatch.chunks_from_numpy`: a run
+    # under two full chunks still goes chunk by chunk).
     max_rows = meta.conf[C.MAX_BATCH_ROWS]
     schema = node.output_schema()
     # the upload is eager and most of a hot scan query's wall: one span

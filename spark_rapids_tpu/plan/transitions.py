@@ -19,6 +19,7 @@ import pandas as pd
 from spark_rapids_tpu import config as C
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
+from spark_rapids_tpu.columnar.vector import host_strings
 from spark_rapids_tpu.exec.base import (
     CoalesceGoal, LeafExec, TargetSize, TpuExec, max_goal)
 from spark_rapids_tpu.exec.coalesce import CoalesceBatchesExec
@@ -31,7 +32,12 @@ from spark_rapids_tpu.utils import metrics as M
 def host_columns_from_df(df: pd.DataFrame, schema: T.Schema
                          ) -> tuple[dict, dict]:
     """The host half of `batch_from_df`: pandas columns -> numpy storage
-    arrays and validity masks, nothing on the device yet."""
+    arrays and validity masks, nothing on the device yet.  A STRING
+    column stays columnar: the Arrow `large_string` array a pandas `str`
+    column holds (a partition's slice keeps it, at an offset), or one
+    made of an object column in one pass (`host_strings`; None and NaN
+    null).  Only what Arrow refuses (numbers, other objects) becomes an
+    object array of Python values, for the per-value encoder."""
     data, validity = {}, {}
     for f in schema.fields:
         s = df[f.name]
@@ -40,7 +46,10 @@ def host_columns_from_df(df: pd.DataFrame, schema: T.Schema
             s = normalize_df(df[[f.name]], T.Schema((f,)))[f.name]
         mask = s.isna().to_numpy() if hasattr(s, "isna") else None
         if f.dtype.is_string:
-            data[f.name] = np.array(
+            strings = host_strings(
+                s.to_numpy() if s.dtype == object else s.array,
+                nan_is_null=True)
+            data[f.name] = strings if strings is not None else np.array(
                 [None if m else v for v, m in zip(s.tolist(), mask)],
                 dtype=object)
         else:

@@ -73,7 +73,7 @@ SPAN_ACCELERATE = "accelerate"            # plan:accelerate
 SPAN_SOURCE_UPLOAD = "SourceUpload"       # exec:SourceUpload[s<k>]
 SPAN_UPLOAD_CONVERT = "upload-convert"    # per partition: pandas -> numpy
 SPAN_UPLOAD_PUT = "upload-put"            # per partition: pad + device_put
-SPAN_UPLOAD_STRINGS = "upload-strings"    # inside it, per run: string columns
+SPAN_UPLOAD_STRINGS = "upload-strings"    # inside it, per run: encode + put + cut
 SPAN_READBACK = "Readback"                # the device-to-host half
 #: one span per exec, partition and phase (never per batch): what a
 #: trace reader splits a join / exchange / group-by query's host time by

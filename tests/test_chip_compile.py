@@ -219,3 +219,25 @@ def test_upload_split_compiles_for_v5e(one_chip):
     outs = jax.tree_util.tree_leaves(compiled.out_info)
     assert len(outs) == 45 * 11 and {o.shape for o in outs} == {(CAP,)}
     assert compiled.memory_analysis() is not None
+
+
+def test_upload_split_of_string_columns_compiles_for_v5e(one_chip):
+    """The same program over a run's string columns: q1's two
+    one-character keys, 45 full chunks each (byte matrices laid flat at
+    `char_cap` 8, validity, lengths), one chunk of one column at a
+    longer bucket."""
+    from spark_rapids_tpu.columnar.batch import _split_chunks_jit
+    rows = 45 * CAP
+    caps = [(8,) * 45, (8,) * 44 + (32,)]
+    arrays, char_caps = [], []
+    for cc in caps:
+        arrays += [_spec(one_chip, (CAP * sum(cc),), jnp.uint8),
+                   _spec(one_chip, (rows,), jnp.bool_),
+                   _spec(one_chip, (rows,), jnp.int32)]
+        char_caps += [cc, None, None]
+    compiled = _split_chunks_jit.lower(arrays, CAP,
+                                       tuple(char_caps)).compile()
+    outs = jax.tree_util.tree_leaves(compiled.out_info)
+    assert len(outs) == 45 * 6
+    assert {o.shape for o in outs} == {(CAP,), (CAP, 8), (CAP, 32)}
+    assert compiled.memory_analysis() is not None

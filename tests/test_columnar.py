@@ -2,12 +2,13 @@
 GpuCoalesceBatchesSuite concat)."""
 import numpy as np
 import pandas as pd
+import pyarrow as pa
 import pytest
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnarBatch, concat_batches
 from spark_rapids_tpu.columnar.vector import (
-    ColumnVector, bucket_capacity)
+    ColumnVector, _strings_from_host, bucket_capacity)
 
 
 def test_bucket_capacity():
@@ -245,10 +246,12 @@ def _flags(n, long_at):
         "strings-alone"])
 def test_string_columns_of_a_run_equal_per_chunk_from_numpy(
         fixed_kinds, n, max_rows):
-    """A run's string columns are built in one pass, after its
-    fixed-width ones are sent: the batches are still those of a chunk-by-chunk
-    `from_numpy` (rows, capacities, `char_cap` per chunk, contents) and
-    the strings add the transfers they added chunk by chunk."""
+    """A run's string columns are encoded once and cut on the device,
+    after its fixed-width ones are sent: the batches are still those of
+    a chunk-by-chunk `from_numpy` (rows, capacities, `char_cap` per
+    chunk, contents), and those of the per-value path; two full chunks
+    or more send three arrays a column and run (three more with a
+    tail), whatever stands beside them."""
     data, schema, validity = _host_frame(fixed_kinds, n)
     fields = list(schema.fields)
     for name, long_at in (("flag", n // 2), ("status", n - 1)):
@@ -268,12 +271,118 @@ def test_string_columns_of_a_run_equal_per_chunk_from_numpy(
         caps = [b.column(name).char_cap for b in got]
         assert caps == [b.column(name).char_cap for b in ref]
         assert caps[long_at // max_rows] > min(caps) and len(set(caps)) == 2
-    grouped = n // max_rows >= 2 and fixed_kinds
+        for b, lo in zip(got, range(0, n, max_rows)):
+            _assert_same_vector(b.column(name), _per_value(
+                data[name][lo:lo + max_rows], b.capacity))
+    runs = n // max_rows >= 2
     fixed_arrays = sum(c.device_arrays for f, c in
                        zip(schema.fields, got[0].columns)
                        if not f.dtype.is_string)
-    assert sent == 2 * 3 * chunks + fixed_arrays * (
-        1 + (n % max_rows > 0) if grouped else chunks)
+    tail = n % max_rows > 0
+    assert sent == 2 * 3 * (1 + tail if runs else chunks) + fixed_arrays * (
+        1 + tail if runs and fixed_kinds else chunks)
+
+
+def _per_value(values, capacity):
+    """The oracle: `_strings_from_host`, a Python call a value."""
+    valid = np.array([v is not None for v in values], bool)
+    return _strings_from_host(
+        np.asarray(values, object), np.pad(valid, (0, capacity - len(valid))),
+        capacity)
+
+
+def _assert_same_vector(got, ref):
+    for x, y in ((got.data, ref.data), (got.validity, ref.validity),
+                 (got.lengths, ref.lengths)):
+        assert x.dtype == y.dtype and x.shape == y.shape
+        assert np.array_equal(np.asarray(x), np.asarray(y))
+
+
+_ARROW_STR = pd.StringDtype(na_value=np.nan)
+
+
+def _nulls_that_span_bytes():
+    """An Arrow array whose null slots keep their bytes (a filter or a
+    cast may leave such): every third value nulled in the bitmap only."""
+    whole = pa.array(["ab", "cde", "f", "", "ghij", "k"] * 50,
+                     pa.large_string())
+    bitmap = pa.array([i % 3 != 1 for i in range(len(whole))]).buffers()[1]
+    return pd.Series(pd.array(pa.Array.from_buffers(
+        pa.large_string(), len(whole), [bitmap] + whole.buffers()[1:]),
+        dtype=_ARROW_STR))
+
+
+_WORDS = ["ab", "", "Zoë", None, "日本", "c", np.nan, "defg"]
+_STRING_COLUMNS = {
+    "arrow-backed": lambda: pd.Series(
+        [w for w in _WORDS * 40 if isinstance(w, str)], dtype=_ARROW_STR),
+    "object": lambda: pd.Series(
+        [w for w in _WORDS * 40 if isinstance(w, str)], dtype=object),
+    "arrow-slice-at-an-offset": lambda: pd.Series(
+        _WORDS * 60, dtype=_ARROW_STR).iloc[101:401],
+    "nulls-none-and-nan-arrow": lambda: pd.Series(_WORDS * 40,
+                                                  dtype=_ARROW_STR),
+    "nulls-none-and-nan-object": lambda: pd.Series(_WORDS * 40,
+                                                   dtype=object),
+    "empty-strings": lambda: pd.Series([""] * 300, dtype=_ARROW_STR),
+    "all-null-chunk": lambda: pd.Series(
+        ["x"] * 128 + [None] * 128 + ["yz"] * 44, dtype=_ARROW_STR),
+    "equal-lengths": lambda: pd.Series(list("ANR") * 100,
+                                       dtype=_ARROW_STR),
+    "multi-byte-utf8": lambda: pd.Series(["Zoë", "日本", "ü", "naïve"] * 75,
+                                         dtype=object),
+    "one-long-among-short": lambda: pd.Series(
+        ["s"] * 200 + ["RETURNED-" * 9] + ["t"] * 99, dtype=_ARROW_STR),
+    "zero-rows": lambda: pd.Series([], dtype=_ARROW_STR),
+    "nulls-that-span-bytes": _nulls_that_span_bytes,
+    "bytes": lambda: pd.Series([b"ab", "c", None, b"Zo\xc3\xab"] * 75,
+                               dtype=object),
+    "bytes-and-numbers": lambda: pd.Series(
+        [b"ab", 12, 3.5, "x", None, b"\xff\xfe"] * 50, dtype=object),
+}
+
+
+@pytest.mark.parametrize("kind", list(_STRING_COLUMNS))
+def test_string_encoder_equals_the_per_value_path(kind):
+    """A STRING column from pandas to the device through its Arrow
+    buffers: one chunk (`from_numpy`) and a run cut on the device
+    (`chunks_from_numpy`) are array-equal (bytes, validity, lengths,
+    `char_cap`) to `_strings_from_host`, which encodes every value by a
+    Python call.  Only what Arrow refuses takes that path: the span's
+    `per_value` counts those values."""
+    from spark_rapids_tpu import config as C
+    from spark_rapids_tpu.plan.transitions import host_columns_from_df
+    from spark_rapids_tpu.utils import profile as P
+    s = _STRING_COLUMNS[kind]()
+    n, max_rows = len(s), 128
+    schema = T.Schema((T.Field("s", T.STRING),))
+    data, validity = host_columns_from_df(pd.DataFrame({"s": s}), schema)
+    refused = kind == "bytes-and-numbers"
+    if refused:
+        assert data["s"].dtype == object
+    else:       # no Python object per value, at a slice's offset too
+        assert isinstance(data["s"], pa.LargeStringArray)
+        assert (data["s"].offset > 0) == (kind == "arrow-slice-at-an-offset")
+    values = [None if null else v for v, null in zip(s.tolist(), s.isna())]
+    assert validity["s"].tolist() == [v is not None for v in values]
+    one = ColumnVector.from_numpy(data["s"], T.STRING, validity["s"])
+    _assert_same_vector(one, _per_value(values, bucket_capacity(n)))
+    tr = P.begin_plan(C.RapidsConf({"spark.rapids.sql.profile.enabled": True}))
+    try:
+        got, sent = ColumnarBatch.chunks_from_numpy(data, schema, validity,
+                                                    max_rows)
+    finally:
+        P.park_plan(tr)
+    assert len(got) == -(-n // max_rows)
+    for b, lo in zip(got, range(0, n, max_rows)):
+        _assert_same_vector(b.columns[0], _per_value(
+            values[lo:lo + max_rows], b.capacity))
+    spans = [x for x in tr.spans() if x.name == P.SPAN_UPLOAD_STRINGS]
+    assert len(spans) == (n > 0)
+    if n:
+        assert spans[0].args["per_value"] == (n if refused else 0)
+        assert spans[0].args["transfers"] == sent == (
+            3 * len(got) if refused else 3 * (1 + (n % max_rows > 0)))
 
 
 def test_grouped_upload_mixed_frame_without_validity():

@@ -1,5 +1,6 @@
-"""What a profiled TPC-H q1 says of itself: the string upload's span,
-the group-by merge's capacity and rounds, and the lane a FLOAT64
+"""What a profiled TPC-H q1 says of itself: the string upload's span
+(and that no `str` column is handled a value at a time on its way to
+the device), the group-by merge's capacity and rounds, and the lane a FLOAT64
 measure grouped on STRING keys takes under the default conf (ROADMAP
 Queue 2, first list #3: the test to turn when a fast lane takes it).
 """
@@ -44,7 +45,8 @@ def _run(query, tables, profile):
 @pytest.fixture(scope="module")
 def tables():
     from benchmark.gen import tpch
-    return tpch.generate(2 ** 31 + 33, SCALE, ["lineitem"])
+    return tpch.generate(2 ** 31 + 33, SCALE,
+                         ["lineitem", "orders", "customer"])
 
 
 @pytest.fixture(scope="module")
@@ -71,16 +73,55 @@ def test_q1_opens_one_string_upload_span_a_partition(q1, tables):
         put = by_id[s.parent_id]            # inside the partition's put
         assert put.t0 <= s.t0 and s.t0 + s.dur_ns <= put.t0 + put.dur_ns
         assert set(s.args) == {"columns", "chunks", "rows", "device_bytes",
-                               "transfers"}
+                               "transfers", "per_value"}
         assert s.args["columns"] == 2       # l_returnflag, l_linestatus
         assert s.args["chunks"] == put.args["chunks"] == 3
         assert s.args["rows"] == put.args["rows"]
-        # byte matrix + validity + lengths a column and chunk; q1's five
+        # byte matrices + validity + lengths a column, once for the
+        # run's two full chunks and once for its tail; q1's five
         # fixed-width columns are the rest of the put's arrays
-        assert s.args["transfers"] == 3 * 2 * s.args["chunks"]
+        tail = s.args["rows"] % CHUNK_ROWS > 0
+        assert tail and s.args["transfers"] == 3 * s.args["columns"] * (
+            1 + tail)
+        assert s.args["per_value"] == 0     # no Python call a value
         assert 0 < s.args["transfers"] < put.args["transfers"]
         assert 0 < s.args["device_bytes"] < put.args["device_bytes"]
     assert sum(s.args["rows"] for s in strings) == len(tables["lineitem"])
+
+
+@pytest.mark.parametrize("query", [1, 3])
+def test_no_str_column_takes_the_per_value_path(query, tables, monkeypatch):
+    """q1's two keys and q3's `c_mktsegment` reach the device from their
+    Arrow buffers: with the per-value encoder taken away both still
+    answer, and as they answered with it."""
+    from spark_rapids_tpu.columnar import batch as CB
+    from spark_rapids_tpu.columnar import vector as CV
+    expected, _, _ = _run(query, tables, False)
+
+    def per_value(*_a, **_k):
+        raise AssertionError("a string column went value by value")
+    monkeypatch.setattr(CV, "_strings_from_host", per_value)
+    monkeypatch.setattr(CB, "_strings_from_host", per_value)
+    answer, _, _ = _run(query, tables, False)
+    assert len(answer) and answer.equals(expected)
+
+
+def test_an_arrow_backed_frame_builds_no_object_array(tables):
+    import numpy as np
+    import pyarrow as pa
+    from spark_rapids_tpu.models.tpch_data import SCHEMAS
+    from spark_rapids_tpu.plan.transitions import host_columns_from_df
+    part = tables["lineitem"].iloc[7:5000]      # a partition's slice
+    data, validity = host_columns_from_df(part, SCHEMAS["lineitem"])
+    strings = [f.name for f in SCHEMAS["lineitem"].fields
+               if f.dtype.is_string]
+    assert {"l_returnflag", "l_linestatus"} <= set(strings)
+    for name in strings:
+        assert isinstance(data[name], pa.LargeStringArray)
+        assert not isinstance(data[name], np.ndarray)
+        assert validity[name].dtype == bool and validity[name].all()
+    assert not any(isinstance(a, np.ndarray) and a.dtype == object
+                   for a in data.values())
 
 
 def test_a_source_without_string_columns_opens_none(tables):
