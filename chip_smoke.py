@@ -237,10 +237,16 @@ def run_mesh_exchange(tables, n_chips: int, query: int = MESH_QUERY,
     query with one partition per chip under an active n-chip mesh (hash
     exchanges take the mesh collective lane), then the same query on
     one device with no mesh, then the CPU reference.  Asserts every
-    mesh device held a shard of an exchange's output."""
+    mesh device held a shard of an exchange's output, that n distinct
+    chips hold the partitions after each exchange (the profiled hot
+    run's `exec:exchange-read` spans), that the joins' spans name n
+    devices, and that batches changed chips outside the collective only
+    in counted moves."""
     from spark_rapids_tpu.models.tpch_bench import run_query
     from spark_rapids_tpu.parallel import mesh as M
     from spark_rapids_tpu.shuffle.exchange import ShuffleExchangeExec as X
+    from spark_rapids_tpu.utils import checks as CK
+    from spark_rapids_tpu.utils import profile as P
 
     def timed(conf):
         t0 = time.perf_counter()
@@ -255,8 +261,21 @@ def run_mesh_exchange(tables, n_chips: int, query: int = MESH_QUERY,
     with M.active_mesh(mesh):
         sharded_cold, cold_s = timed(conf)
         sharded, hot_s = timed(conf)
-    n_exchanges, shard_devices = (X._MESH_EXCHANGES_RUN,
-                                  list(X._MESH_SHARD_DEVICES))
+        n_exchanges, shard_devices = (X._MESH_EXCHANGES_RUN,
+                                      list(X._MESH_SHARD_DEVICES))
+        # once more, profiled: where the partitions lay
+        P.clear_history()
+        CK.reset_cross_chip_moves()
+        timed(smoke_conf({
+            "spark.rapids.shuffle.meshExchange.enabled": True,
+            "spark.rapids.sql.profile.enabled": True}))
+    spans = P.last_profile().spans
+    read_devices = sorted({s.args["device"] for s in spans
+                           if s.name == P.SPAN_EXCHANGE_READ})
+    join_devices = sorted({s.args.get("device") for s in spans
+                           if s.name in (P.SPAN_JOIN_BUILD,
+                                         P.SPAN_JOIN_PROBE)})
+    moves = CK.cross_chip_move_sites()
 
     _, single_cold_s = timed(smoke_conf())
     single, single_hot_s = timed(smoke_conf())
@@ -274,6 +293,9 @@ def run_mesh_exchange(tables, n_chips: int, query: int = MESH_QUERY,
          "one_device_hot_s": single_hot_s,
          "mesh_exchanges": n_exchanges,
          "devices_holding_shards_per_exchange": shard_devices,
+         "devices_holding_partitions_after_exchange": read_devices,
+         "devices_named_by_join_spans": join_devices,
+         "cross_chip_moves": {k: list(v) for k, v in moves.items()},
          "matches_cpu_reference": True, "matches_one_device": True}
     out(json.dumps({"mesh_exchange": s}))
     assert n_exchanges > 0, "no hash exchange took the mesh lane"
@@ -281,6 +303,14 @@ def run_mesh_exchange(tables, n_chips: int, query: int = MESH_QUERY,
         assert ids == mesh_ids, (
             f"an exchange's output lived on devices {ids}, the mesh "
             f"is {mesh_ids}")
+    assert read_devices == mesh_ids, (
+        f"after the exchange the partitions lay on {read_devices}, "
+        f"the mesh is {mesh_ids}")
+    assert join_devices == mesh_ids, (
+        f"the joins ran on {join_devices}, the mesh is {mesh_ids}")
+    # the query's one single-partition point: the top-n merge
+    assert set(moves) <= {"topn-merge"} and sum(
+        n for n, _ in moves.values()) <= 1, moves
     return s
 
 

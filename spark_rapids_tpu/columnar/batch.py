@@ -463,7 +463,7 @@ class ColumnarBatch:
     @staticmethod
     def chunks_from_numpy(data: dict[str, np.ndarray], schema: T.Schema,
                           validity: Optional[dict[str, np.ndarray]],
-                          max_rows: int
+                          max_rows: int, device=None
                           ) -> tuple[list["ColumnarBatch"], int]:
         """Host columns -> the batches `from_numpy` gives for each
         `max_rows` rows of them (same rows, capacities and contents), in
@@ -482,7 +482,20 @@ class ColumnarBatch:
         `from_numpy`'s path, and so does a string column that Arrow
         refuses, value by value (`host_strings`); a run holds whole
         chunks up to `UPLOAD_TRANSFER_BYTES`.  An INT64 `narrow` shadow
-        is decided once a run: there when the whole run fits int32."""
+        is decided once a run: there when the whole run fits int32.
+
+        `device` (a partition's chip under an active mesh): the
+        transfers and the split program go there and the batches come
+        back COMMITTED to it, so every program downstream follows them;
+        without it they lie on the default chip, committed nowhere."""
+        if device is not None:
+            with jax.default_device(device):
+                batches, sent = ColumnarBatch.chunks_from_numpy(
+                    data, schema, validity, max_rows)
+            # on the chip already: committing copies nothing
+            cols = jax.device_put([b.columns for b in batches], device)
+            return [ColumnarBatch(schema, c, b._rows)
+                    for b, c in zip(batches, cols)], sent
         n = len(next(iter(data.values()))) if data else 0
         fixed = [f for f in schema.fields if not f.dtype.is_string]
         # storage + validity + at most a 4-byte shadow

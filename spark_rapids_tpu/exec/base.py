@@ -611,6 +611,10 @@ class TpuExec:
         batches = list(self.execute_columnar())
         if not batches:
             return empty_batch(self.output_schema())
+        # a plan that ends in several partitions under a mesh: the
+        # answer is one batch on one chip
+        from spark_rapids_tpu.parallel import mesh as PM
+        batches = PM.to_one_chip(batches, "collect")
         # sparse_ok: collect() densifies right after, so the concat can
         # skip per-input compaction gathers — one gather round total
         return concat_batches(batches, sparse_ok=True)

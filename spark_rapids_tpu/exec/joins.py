@@ -512,7 +512,8 @@ class HashJoinExec(TpuExec):
         return self._join_cache.get_or_build(
             key, build_fn, meta=self.kp_meta("join-dense"))
 
-    def _execute_dense(self, build, tab) -> Iterator[ColumnarBatch]:
+    def _execute_dense(self, build, tab, probe_batches, where
+                       ) -> Iterator[ColumnarBatch]:
         kmin, g, bidx1_tab, vmask_tab = tab
         jt = self.join_type
         kmin_op = jnp.int64(kmin)
@@ -541,26 +542,26 @@ class HashJoinExec(TpuExec):
                                                  rows=pb._rows)
 
         from spark_rapids_tpu.utils import profile as P
-        ph = P.phase(P.SPAN_JOIN_PROBE, lane="dense", **_PROBE_COUNTS)
+        ph = P.phase(P.SPAN_JOIN_PROBE, lane="dense", **_PROBE_COUNTS,
+                     **where)
         try:
-            for it in self._probe.execute_partitions():
-                for pb in it:
-                    if not pb.maybe_nonempty():
-                        continue
-                    if ph is not None:
-                        ph.add(probe_batches=1, rows_in=P.known_rows([pb]),
-                               capacity_rows=pb.capacity)
-                    # probe rows are independent given a fixed build
-                    # table, so the probe side is fully
-                    # split-and-retry-able
-                    for out in self.oom_retry_batches(
-                            pb, probe_one,
-                            label=f"{self.name()}.denseProbe"):
-                        if out.maybe_nonempty():
-                            if ph is not None:
-                                ph.add(rows_out=P.known_rows([out]))
-                            self.update_output_metrics(out)
-                            yield out
+            for pb in probe_batches:
+                if not pb.maybe_nonempty():
+                    continue
+                if ph is not None:
+                    ph.add(probe_batches=1, rows_in=P.known_rows([pb]),
+                           capacity_rows=pb.capacity)
+                # probe rows are independent given a fixed build
+                # table, so the probe side is fully
+                # split-and-retry-able
+                for out in self.oom_retry_batches(
+                        pb, probe_one,
+                        label=f"{self.name()}.denseProbe"):
+                    if out.maybe_nonempty():
+                        if ph is not None:
+                            ph.add(rows_out=P.known_rows([out]))
+                        self.update_output_metrics(out)
+                        yield out
         finally:
             if ph is not None:
                 ph.close()
@@ -631,44 +632,112 @@ class HashJoinExec(TpuExec):
             cols = list(pout) + list(bout)
         return ColumnarBatch(self._schema, cols, n)
 
+    def co_partitions(self) -> Optional[int]:
+        """The partition count when this is a SHUFFLED hash join the
+        planner set up partition by partition: both children hash
+        exchanges on the join's own keys, key types equal pair by pair
+        (murmur3 hashes an INT32 and an INT64 of one value apart) and
+        the same number of partitions.  Then rows that can match lie in
+        the same partition of both sides, whatever lane an exchange
+        takes (both route by murmur3 pmod n).  None for every other
+        shape: broadcast, a child that is no exchange (AQE's stage
+        readers), range or single partitioning, unequal counts."""
+        from spark_rapids_tpu.exprs.base import fingerprint
+        from spark_rapids_tpu.shuffle.exchange import ShuffleExchangeExec
+        from spark_rapids_tpu.shuffle.partitioning import HashPartitioning
+        sides = ((self._build, self._build_keys),
+                 (self._probe, self._probe_keys))
+        for child, keys in sides:
+            if (not keys or not isinstance(child, ShuffleExchangeExec)
+                    or child.coalesce_small
+                    or not isinstance(child.partitioning, HashPartitioning)
+                    or fingerprint(list(child.partitioning.exprs))
+                    != fingerprint(keys)):
+                return None
+        (b, bk), (p, pk) = sides
+        n = b.partitioning.num_partitions
+        if n != p.partitioning.num_partitions or any(
+                x.data_type(b.output_schema())
+                != y.data_type(p.output_schema())
+                for x, y in zip(bk, pk)):
+            return None
+        return n
+
     def execute_columnar(self) -> Iterator[ColumnarBatch]:
+        if self.co_partitions() is not None:
+            for it in self.execute_partitions():
+                yield from it
+            return
+        # the build side whole against every probe partition.  Under an
+        # active mesh the children's partitions may lie a chip each:
+        # both sides come to one chip through the counted move (the
+        # probe side then waits for all of itself)
+        from spark_rapids_tpu.parallel import mesh as PM
+
+        def build_batches():
+            batches = self._grace_candidate_batches()
+            whole = batches is None
+            if whole:
+                batches = self._collect_build_batches()
+            return PM.to_one_chip(batches, "join-build"), whole
+
+        def probe_batches(build_at):
+            return (pb for it in PM.one_chip_partitions(
+                self._probe.execute_partitions(), "join-probe",
+                device=build_at) for pb in it)
+        yield from self._join_sides(build_batches, probe_batches, {})
+
+    def _join_sides(self, build_batches, probe_batches, where: dict
+                    ) -> Iterator[ColumnarBatch]:
+        """One build side against one probe stream: the whole join, or
+        one partition of a co-partitioned one (`where`: its `partition`
+        and `device`, for the spans).  `build_batches()` drains the
+        build side (and says whether it must be taken whole);
+        `probe_batches(chip)` opens the probe stream for a build that
+        lies on `chip`."""
         from spark_rapids_tpu import config as C
         from spark_rapids_tpu.memory import oocore as OC
+        from spark_rapids_tpu.parallel import mesh as PM
         from spark_rapids_tpu.utils import profile as P
-        with P.span(P.SPAN_JOIN_BUILD) as sp:
-            batches = self._grace_candidate_batches()
+        with P.span(P.SPAN_JOIN_BUILD, **where) as sp:
+            batches, whole = build_batches()
             build = None
-            if batches is None:
-                batches = self._collect_build_batches()
+            if whole:
                 build, reads = self._concat_build(batches)
             else:
                 conf = C.get_active_conf()
                 est = 2 * sum(b.device_size_bytes() for b in batches)
                 if not OC.should_go_external(est, conf):
                     build, reads = self._concat_build(batches)
-            if sp is not None and build is not None:
-                sp.args = {"rows": P.known_rows([build]),
-                           "capacity_rows": build.capacity,
-                           "slices": len(batches), "count_reads": reads}
+            if build is not None:
+                at = PM.device_of(build)
+                if at is not None and PM.get_active_mesh() is not None:
+                    where = dict(where, device=at.id)
+                if sp is not None:
+                    sp.args = dict(
+                        where, rows=P.known_rows([build]),
+                        capacity_rows=build.capacity,
+                        slices=len(batches), count_reads=reads)
         if build is None:
             P.event(P.EV_OOCORE_DEGRADE, op=self.name(),
                     est_bytes=est, algo="grace-hash")
-            probe_src = (pb for it in self._probe.execute_partitions()
-                         for pb in it if pb.maybe_nonempty())
+            probe_src = (pb for pb in probe_batches(None)
+                         if pb.maybe_nonempty())
             yield from self._grace_join(iter(batches), probe_src,
                                         0, conf)
             return
+        probe_src = probe_batches(at)
         if self._dense_qual:
             tab = self._try_dense_table(build)
             if tab is not None:
-                yield from self._execute_dense(build, tab)
+                yield from self._execute_dense(build, tab, probe_src,
+                                               where)
                 return
-        probe_src = (pb for it in self._probe.execute_partitions()
-                     for pb in it)
-        yield from self._join_stream(build, probe_src)
+        yield from self._join_stream(build, probe_src, where)
 
-    def _join_stream(self, build: ColumnarBatch,
-                     probe_batches) -> Iterator[ColumnarBatch]:
+    def _join_stream(self, build: ColumnarBatch, probe_batches,
+                     where: Optional[dict] = None
+                     ) -> Iterator[ColumnarBatch]:
         """Sort-path join of one WHOLE build batch against a stream of
         probe batches (the former execute_columnar body, factored out
         so the grace-hash lane can run it once per key partition —
@@ -679,7 +748,8 @@ class HashJoinExec(TpuExec):
                              JoinType.FULL_OUTER)
         bmatched_total = np.zeros(build.capacity, bool)
         from spark_rapids_tpu.utils import profile as P
-        ph = P.phase(P.SPAN_JOIN_PROBE, lane="sort", **_PROBE_COUNTS)
+        ph = P.phase(P.SPAN_JOIN_PROBE, lane="sort", **_PROBE_COUNTS,
+                     **(where or {}))
 
         def probe_one(pb: ColumnarBatch) -> ColumnarBatch:
             pb = _owned_dense(pb)
@@ -895,10 +965,35 @@ class HashJoinExec(TpuExec):
         return self._assemble(nulls, bout, len(idx))
 
     def output_partition_count(self) -> int:
-        return 1
+        return self.co_partitions() or 1
 
     def execute_partitions(self):
-        return [self.execute_columnar()]
+        """Co-partitioned children: partition p builds from build
+        partition p alone and probes with probe partition p, where they
+        lie (under a mesh: on chip p), each with its own build concat,
+        dense-table attempt, probe stream and grace lane; FULL OUTER's
+        unmatched build rows come out partition by partition, sound
+        because hash partitions are key-disjoint.  Anything else: one
+        partition, the whole build."""
+        n = self.co_partitions()
+        if n is None:
+            return [self.execute_columnar()]
+        from spark_rapids_tpu.utils import profile as P
+        # as every operator's partitions: the children's iterators are
+        # made here, on the caller's thread and under its task (an
+        # exchange runs its map side at this point), and partition p's
+        # are consumed by whichever thread pulls partition p
+        builds = self._build.execute_partitions()
+        probes = self._probe.execute_partitions()
+
+        def one(p: int):
+            def build_batches():
+                return [_owned_dense(b) for b in builds[p]
+                        if b.maybe_nonempty()], False
+            return self._join_sides(build_batches,
+                                    lambda build_at: probes[p],
+                                    {"partition": p})
+        return [P.wrap_operator(self, p, one(p)) for p in range(n)]
 
 
 class BroadcastHashJoinExec(HashJoinExec):

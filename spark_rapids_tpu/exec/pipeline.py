@@ -265,6 +265,10 @@ class PrefetchIterator:
             t.name, _JOIN_TIMEOUT_S, stack or "<unavailable>")
 
     # -- producer side ------------------------------------------------------
+    def start(self) -> None:
+        """Start producing now, ahead of the first pull."""
+        self._ensure_started()
+
     def _ensure_started(self) -> None:
         if self._thread is None:
             self._thread = threading.Thread(
@@ -383,3 +387,31 @@ def maybe_prefetch(source: Iterable, label: str = "pipeline",
         return iter(source)
     return PrefetchIterator(source, depth, label=label, metrics=metrics,
                             conf=conf)
+
+
+def drain_partitions(partitions: list, label: str = "partition",
+                     metrics=None) -> list[list]:
+    """Every batch of every partition, partition by partition.  Under an
+    active mesh with one partition a chip the partitions are drained
+    TOGETHER, one producer thread a chip (a task a chip, as one executor
+    a chip runs them): their programs lie on different chips, so their
+    dispatches overlap, and a cold kernel's per-chip copies (independent
+    XLA compilations, which release the GIL) compile side by side and
+    not one after the other.  Anywhere else, or with pipelining off: one
+    after the other on the caller's thread, as a plain loop would."""
+    from spark_rapids_tpu import config as C
+    from spark_rapids_tpu.parallel import mesh as PM
+    if (PM.partition_devices(len(partitions)) is None
+            or not C.get_active_conf()[C.PIPELINE_ENABLED]):
+        return [list(it) for it in partitions]
+    # unbounded: the caller keeps every batch anyway (a barrier)
+    tasks = [PrefetchIterator(it, 1 << 30, label=label, metrics=metrics)
+             for it in partitions]
+    try:
+        for t in tasks:
+            t.start()
+        return [list(t) for t in tasks]
+    finally:
+        for t in tasks:
+            t.close()
+

@@ -454,15 +454,22 @@ class SortedTopNExec(UnaryExecBase):
 
     def execute_columnar(self):
         from spark_rapids_tpu.columnar.batch import concat_batches
-        pruned = []
-        for part in self.child.execute_partitions():
+        from spark_rapids_tpu.exec.pipeline import drain_partitions
+        from spark_rapids_tpu.parallel import mesh as PM
+
+        def pruned_of(part):
             for batch in part:
                 top = self._prune_one(batch)
                 if top.maybe_nonempty():
-                    pruned.append(top)
+                    yield top
+        # per partition (under a mesh: a chip each, side by side), then
+        # ONE single-partition merge of n candidates a partition
+        pruned = [top for tops in drain_partitions(
+            [pruned_of(part) for part in self.child.execute_partitions()],
+            label="topn-prune", metrics=self.metrics) for top in tops]
         if not pruned:
             return
-        merged = concat_batches(pruned)
+        merged = concat_batches(PM.to_one_chip(pruned, "topn-merge"))
         final = self._sort_one(merged).take_head(self.n)
         self.update_output_metrics(final)
         yield final

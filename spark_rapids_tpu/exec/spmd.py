@@ -327,6 +327,10 @@ def _run_gang(exec_, mesh, axis: str, batches: list) -> list:
     stage = exec_.stage
     schema = stage.in_schema
     n_dev = mesh.shape[axis]
+    # the stacker builds the gang on one device and scatters it: source
+    # partitions that were uploaded a chip each come together first
+    from spark_rapids_tpu.parallel import mesh as PM
+    batches = PM.to_one_chip(batches, "spmd-gang")
     B = len(batches)
     n_slots = -(-B // n_dev) * n_dev
     cap, char_caps, narrows = _gang_layout(schema, batches)

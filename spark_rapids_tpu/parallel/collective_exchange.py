@@ -280,3 +280,32 @@ def unstack_batches(arrs, num_rows, schema: T.Schema):
                 None if lengths is None else lengths[d]))
         out.append(ColumnarBatch(schema, cols, int(num_rows[d])))
     return out
+
+
+@functools.partial(named_jit, "exchange-stack",
+                   static_argnames=("cap", "char_caps"))
+def local_stack(columns, num_rows, cap: int, char_caps: tuple):
+    """One chip's block of the stacked operand, made on that chip (the
+    program follows its committed operands): every column padded to
+    `cap` rows and, a string column, to the exchange's common char cap,
+    under a leading axis of one; `jax.make_array_from_single_device_
+    arrays` then assembles the chips' blocks into the mesh-sharded
+    arrays `build_all_to_all_exchange` takes, and no byte leaves its
+    chip before the all-to-all."""
+    def pad(a, cc=0):
+        width = [(0, cap - a.shape[0])]
+        if a.ndim == 2:
+            width.append((0, cc - a.shape[1]))
+        return jnp.pad(a, width)[None]
+
+    arrs = [(pad(c.data, cc), pad(c.validity),
+             None if c.lengths is None else pad(c.lengths))
+            for c, cc in zip(columns, char_caps)]
+    return arrs, jnp.minimum(jnp.asarray(num_rows, jnp.int32), cap)[None]
+
+
+@functools.partial(named_jit, "exchange-unstack")
+def local_unstack(arrs):
+    """One chip's shard of the exchanged arrays without its leading axis
+    of one: output partition d, on chip d."""
+    return jax.tree_util.tree_map(lambda a: a[0], arrs)

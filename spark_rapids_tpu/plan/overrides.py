@@ -322,6 +322,11 @@ def _conv_source(meta, kids) -> TpuExec:
     label = "" if tr is None else \
         f"{P.SPAN_SOURCE_UPLOAD}[s{tr.ordinal(P.SPAN_SOURCE_UPLOAD)}]"
     parts, nbytes, transfers = [], 0, 0
+    # one partition a chip: under an active mesh of as many chips as the
+    # source has partitions, partition p's transfers and its split
+    # program go to chip p
+    from spark_rapids_tpu.parallel import mesh as PM
+    chips = PM.partition_devices(len(node.partitions))
     with P.span(label) as upload:
         for p, df in enumerate(node.partitions):
             nchunks = -(-len(df) // max_rows)
@@ -331,7 +336,8 @@ def _conv_source(meta, kids) -> TpuExec:
             with P.span(P.SPAN_UPLOAD_PUT, partition=p,
                         chunks=nchunks, rows=len(df)) as put:
                 chunks, sent = ColumnarBatch.chunks_from_numpy(
-                    data, schema, validity, max_rows)
+                    data, schema, validity, max_rows,
+                    device=chips[p] if chips else None)
                 if put is not None:
                     put.args["device_bytes"] = b = sum(
                         MV.vector_device_bytes(col)
@@ -436,11 +442,74 @@ def _exchange_partitions(nparts: int, conf: C.RapidsConf) -> int:
     return nparts
 
 
+def _plain_column(e: Expression, schema: T.Schema) -> Optional[str]:
+    """The name of the column `e` hands on unchanged, or None."""
+    from spark_rapids_tpu.exprs.base import (
+        Alias, AttributeReference, BoundReference)
+    if isinstance(e, Alias):
+        inner = _plain_column(e.child, schema)
+        return inner if inner == e.name else None
+    if isinstance(e, AttributeReference):
+        return e.name
+    if isinstance(e, BoundReference):
+        return schema.fields[e.ordinal].name
+    return None
+
+
+def _clustered_on(plan: TpuExec) -> list[frozenset]:
+    """Sets of `plan`'s output columns such that rows equal on a set lie
+    in ONE of its partitions (Spark's outputPartitioning, as far as the
+    planner needs it): a hash exchange's keys; a co-partitioned join's
+    keys on the sides it keeps whole (an outer join's null-extended
+    side is spread); handed up through the operators that keep rows in
+    their partition and those columns under their names.  A name the
+    schema holds twice says nothing."""
+    schema = plan.output_schema()
+    names = [f.name for f in schema.fields]
+
+    def named(exprs, side_schema):
+        cols = [_plain_column(e, side_schema) for e in exprs]
+        if cols and all(c is not None and names.count(c) == 1
+                        for c in cols):
+            return [frozenset(cols)]
+        return []
+    if isinstance(plan, ShuffleExchangeExec):
+        if isinstance(plan.partitioning, HashPartitioning) \
+                and not plan.coalesce_small:
+            return named(plan.partitioning.exprs, schema)
+        return []
+    if isinstance(plan, HashJoinExec):
+        if plan.co_partitions() is None:
+            return []
+        left = named(plan.left_keys, plan.children[0].output_schema())
+        right = named(plan.right_keys, plan.children[1].output_schema())
+        return {JoinType.INNER: left + right,
+                JoinType.LEFT_OUTER: left, JoinType.LEFT_SEMI: left,
+                JoinType.LEFT_ANTI: left,
+                JoinType.RIGHT_OUTER: right}.get(plan.join_type, [])
+    if isinstance(plan, B.FilterExec):
+        return _clustered_on(plan.child)
+    if isinstance(plan, B.ProjectExec):
+        kept = {_plain_column(e, plan.child.output_schema())
+                for e in plan.exprs}
+        return [k for k in _clustered_on(plan.child)
+                if k <= kept and all(names.count(c) == 1 for c in k)]
+    return []
+
+
 def _conv_aggregate(meta, kids) -> TpuExec:
     node: N.CpuAggregate = meta.node
     child = kids[0]
     nparts = _num_partitions_of(child)
     if nparts <= 1:
+        return HashAggregateExec(node.group_exprs, node.aggregates, child,
+                                 AggMode.COMPLETE)
+    # the child's partitions are group-disjoint where the group keys
+    # contain its partitioning key (q3 groups on `l_orderkey`, the key of
+    # the join below): each partition aggregates completely where it is
+    groups = {_plain_column(e, child.output_schema())
+              for e in node.group_exprs}
+    if any(keys <= groups for keys in _clustered_on(child)):
         return HashAggregateExec(node.group_exprs, node.aggregates, child,
                                  AggMode.COMPLETE)
     # distributed: partial -> key exchange -> final (Spark planner shape;

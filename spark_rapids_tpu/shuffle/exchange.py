@@ -70,13 +70,24 @@ class ShuffleExchangeExec(UnaryExecBase):
     #: arbitrarily large map side can't OOM the device
     SPLIT_PIPELINE_DEPTH = 8
 
+    def _child_partitions(self) -> list:
+        """The child's partitions for the lanes that split and
+        concatenate on ONE device (local, manager, broadcast).  Under an
+        active mesh with the child one partition a chip, every batch
+        comes to one chip first, in one counted move (the map side then
+        waits for all of itself); anywhere else the iterators as they
+        are."""
+        from spark_rapids_tpu.parallel import mesh as PM
+        return PM.one_chip_partitions(self.child.execute_partitions(),
+                                      "exchange-map")
+
     def _range_inputs(self):
         """Range partitioning needs two passes over the child (sample
         bounds, then split), so its inputs are materialized once here.
         Returns (inputs, small) — `small` means a one-partition exchange
         suffices.  Hash/round-robin callers must NOT use this: they
         stream batch-at-a-time so pre-split inputs are freed as they go."""
-        inputs = [b.dense() for it in self.child.execute_partitions()
+        inputs = [b.dense() for it in self._child_partitions()
                   for b in it if b.maybe_nonempty()]
         inputs = [b for b in inputs if b.num_rows > 0]
         total = sum(b.num_rows for b in inputs)
@@ -94,7 +105,7 @@ class ShuffleExchangeExec(UnaryExecBase):
         break)."""
         from spark_rapids_tpu.exec.pipeline import maybe_prefetch
         return maybe_prefetch(
-            (b for it in self.child.execute_partitions()
+            (b for it in self._child_partitions()
              for b in it if b.maybe_nonempty()),
             label="exchange-map", metrics=self.metrics)
 
@@ -353,52 +364,103 @@ class ShuffleExchangeExec(UnaryExecBase):
         """Accelerated path: one SPMD all-to-all over the mesh replaces
         the per-batch split + bucket copy of the local lane.  Each mesh
         device owns one output partition; received rows are compacted
-        device-side into a worst-case-sized (overflow-proof) batch."""
+        device-side into a batch sized from the count phase's totals.
+
+        One partition a chip, in and out: a child with one partition a
+        chip is stacked where it lies (chip d's rows made one batch on
+        chip d, padded to the common capacity by one program there, and
+        the global operand assembled from those single-device arrays),
+        and output partition d IS chip d's addressable shard of the
+        result, never an index into the global array: a row indexed out
+        of the sharded stack stays spread over the mesh, and the first
+        Mosaic kernel to receive one refuses it (four v5e chips, PR 25).
+        So the only bytes that cross chips are the all-to-all's.  Any
+        other child (another partition count, batches committed nowhere)
+        is dealt round-robin and spread through the counted move."""
+        import jax
         import numpy as np
         from spark_rapids_tpu.columnar.batch import empty_batch
-        from spark_rapids_tpu.columnar.vector import bucket_capacity
+        from spark_rapids_tpu.columnar.vector import (
+            ColumnVector, bucket_capacity)
+        from spark_rapids_tpu.exec.pipeline import drain_partitions
+        from spark_rapids_tpu.parallel import mesh as PM
         from spark_rapids_tpu.parallel.collective_exchange import (
             build_all_to_all_exchange, build_count_exchange,
-            stack_batches, stacked_payload_bytes, unstack_batches,
+            local_stack, local_unstack, stacked_payload_bytes,
             watched_collective)
         n = self.partitioning.num_partitions
+        chips = list(mesh.devices.flat)
         from spark_rapids_tpu import config as C
         max_rows = C.get_active_conf()[C.MAX_BATCH_ROWS]
         groups: list[list[ColumnarBatch]] = [[] for _ in range(n)]
-        slot = 0
-        for it in self.child.execute_partitions():
-            for b in it:
-                if not b.maybe_nonempty():
-                    continue
-                # size LAZY batches by CAPACITY (a safe upper bound on
-                # rows): coalesce's lazy_bounded pass-through emits
-                # batches up to LAZY_PASS_MULT x the row cap whole, and
-                # those must not skip HBM-budget sharding and land
-                # entire on one chip.  Only the must-shard shape pays
-                # the count sync (b.num_rows below).
-                est_rows = (b.num_rows if b.num_rows_known
-                            else b.capacity)
-                if est_rows > max_rows and b.num_rows > max_rows:
-                    # SURVEY §5 long-context analog: ONE batch larger
-                    # than the per-chip budget is sharded ACROSS the
-                    # mesh before the all-to-all (the sp lane), instead
-                    # of overflowing one chip's HBM (reference guard:
-                    # GpuCoalesceBatches.scala:166-169 + spill tiers)
-                    per = -(-b.num_rows // n)
-                    ShuffleExchangeExec._OVERSIZED_SPLITS += 1
-                    for lo in range(0, b.num_rows, per):
-                        groups[slot % n].append(
-                            b.slice(lo, min(per, b.num_rows - lo)))
+        with P.span(P.SPAN_EXCHANGE_WRITE) as write:
+            parts = drain_partitions(self.child.execute_partitions(),
+                                     label="exchange-map",
+                                     metrics=self.metrics)
+            aligned = len(parts) == n
+            slot = 0
+            for p, part in enumerate(parts):
+                for b in part:
+                    if not b.maybe_nonempty():
+                        continue
+                    # size LAZY batches by CAPACITY (a safe upper bound
+                    # on rows): coalesce's lazy_bounded pass-through
+                    # emits batches up to LAZY_PASS_MULT x the row cap
+                    # whole, and those must not skip HBM-budget sharding
+                    # and land entire on one chip.  Only the must-shard
+                    # shape pays the count sync (b.num_rows below).
+                    est_rows = (b.num_rows if b.num_rows_known
+                                else b.capacity)
+                    if est_rows > max_rows and b.num_rows > max_rows:
+                        # SURVEY §5 long-context analog: ONE batch larger
+                        # than the per-chip budget is sharded ACROSS the
+                        # mesh before the all-to-all (the sp lane),
+                        # instead of overflowing one chip's HBM
+                        # (reference guard: GpuCoalesceBatches.scala:
+                        # 166-169 + spill tiers)
+                        per = -(-b.num_rows // n)
+                        ShuffleExchangeExec._OVERSIZED_SPLITS += 1
+                        for lo in range(0, b.num_rows, per):
+                            groups[slot % n].append(
+                                b.slice(lo, min(per, b.num_rows - lo)))
+                            slot += 1
+                    elif aligned:
+                        groups[p].append(b)
+                    else:
+                        groups[slot % n].append(b)
                         slot += 1
-                else:
-                    groups[slot % n].append(b)
-                    slot += 1
-        locals_ = [concat_batches(g).dense() if g
-                   else empty_batch(self._schema)
-                   for g in groups]
-        cap = max(b.capacity for b in locals_)
-        locals_ = [b if b.capacity == cap else b.with_capacity(cap)
-                   for b in locals_]
+            def local_of(d: int):
+                """Chip d's rows as one dense batch on chip d."""
+                g = groups[d]
+                if not g:
+                    with jax.default_device(chips[d]):
+                        g = [empty_batch(self._schema)]
+                g = PM.to_one_chip(g, "exchange-spread", device=chips[d],
+                                   strict=True)
+                with programs_of("exchange"):
+                    yield concat_batches(g).dense()
+            # a chip each, side by side: on a cold cache the chips'
+            # copies of the compaction and the concat compile together
+            locals_ = [b for (b,) in drain_partitions(
+                [local_of(d) for d in range(n)], label="exchange-stack",
+                metrics=self.metrics)]
+            cap = max(b.capacity for b in locals_)
+            char_caps = tuple(
+                max(b.columns[i].char_cap for b in locals_)
+                if f.dtype.is_string else 0
+                for i, f in enumerate(self._schema.fields))
+            # chip d's block of the stacked operand, made on chip d
+            sharding = PM.data_sharding(mesh, axis)
+            blocks = [local_stack(b.columns, b.num_rows_i32, cap=cap,
+                                  char_caps=char_caps) for b in locals_]
+            arrs, num_rows = jax.tree_util.tree_map(
+                lambda *xs: jax.make_array_from_single_device_arrays(
+                    (n,) + xs[0].shape[1:], sharding, list(xs)), *blocks)
+            payload = stacked_payload_bytes(arrs)
+            if write is not None:
+                write.args = {
+                    "partitions": n, "rows": P.known_rows(locals_),
+                    "capacity_rows": n * cap, "bytes": payload}
         key_idx = tuple(e.ordinal for e in self.partitioning.exprs)
         # process-global LRU (bounded + clearable): mesh identity enters
         # the key as device ids, not the Mesh object, so dead meshes are
@@ -406,37 +468,27 @@ class ShuffleExchangeExec(UnaryExecBase):
         from spark_rapids_tpu.exec.base import KernelCache
         cache = KernelCache((
             "mesh_exchange", axis,
-            tuple(d.id for d in mesh.devices.flat),
+            tuple(d.id for d in chips),
             tuple((f.name, str(f.dtype)) for f in self._schema.fields),
             key_idx))
         schema = self._schema
         ShuffleExchangeExec._MESH_EXCHANGES_RUN += 1
-        # the whole-mesh dispatch gate covers every enqueue touching
-        # the sharded arrays (count phase, data phase, AND the
-        # unstack slicing): concurrent whole-mesh programs enqueued
-        # from two threads can invert per-device queue order and
-        # deadlock the collective rendezvous (exec/scheduler.py)
+        # the whole-mesh dispatch gate covers every enqueue of a
+        # whole-mesh program (count phase, data phase): concurrent
+        # whole-mesh programs enqueued from two threads can invert
+        # per-device queue order and deadlock the collective rendezvous
+        # (exec/scheduler.py)
         from spark_rapids_tpu.exec import scheduler as S
         with self.metrics.timed(M.TOTAL_TIME), \
-                P.span("mesh-exchange", cat=P.CAT_SHUFFLE), \
+                P.span(P.SPAN_EXCHANGE_COLLECTIVE, chips=n,
+                       payload_bytes=payload,
+                       cross_chip_bytes=payload - payload // n) as coll, \
                 S.whole_mesh_dispatch(label="mesh-exchange"):
-            arrs, num_rows = stack_batches(locals_, cap)
-            # explicit mesh layout (the pjit/GDA pattern): device d of
-            # the data axis owns stacked slot d.  Also REQUIRED for
-            # committed single-device inputs (an upstream SPMD gang's
-            # outputs live on the default device) — shard_map rejects
-            # them without the reshard.
-            import jax
-            from spark_rapids_tpu.parallel import mesh as PM
-            arrs, num_rows = jax.device_put(
-                (arrs, num_rows), PM.data_sharding(mesh, axis))
             # movement ledger: the payload the data-phase all-to-all
             # ships over ICI — every column's stacked data + validity
             # (+ lengths) arrays (the count phase is n_dev ints, noise)
             from spark_rapids_tpu.utils import movement as MV
-            payload = 0
             if MV.ledger() is not None:
-                payload = stacked_payload_bytes(arrs)
                 self.metrics.add(M.COLLECTIVE_BYTES, payload)
             # two-phase exchange (ADVICE r2): a counts-only all-to-all
             # sizes the data phase's receive buffers from ACTUAL totals
@@ -451,6 +503,8 @@ class ShuffleExchangeExec(UnaryExecBase):
                 lambda: np.asarray(count_fn(arrs, num_rows)),
                 label="mesh-count")
             out_cap = int(bucket_capacity(max(int(totals.max()), 1)))
+            if coll is not None:
+                coll.args["out_cap"] = out_cap
             step = cache.get_or_build(
                 ("step", cap, out_cap),
                 lambda: build_all_to_all_exchange(
@@ -458,28 +512,34 @@ class ShuffleExchangeExec(UnaryExecBase):
                     out_capacity=out_cap))
             out_arrs, out_rows = watched_collective(
                 lambda: step(arrs, num_rows), label="mesh-exchange",
-                nbytes=payload)
+                nbytes=payload if MV.ledger() is not None else 0)
             ShuffleExchangeExec._MESH_SHARD_DEVICES.append(sorted(
                 s.device.id for s in out_rows.addressable_shards))
-            # the partitions come home to ONE device before they are
-            # unstacked: the operators downstream are single-device
-            # programs, and a row indexed out of the mesh-sharded stack
-            # stays spread over the mesh — on real chips the first
-            # Mosaic kernel to receive one fails ("Mosaic kernels
-            # cannot be automatically partitioned"; four v5e chips,
-            # PR 25), where virtual CPU devices ran it replicated
-            out_arrs = jax.device_put(out_arrs, jax.devices()[0])
-            out = unstack_batches(out_arrs, np.asarray(out_rows),
-                                  self._schema)
-        for b in out:
-            self.metrics.add("dataSize", b.device_size_bytes())
+        del out_rows    # the count phase's totals ARE the rows received
 
-        def reader(b: ColumnarBatch):
-            if b.num_rows > 0:
-                self.metrics.add(M.NUM_OUTPUT_ROWS, b.num_rows)
+        def reader(d: int):
+            # partition d is chip d's own shard of every output array: a
+            # single-device array that is already there
+            rows = int(totals[d])
+            if rows == 0:
+                return
+            ph = P.phase(P.SPAN_EXCHANGE_READ, rows=rows,
+                         capacity_rows=out_cap, device=chips[d].id)
+            try:
+                mine = jax.tree_util.tree_map(
+                    lambda a: next(s.data for s in a.addressable_shards
+                                   if s.device == chips[d]), out_arrs)
+                b = ColumnarBatch(schema, [
+                    ColumnVector(f.dtype, *col) for f, col in zip(
+                        schema.fields, local_unstack(mine))], rows)
+                self.metrics.add("dataSize", b.device_size_bytes())
+                self.metrics.add(M.NUM_OUTPUT_ROWS, rows)
                 self.metrics.add(M.NUM_OUTPUT_BATCHES, 1)
                 yield b
-        return [reader(b) for b in out]
+            finally:
+                if ph is not None:
+                    ph.close()
+        return [reader(d) for d in range(n)]
 
     _SHUFFLE_IDS = iter(range(1, 1 << 31))
 
@@ -515,7 +575,7 @@ class ShuffleExchangeExec(UnaryExecBase):
             # two passes needed: materialize per-map batches once so the
             # bounds sample and the split see the same data
             per_map = [[b for b in it if b.num_rows > 0]
-                       for it in self.child.execute_partitions()]
+                       for it in self._child_partitions()]
             part.bounds = self._sample_bounds(
                 part, [b for bs in per_map for b in bs])
             map_iters = [iter(bs) for bs in per_map]
@@ -523,7 +583,7 @@ class ShuffleExchangeExec(UnaryExecBase):
             from spark_rapids_tpu.exec.pipeline import maybe_prefetch
             map_iters = [maybe_prefetch(it, label="exchange-map",
                                         metrics=self.metrics)
-                         for it in self.child.execute_partitions()]
+                         for it in self._child_partitions()]
         n = part.num_partitions
         repl_factor = max(1, int(conf[C.SHUFFLE_REPLICATION_FACTOR]))
 
@@ -579,7 +639,7 @@ class ShuffleExchangeExec(UnaryExecBase):
         def lineage(map_id):
             # retained map-side lineage (shared with recovery): a
             # FRESH run of exactly this child partition
-            return self.child.execute_partitions()[map_id]
+            return self._child_partitions()[map_id]
 
         def backup_for(exclude_mgr):
             ok = [m for m in healthy_mgrs() if m is not exclude_mgr]
@@ -619,7 +679,7 @@ class ShuffleExchangeExec(UnaryExecBase):
                 # bound partitioning (range bounds already sampled),
                 # and land them on the reducing executor — the one
                 # peer recovery can rely on being alive
-                its = self.child.execute_partitions()
+                its = self._child_partitions()
                 for map_id in lost_map_ids:
                     write_map_task(map_id, its[map_id], primary,
                                    epoch=epoch)
@@ -710,7 +770,9 @@ class BroadcastExchangeExec(UnaryExecBase):
             with self.metrics.timed("broadcastTime"):
                 t0 = time.monotonic()
                 batches, total = [], 0
-                for it in self.child.execute_partitions():
+                from spark_rapids_tpu.parallel import mesh as PM
+                for it in PM.one_chip_partitions(
+                        self.child.execute_partitions(), "broadcast"):
                     for b in it:
                         if not b.maybe_nonempty():
                             continue

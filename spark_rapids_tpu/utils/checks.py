@@ -79,6 +79,39 @@ def reset_host_syncs() -> None:
         _SYNC_BYTES.clear()
 
 
+# ---------------------------------------------------------------------------
+# cross-chip move counter, beside the host-sync counter: under an active
+# mesh a partition lives on its own chip, and the only way a batch
+# changes chips outside the all-to-all is `parallel/mesh.to_one_chip`,
+# which counts here.  One move = one call that found something on
+# another chip (a plan's single-partition point), whatever it carried.
+_MOVE_SITES: "collections.Counter" = collections.Counter()
+_MOVE_BYTES: "collections.Counter" = collections.Counter()
+
+
+def note_cross_chip_move(site: str, nbytes: int) -> None:
+    with _SYNC_LOCK:
+        _MOVE_SITES[site] += 1
+        _MOVE_BYTES[site] += nbytes
+
+
+def cross_chip_moves() -> int:
+    with _SYNC_LOCK:
+        return sum(_MOVE_SITES.values())
+
+
+def cross_chip_move_sites() -> dict:
+    """{site: (moves, bytes)} (copy): the audit view."""
+    with _SYNC_LOCK:
+        return {k: (n, _MOVE_BYTES[k]) for k, n in _MOVE_SITES.items()}
+
+
+def reset_cross_chip_moves() -> None:
+    with _SYNC_LOCK:
+        _MOVE_SITES.clear()
+        _MOVE_BYTES.clear()
+
+
 @dataclasses.dataclass(frozen=True, eq=False)
 class BatchCheck:
     # eq=False: identity equality/hash.  The generated field-tuple

@@ -137,7 +137,9 @@ class MetricSet:
         # wave: per-value np.asarray costs a device round trip each, and
         # a long-running exec can queue hundreds of lazy row counts
         # between reads.  Grouping by dtype (instead of upcasting to one
-        # stack dtype) keeps i32 row counts exact on non-x64 platforms.
+        # stack dtype) keeps i32 row counts exact on non-x64 platforms;
+        # by device too, since under a mesh an exec's partitions count
+        # their rows a chip each and a stack takes one device's arrays.
         # Host values (ints/floats, common for set_max) resolve with no
         # readback at all.
         import jax.numpy as jnp
@@ -149,7 +151,8 @@ class MetricSet:
                 continue
             try:
                 a = jnp.asarray(v).reshape(())
-                groups.setdefault(str(a.dtype), []).append((i, a))
+                groups.setdefault((str(a.dtype), frozenset(a.devices())),
+                                  []).append((i, a))
             except Exception:
                 resolved[i] = float(np.asarray(v))
         for items in groups.values():

@@ -81,6 +81,8 @@ SPAN_JOIN_BUILD = "join-build"            # build side drained + concatenated
 SPAN_JOIN_PROBE = "join-probe"            # match + expand over the stream
 SPAN_EXCHANGE_WRITE = "exchange-write"    # map side: split + cut
 SPAN_EXCHANGE_READ = "exchange-read"      # one reduce partition's slices
+SPAN_EXCHANGE_COLLECTIVE = "exchange-collective"  # mesh lane: count + data all-to-all
+SPAN_TO_ONE_CHIP = "to-one-chip"          # batches moved to one chip (counted)
 SPAN_GROUPBY_UPDATE = "groupby-update"    # every input batch grouped
 SPAN_GROUPBY_MERGE = "groupby-merge"      # partials merged + evaluated
 

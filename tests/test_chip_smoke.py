@@ -70,6 +70,10 @@ def test_mesh_exchange_lane_on_virtual_devices(tables):
     assert all(ids == [0, 1, 2, 3]
                for ids in s["devices_holding_shards_per_exchange"])
     assert s["matches_cpu_reference"] and s["matches_one_device"]
+    # one partition a chip after every exchange, and joined there
+    assert s["devices_holding_partitions_after_exchange"] == [0, 1, 2, 3]
+    assert s["devices_named_by_join_spans"] == [0, 1, 2, 3]
+    assert list(s["cross_chip_moves"]) == ["topn-merge"]
 
 
 def test_main_refuses_without_a_tpu():

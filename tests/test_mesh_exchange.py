@@ -65,22 +65,40 @@ def test_exchange_exec_mesh_vs_local_lane(mesh8, rng):
         compare_frames(lp, mp, f"part{p}")
 
 
-def test_mesh_lane_shards_on_every_device_then_one_device_out(mesh8):
-    """The exchange's output holds a shard on every mesh device, and the
-    partitions it hands downstream are single-device arrays: on real
-    chips a Mosaic kernel fed an array still spread over the mesh
-    cannot be partitioned (four v5e chips, PR 25)."""
+def test_mesh_lane_shards_on_every_device_and_partition_d_stays_on_chip_d(
+        mesh8):
+    """The exchange's output holds a shard on every mesh device, and
+    partition d is chip d's own shard of it: every array of it is a
+    single-device array on chip d (on real chips a Mosaic kernel fed an
+    array still spread over the mesh cannot be partitioned: four v5e
+    chips, PR 25), and nothing came home to the first chip."""
+    from spark_rapids_tpu.utils import checks as CK
     ShuffleExchangeExec._MESH_SHARD_DEVICES = []
+    chips = list(mesh8.devices.flat)
     with active_mesh(mesh8):
         meshed = ShuffleExchangeExec(
             HashPartitioning([col("k")], 8), _source(
-                np.random.default_rng(42)))
-        batches = [b for it in meshed.execute_partitions() for b in it]
-    assert ShuffleExchangeExec._MESH_SHARD_DEVICES == [list(range(8))]
-    assert batches
-    for b in batches:
-        for c in b.columns:
-            assert len(c.data.devices()) == 1, c.data.sharding
+                np.random.default_rng(42), n_parts=8))
+        parts = [list(it) for it in meshed.execute_partitions()]
+        # a child with one partition a chip, already there: stacked
+        # where it lies, so no byte crosses chips but in the all-to-all
+        CK.reset_cross_chip_moves()
+        again = ShuffleExchangeExec(
+            HashPartitioning([col("v")], 8),
+            LocalBatchSource(parts, parts[0][0].schema))
+        parts2 = [list(it) for it in again.execute_partitions()]
+        assert CK.cross_chip_moves() == 0
+    assert ShuffleExchangeExec._MESH_SHARD_DEVICES == 2 * [list(range(8))]
+    assert sum(b.num_rows for p in parts for b in p) == \
+        sum(b.num_rows for p in parts2 for b in p) == 8 * 200
+    for got in (parts, parts2):
+        assert sum(bool(p) for p in got) > 1
+        for d, part in enumerate(got):
+            for b in part:
+                for c in b.columns:
+                    for a in (c.data, c.validity, c.lengths):
+                        assert a is None or a.devices() == {chips[d]}, \
+                            (d, a.sharding)
 
 
 def test_mesh_lane_declines_without_mesh(rng):
