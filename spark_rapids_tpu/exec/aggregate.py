@@ -11,6 +11,20 @@ Modes mirror Spark: Partial (update only, emits keys+intermediates),
 Final (merge intermediates, evaluate), Complete (update+evaluate in one
 node — used for single-stage local plans).  The reduction path (no group
 keys) skips the sort entirely and uses masked whole-batch reductions.
+
+The grouped kernel (`_groupby_kernel`: `jit_agg_update` / `jit_agg_merge`)
+holds two bodies under one `lax.cond`, chosen on the device from the
+batch alone.  `elect_group_leaders` spends up to `FEW_GROUPS_MAX` rounds
+of exact key comparison naming the batch's groups; a batch they cover
+takes `_few_groups` (no sort, no gather, no scan: each measure reduced
+once a group under the group's mask, in its own dtype), any other
+`_sorted_groups`, today's sort body, after the rounds it lost (one pass
+over the key columns each).  The cond is built when every function
+reduces through the kernel's context (`reduces_through_scans`) and the
+banded lane is not taken, in both phases; it has no switch, keeps no
+state, and registers no check (membership is never a hash's word).
+Which body ran comes back as a device scalar and is counted lazily
+(`numFewGroupBatches` of `numFewGroupsOffered`).
 """
 from __future__ import annotations
 
@@ -21,6 +35,7 @@ from typing import Iterator, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import (
@@ -31,9 +46,10 @@ from spark_rapids_tpu.exec.base import (
     SchemaOnlyExec as _SchemaOnly, TpuExec, UnaryExecBase,
     batch_signature, make_eval_context, named_jit)
 from spark_rapids_tpu.exprs.aggregates import (
-    AggAlias, AggContext, AggregateFunction)
+    AggAlias, AggContext, AggregateFunction, run_agg_phase)
 from spark_rapids_tpu.exprs.base import Expression, output_name
-from spark_rapids_tpu.ops.sort_encode import (hash_sort_bounds,
+from spark_rapids_tpu.ops.sort_encode import (elect_group_leaders,
+                                              hash_sort_bounds,
                                               sort_with_bounds,
                                               wide_key_set)
 from spark_rapids_tpu.utils import checks as CK
@@ -138,8 +154,12 @@ class HashAggregateExec(UnaryExecBase):
         # per-key pads for the composite multi-key path), sized from a
         # one-time first-batch range probe (None until probed)
         self._dict_gpad: Optional[object] = None
-        #: the lane the last batch took: "dict", "banded",
-        #: "sort-segment" or "reduce" (the group-by spans' `lane`)
+        #: the lane the last batch took: "dict", "banded", "reduce",
+        #: "sort-segment" (the grouped kernel with its sort body alone)
+        #: or "few-or-sort" (the same with the few-groups body built
+        #: in: WHICH body a batch took is decided on the device and
+        #: counted in `numFewGroupBatches`); host-known, the group-by
+        #: spans' `lane`
         self._lane: Optional[str] = None
 
     def output_schema(self) -> T.Schema:
@@ -322,109 +342,51 @@ class HashAggregateExec(UnaryExecBase):
         escalate-and-retry contract as _compact_groups)."""
         use_hash = self._use_hash_grouping(batch)
         use_banded = self._use_banded(batch, phase)
-        self._lane = "banded" if use_banded else "sort-segment"
-        key = ("agg", phase, use_hash, use_banded, wcap,
+        out_cap = wcap if wcap is not None else batch.capacity
+        few_slots = 0 if use_banded else self._few_groups_slots(out_cap)
+        self._lane = ("banded" if use_banded else
+                      "few-or-sort" if few_slots else "sort-segment")
+        key = ("agg", phase, use_hash, use_banded, wcap, few_slots,
                batch_signature(batch))
         kp_members = (self._pre_stage.member_names()
                       if self._pre_stage is not None else None)
 
         def build():
             cap = batch.capacity
-            out_cap = wcap if wcap is not None else cap
-            bound_groups = self._bound_groups
-            funcs = self._funcs
 
             @named_jit(f"agg-{phase}")
             def kernel(columns, num_rows, mask=None):
                 ctx = self._make_ctx(columns, cap, num_rows, mask)
-                keys = [e.eval(ctx) for e in bound_groups]
-                if use_hash:
-                    perm, sorted_valid, bounds, collision = \
-                        hash_sort_bounds([(k, True, True) for k in keys],
-                                         ctx.row_mask)
-                else:
-                    perm, sorted_valid, bounds, _ = sort_with_bounds(
-                        [(k, True, True) for k in keys], ctx.row_mask)
-                    collision = None
-                seg_ids = jnp.cumsum(bounds.astype(jnp.int32)) - 1
-                num_groups = bounds.sum().astype(jnp.int32)
-                excess = (num_groups > out_cap) if wcap is not None \
-                    else None
-                grp_valid = jnp.arange(out_cap) < num_groups
-
+                keys = [e.eval(ctx) for e in self._bound_groups]
                 if phase == "update":
                     inputs_per_f = [
                         [e.eval(ctx) for e in bins]
                         for bins in self._bound_inputs]
-                    flat = [v for ins in inputs_per_f for v in ins]
                 else:
                     inputs_per_f = [
                         [ctx.columns[i] for i in range(lo, hi)]
                         for lo, hi in self._inter_offsets]
-                    flat = [v for ins in inputs_per_f for v in ins]
-                # grouped-stream reorder: ALL 4-byte value streams plus
-                # the packed validity word ride ONE stacked gather and
-                # f64 streams another (random access costs ~70ns per
-                # ROW, not per byte — a 4-measure agg paid 4 gathers
-                # here before)
-                from spark_rapids_tpu.columnar.vector import \
-                    gather_columns_grouped
-                sorted_flat = gather_columns_grouped(flat, perm,
-                                                     sorted_valid)
-                it = iter(sorted_flat)
-                sorted_per_f = [[next(it) for _ in ins]
-                                for ins in inputs_per_f]
 
-                if use_banded:
-                    out_cols, first_idx, cert = self._banded_aggregate(
-                        phase, sorted_per_f, sorted_valid, bounds,
-                        seg_ids, grp_valid, cap, out_cap)
-                    rep_idx = jnp.take(perm, first_idx, mode="clip")
-                    key_cols = [k.gather(rep_idx, grp_valid)
-                                for k in keys]
-                    return (key_cols + out_cols, num_groups, collision,
-                            excess, cert)
+                def sorted_body():
+                    return self._sorted_groups(
+                        phase, use_hash, use_banded, wcap, keys,
+                        inputs_per_f, ctx.row_mask)
 
-                # group key representatives: first row of each segment
-                from spark_rapids_tpu.ops.sort_encode import \
-                    masked_positions
-                first_idx = masked_positions(bounds, out_cap,
-                                             fill_value=cap - 1)
-                # per-segment LAST sorted row: one before the next
-                # segment's start; the last real segment (which also
-                # absorbs trailing invalid rows' segment ids) ends at
-                # cap-1 — aggregates fill invalid rows with identities
-                nxt = jnp.concatenate(
-                    [first_idx[1:],
-                     jnp.full((1,), cap, first_idx.dtype)])
-                ends = jnp.where(jnp.arange(out_cap) >= num_groups - 1,
-                                 cap - 1, nxt - 1).astype(jnp.int32)
-                actx = AggContext(seg_ids, cap, sorted_valid, bounds,
-                                  ends, out_capacity=out_cap)
+                if not few_slots:
+                    return sorted_body() + (None,)
+                # the batch says how many groups it has: up to
+                # `few_slots` of them are named by exact key equality,
+                # and only a batch with more pays the sort
+                slot, leaders, n_few, overflow = elect_group_leaders(
+                    keys, ctx.row_mask, few_slots)
 
-                out_cols = []
-                # representatives via index COMPOSITION: one i32 gather
-                # (perm at first_idx) + one gather per key column — the
-                # sorted_keys detour re-gathered every key column at
-                # full cap twice (random-access streams are the
-                # dominant kernel cost at ~70ns/row on this chip)
-                rep_idx = jnp.take(perm, first_idx, mode="clip")
-                for k in keys:
-                    out_cols.append(k.gather(rep_idx, grp_valid))
+                def few_body():
+                    return self._few_groups(
+                        phase, use_hash, wcap, keys, inputs_per_f,
+                        ctx.row_mask, slot, leaders, n_few)
 
-                # ONE cross-function segmented scan per round (each
-                # function's operands batch into a shared _segscan —
-                # a q1-shaped aggregate ran 8 separate 2M-row scan
-                # dispatches at ~100ms each before)
-                from spark_rapids_tpu.exprs.aggregates import \
-                    run_agg_phase
-                for outs in run_agg_phase(actx, funcs, sorted_per_f,
-                                          phase):
-                    out_cols.extend(
-                        ColumnVector(o.dtype, o.data,
-                                     o.validity & grp_valid,
-                                     o.lengths) for o in outs)
-                return out_cols, num_groups, collision, excess, None
+                return lax.cond(overflow, sorted_body, few_body) + (
+                    (~overflow).astype(jnp.int32),)
 
             return kernel
 
@@ -434,6 +396,134 @@ class HashAggregateExec(UnaryExecBase):
         return self.kernels.get_or_build(
             key, build,
             meta=self.kp_meta(f"agg-{phase}", members=kp_members))
+
+    #: a batch with at most this many groups is grouped without a sort
+    #: (`_few_groups`): the grouped kernel spends up to this many rounds
+    #: of exact key comparison on finding that out, so a batch of many
+    #: groups pays them on top of its sort.  Not a switch: no conf key
+    #: reads it, and either body gives the same groups
+    FEW_GROUPS_MAX = 16
+
+    def _few_groups_slots(self, out_cap: int) -> int:
+        """How many slots the kernel's few-groups body gets, 0 where it
+        is not built: every function must leave HOW a group is reduced
+        to the context (`reduces_through_scans`)."""
+        if not all(f.reduces_through_scans(ts)
+                   for f, ts in zip(self._funcs, self._inter_types)):
+            return 0
+        return min(self.FEW_GROUPS_MAX, out_cap)
+
+    def _sorted_groups(self, phase, use_hash, use_banded, wcap, keys,
+                       inputs_per_f, row_mask):
+        """The grouped kernel's sort body: rows sorted by group (hash
+        or lexicographic words), one stacked gather of the value
+        streams, one segmented scan a round.  Returns (columns,
+        num_groups, collision, excess, cert)."""
+        cap = row_mask.shape[0]
+        out_cap = wcap if wcap is not None else cap
+        if use_hash:
+            perm, sorted_valid, bounds, collision = \
+                hash_sort_bounds([(k, True, True) for k in keys],
+                                 row_mask)
+        else:
+            perm, sorted_valid, bounds, _ = sort_with_bounds(
+                [(k, True, True) for k in keys], row_mask)
+            collision = None
+        seg_ids = jnp.cumsum(bounds.astype(jnp.int32)) - 1
+        num_groups = bounds.sum().astype(jnp.int32)
+        excess = (num_groups > out_cap) if wcap is not None \
+            else None
+        grp_valid = jnp.arange(out_cap) < num_groups
+
+        flat = [v for ins in inputs_per_f for v in ins]
+        # grouped-stream reorder: ALL 4-byte value streams plus
+        # the packed validity word ride ONE stacked gather and
+        # f64 streams another (random access costs ~70ns per
+        # ROW, not per byte — a 4-measure agg paid 4 gathers
+        # here before)
+        from spark_rapids_tpu.columnar.vector import \
+            gather_columns_grouped
+        sorted_flat = gather_columns_grouped(flat, perm,
+                                             sorted_valid)
+        it = iter(sorted_flat)
+        sorted_per_f = [[next(it) for _ in ins]
+                        for ins in inputs_per_f]
+
+        if use_banded:
+            out_cols, first_idx, cert = self._banded_aggregate(
+                phase, sorted_per_f, sorted_valid, bounds,
+                seg_ids, grp_valid, cap, out_cap)
+            rep_idx = jnp.take(perm, first_idx, mode="clip")
+            key_cols = [k.gather(rep_idx, grp_valid)
+                        for k in keys]
+            return (key_cols + out_cols, num_groups, collision,
+                    excess, cert)
+
+        # group key representatives: first row of each segment
+        from spark_rapids_tpu.ops.sort_encode import \
+            masked_positions
+        first_idx = masked_positions(bounds, out_cap,
+                                     fill_value=cap - 1)
+        # per-segment LAST sorted row: one before the next
+        # segment's start; the last real segment (which also
+        # absorbs trailing invalid rows' segment ids) ends at
+        # cap-1 — aggregates fill invalid rows with identities
+        nxt = jnp.concatenate(
+            [first_idx[1:],
+             jnp.full((1,), cap, first_idx.dtype)])
+        ends = jnp.where(jnp.arange(out_cap) >= num_groups - 1,
+                         cap - 1, nxt - 1).astype(jnp.int32)
+        actx = AggContext(seg_ids, cap, sorted_valid, bounds,
+                          ends, out_capacity=out_cap)
+
+        out_cols = []
+        # representatives via index COMPOSITION: one i32 gather
+        # (perm at first_idx) + one gather per key column — the
+        # sorted_keys detour re-gathered every key column at
+        # full cap twice (random-access streams are the
+        # dominant kernel cost at ~70ns/row on this chip)
+        rep_idx = jnp.take(perm, first_idx, mode="clip")
+        for k in keys:
+            out_cols.append(k.gather(rep_idx, grp_valid))
+
+        # ONE cross-function segmented scan per round (each
+        # function's operands batch into a shared _segscan —
+        # a q1-shaped aggregate ran 8 separate 2M-row scan
+        # dispatches at ~100ms each before)
+        for outs in run_agg_phase(actx, self._funcs, sorted_per_f,
+                                  phase):
+            out_cols.extend(
+                ColumnVector(o.dtype, o.data,
+                             o.validity & grp_valid,
+                             o.lengths) for o in outs)
+        return out_cols, num_groups, collision, excess, None
+
+    def _few_groups(self, phase, use_hash, wcap, keys, inputs_per_f,
+                    row_mask, slot, leaders, num_groups):
+        """The grouped kernel's few-groups body, for a batch whose
+        groups `elect_group_leaders` could all name: no sort, no gather
+        of the value streams, no scan.  The measures stay in row order,
+        every function reduces under its slot's mask
+        (`AggContext.few_groups`), a group's keys are its leader's, and
+        the groups come out in order of first appearance at `slots`
+        rows, padded to the sort body's `out_cap`.  Membership was
+        decided by exact equality, so there is nothing to check: the
+        collision and excess flags are the sort body's, never set."""
+        cap, slots = row_mask.shape[0], leaders.shape[0]
+        out_cap = wcap if wcap is not None else cap
+        live = jnp.arange(slots) < num_groups
+        actx = AggContext(slot, cap, row_mask, out_capacity=slots,
+                          few_groups=True, num_groups=num_groups)
+        out_cols = [k.gather(leaders, live) for k in keys]
+        for outs in run_agg_phase(actx, self._funcs, inputs_per_f,
+                                  phase):
+            out_cols.extend(
+                ColumnVector(o.dtype, o.data, o.validity & live,
+                             o.lengths) for o in outs)
+        never = jnp.zeros((), bool)
+        return ([c.with_capacity(out_cap) for c in out_cols], num_groups,
+                never if use_hash else None,
+                never if wcap is not None else None, None)
 
     def _banded_aggregate(self, phase, sorted_per_f, sorted_valid,
                           bounds, seg_ids, grp_valid, cap, out_cap):
@@ -1249,17 +1339,27 @@ class HashAggregateExec(UnaryExecBase):
         wcap = self._kernel_compact_cap(batch)
         kern = self._groupby_kernel(batch, phase, wcap)
         if batch.sparse is not None:
-            cols, n, coll, excess, cert = kern(
+            cols, n, coll, excess, cert, few = kern(
                 batch.columns, batch.num_rows_i32, batch.sparse)
         else:
-            cols, n, coll, excess, cert = kern(
+            cols, n, coll, excess, cert, few = kern(
                 batch.columns, batch.num_rows_i32)
         self._charge_pre_stage(t0)
+        self._count_few_groups(few)
         checks = self._register_collision_check(coll, batch.checks)
         checks = self._register_excess_check(excess, wcap, checks)
         checks = self._register_banded_check(cert, checks)
         return ColumnarBatch(self._partial_schema(), list(cols), n,
                              checks)
+
+    def _count_few_groups(self, few) -> None:
+        """`few`: the kernel's word on which body ran (a device int32,
+        1 = the few-groups body), None where the kernel has one body.
+        Queued like a lazy row count, so it is read, if ever, in the
+        stacked read that resolves this exec's metrics."""
+        if few is not None:
+            self.metrics.add(M.NUM_FEW_GROUPS_OFFERED, 1)
+            self.metrics.add(M.NUM_FEW_GROUP_BATCHES, few)
 
     def _evaluate_one(self, merged: ColumnarBatch) -> ColumnarBatch:
         kern = self._evaluate_kernel(merged)
@@ -1402,11 +1502,12 @@ class HashAggregateExec(UnaryExecBase):
         with self.metrics.timed(M.TOTAL_TIME):
             kern = merge_exec._groupby_kernel(merged, "merge", wcap)
             if merged.sparse is not None:
-                cols, n, coll, excess, cert = kern(
+                cols, n, coll, excess, cert, few = kern(
                     merged.columns, merged.num_rows_i32, merged.sparse)
             else:
-                cols, n, coll, excess, cert = kern(
+                cols, n, coll, excess, cert, few = kern(
                     merged.columns, merged.num_rows_i32)
+        self._count_few_groups(few)
         checks = merge_exec._register_collision_check(coll, merged.checks)
         # escalation is learned on the OUTER exec (the merge exec is a
         # cached internal helper; the compact policy lives with self)
@@ -1494,8 +1595,6 @@ class HashAggregateExec(UnaryExecBase):
                         n = f.num_intermediates
                         inputs_per_f.append(columns[off: off + n])
                         off += n
-                from spark_rapids_tpu.exprs.aggregates import \
-                    run_agg_phase
                 out_cols = []
                 for outs in run_agg_phase(actx, funcs, inputs_per_f,
                                           phase):

@@ -20,6 +20,14 @@ is the one case that is not a segment op: its context says
 `single_segment`, and every operand a function registers is reduced over
 the whole batch with the plain reduction of its op (a total needs no
 scan), the partial emitted as the one row it is.
+
+The grouped kernel's FEW-GROUPS body (`exec/aggregate._few_groups`) is
+the other: its context says `few_groups`, the rows are not sorted,
+`seg_ids` is each row's slot, and every operand a function registers is
+reduced once a live slot under the slot's mask (`_reduce_slots`), with
+its own op in its own dtype.  The functions are the same code in all
+three; only `ScanBatch.run_round` and `_sorted_seg_sums` ask the context
+how a group is reduced.
 """
 from __future__ import annotations
 
@@ -116,6 +124,44 @@ _REDUCE_OPS = {
 }
 
 
+def _identity(op: str, dtype):
+    """The element a reduction of `op` over no row of `dtype` gives."""
+    if op == "add":
+        return jnp.zeros((), dtype)
+    if jnp.issubdtype(dtype, jnp.floating):
+        return jnp.asarray(jnp.inf if op == "min" else -jnp.inf, dtype)
+    if dtype == jnp.bool_:
+        return jnp.asarray(op == "min", dtype)
+    info = jnp.iinfo(dtype)
+    return jnp.asarray(info.max if op == "min" else info.min, dtype)
+
+
+def _reduce_slots(ops, arrs, ctx: "AggContext"):
+    """The few-groups body's reduction: rows stay in row order and
+    `ctx.seg_ids` holds each row's slot, so every operand is reduced
+    once a LIVE slot under the slot's mask, with its own reduction in
+    its own dtype (a FLOAT64 sum stays float64).  One loop turn a
+    group the batch really has (`ctx.num_groups`, on the device): four
+    groups cost four masked passes whatever the slots' number.  Slots
+    past the count keep the identity; the caller masks them."""
+    k = ctx.out_capacity
+    idents = [_identity(op, a.dtype) for op, a in zip(ops, arrs)]
+
+    def one_slot(g, accs):
+        mine = ctx.seg_ids == g
+        out = []
+        for op, a, ident, acc in zip(ops, arrs, idents, accs):
+            m = mine.reshape(mine.shape + (1,) * (a.ndim - 1))
+            out.append(acc.at[g].set(
+                _REDUCE_OPS[op](jnp.where(m, a, ident))))
+        return tuple(out)
+
+    return jax.lax.fori_loop(
+        0, ctx.num_groups, one_slot,
+        tuple(jnp.full((k,) + a.shape[1:], ident, a.dtype)
+              for a, ident in zip(arrs, idents)))
+
+
 def _reduce_all(op: str, arr, out_capacity: int):
     """One segment covering every row: `arr` reduced over axis 0 in its
     own dtype (a FLOAT64 sum stays float64; only the order of the
@@ -166,23 +212,25 @@ class ScanBatch:
     def run_round(self) -> None:
         if not self._pend:
             return
+        idxs = [h for h, _ in self._pend]
+        arrs = [a for _, a in self._pend]
+        ops = [self._ops[h] for h in idxs]
         if self._ctx.single_segment:
             # the ungrouped aggregate: a total needs no scan
-            for h, a in self._pend:
-                self._results[h] = _reduce_all(
-                    self._ops[h], a, self._ctx.out_capacity)
+            outs = [_reduce_all(op, a, self._ctx.out_capacity)
+                    for op, a in zip(ops, arrs)]
+        elif self._ctx.few_groups:
+            # a slot a group, rows unsorted: masked reductions, no scan
+            outs = _reduce_slots(ops, arrs, self._ctx)
         else:
-            idxs = [h for h, _ in self._pend]
-            arrs = [a for _, a in self._pend]
-            ops = [_SCAN_OPS[self._ops[h]] for h in idxs]
+            scan_ops = [_SCAN_OPS[op] for op in ops]
 
             def combine(a, b):
-                return tuple(op(x, y) for op, x, y in zip(ops, a, b))
+                return tuple(op(x, y) for op, x, y in zip(scan_ops, a, b))
 
             runs = _segscan(combine, self._ctx.bounds, *arrs)
-            ends = self._ctx.ends
-            for h, r in zip(idxs, runs):
-                self._results[h] = jnp.take(r, ends)
+            outs = [jnp.take(r, self._ctx.ends) for r in runs]
+        self._results.update(zip(idxs, outs))
         self._pend = []
 
     def result(self, h: int):
@@ -247,6 +295,8 @@ def _sorted_seg_sums(ctx: "AggContext", *vals):
     (they share the last group's segment id)."""
     if ctx.single_segment:
         return tuple(_reduce_all("add", v, ctx.out_capacity) for v in vals)
+    if ctx.few_groups:
+        return _reduce_slots(["add"] * len(vals), vals, ctx)
     runs = _segscan(lambda a, b: tuple(x + y for x, y in zip(a, b)),
                     ctx.bounds, *vals)
     return tuple(jnp.take(r, ctx.ends) for r in runs)
@@ -277,11 +327,22 @@ class AggContext:
     #: else: exactly one segment covers every row (`seg_ids` all zero),
     #: so scan operands are plainly reduced and `bounds` / `ends` unused
     single_segment: bool = False
+    #: a STATIC fact, set by the grouped kernel's few-groups body and
+    #: nobody else: the rows are NOT sorted; `seg_ids` is each row's
+    #: slot (-1: none), a slot a group in order of first appearance,
+    #: `out_capacity` the number of slots and `num_groups` (a device
+    #: scalar) how many of them are live.  Scan operands are reduced
+    #: once a live slot under its mask (`_reduce_slots`); `bounds` /
+    #: `ends` unused
+    few_groups: bool = False
+    num_groups: Optional[jnp.ndarray] = None
 
     def __post_init__(self):
         assert self.single_segment or (
+            self.num_groups is not None if self.few_groups else
             self.bounds is not None and self.ends is not None), \
-            "a grouped AggContext needs its bounds and ends"
+            "a grouped AggContext needs its bounds and ends, or its " \
+            "slots' count"
         if self.out_capacity is None:
             self.out_capacity = self.capacity
 
@@ -334,6 +395,13 @@ class AggregateFunction:
     def merge_scans(self, ctx: AggContext, scans: "ScanBatch",
                     partials: Sequence[ColumnVector]):
         return None
+
+    def reduces_through_scans(self, inter: Sequence[T.DataType]) -> bool:
+        """True when both phases drive every reduction through
+        `ScanBatch.seg` (given this function's intermediate types), so
+        the context alone decides HOW a group is reduced: what the
+        grouped kernel's few-groups body asks of every function."""
+        return True
 
     def evaluate(self, partials: Sequence[ColumnVector],
                  schema: T.Schema) -> ColumnVector:
@@ -493,6 +561,11 @@ class _MinMax(AggregateFunction):
 
     def intermediate_types(self, schema):
         return (self.child.data_type(schema),)
+
+    def reduces_through_scans(self, inter):
+        # a string's winner comes from `_update_string`'s own lexsort
+        # over the SORTED segments
+        return not inter[0].is_string
 
     def update(self, ctx, inputs):
         (v,) = inputs

@@ -18,6 +18,7 @@ Encodings (all yield uint64/int16 keys whose integer order == SQL order):
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -507,11 +508,19 @@ def masked_positions(mask: jnp.ndarray, size: int,
       - large size: ONE stable 1-bit-key sort carrying the iota as a
         payload operand (payload moves are ~free in the sort network;
         cost is flat in `size` where top_k grows with k)
-      - size covering the array: nonzero fallback."""
+      - size covering the array (a small batch: its groups are not
+        compacted): a running count and one small scatter, in 32 bits.
+        `jnp.nonzero(size=...)` is the same in 64 (x64 is on), and its
+        int64 cumsum does not compile for the chip inside a
+        `lax.cond` branch at 256 to 1,024 rows (the branch's scoped
+        VMEM: 19 MB asked of 16; PR 36), where the grouped kernel's
+        sort body now lives."""
     cap = mask.shape[0]
-    if size * 2 > cap:
-        return jnp.nonzero(mask, size=size, fill_value=fill_value)[0]
     iota = lax.iota(jnp.int32, cap)
+    if size * 2 > cap:
+        nth = jnp.cumsum(mask, dtype=jnp.int32) - 1
+        return jnp.full(size, fill_value, jnp.int32).at[
+            jnp.where(mask, nth, size)].set(iota, mode="drop")
     if size <= MASKED_POSITIONS_TOPK_MAX:
         keyv = jnp.where(mask, iota, jnp.iinfo(jnp.int32).max)
         neg, _ = lax.top_k(-keyv, size)
@@ -522,6 +531,59 @@ def masked_positions(mask: jnp.ndarray, size: int,
     count = mask.sum()
     head = sorted_iota[:size]
     return jnp.where(jnp.arange(size) < count, head, fill_value)
+
+
+def key_rows_differ(col: ColumnVector, v, d, ln, v_o, d_o, ln_o):
+    """SQL group INEQUALITY of one key column's rows against other rows
+    of it: (validity, data, lengths) of each side, the other side
+    broadcastable against the first (the previous sorted row of each
+    row, or one leader row for all).  Null equals null, NaN equals NaN
+    (and -0.0 equals 0.0), a string's bytes count inside its length
+    only and the lengths must be equal.  A string's `d` is its
+    `[rows, char_cap]` byte matrix, or the list of its
+    `packed_string_words`.  The one definition of a group key's
+    equality: `segment_boundaries` reads the sorted lanes' boundaries
+    off it and `elect_group_leaders` a row's slot."""
+    if isinstance(d, (list, tuple)):
+        # a string column as `packed_string_words`: with the lengths,
+        # equal words are equal strings
+        val_neq = ln != ln_o
+        for w, w_o in zip(d, d_o):
+            val_neq = val_neq | (w != w_o)
+    elif col.dtype.is_string:
+        pos = jnp.arange(col.char_cap)[None, :]
+        in_a = pos < ln[:, None]
+        in_b = pos < ln_o[:, None]
+        byte_neq = jnp.where(in_a | in_b,
+                             jnp.where(in_a & in_b,
+                                       d != d_o, True),
+                             False).any(axis=1)
+        val_neq = byte_neq | (ln != ln_o)
+    elif col.dtype.is_floating:
+        # group NaNs together
+        both_nan = jnp.isnan(d) & jnp.isnan(d_o)
+        val_neq = (d != d_o) & ~both_nan
+    else:
+        val_neq = d != d_o
+    return (v != v_o) | (v & v_o & val_neq)
+
+
+def packed_string_words(col: ColumnVector) -> list:
+    """A string column's bytes as uint32 words, four bytes a word, a
+    byte at or past its row's length zeroed: one pass over the byte
+    matrix, after which a comparison of rows is a comparison of a few
+    1-D words (a round of `elect_group_leaders` over the matrix itself
+    was 0.12 ms a key column at 65,536 rows on the chip: PR 36)."""
+    pos = jnp.arange(col.char_cap)[None, :]
+    inside = jnp.where(pos < col.lengths[:, None], col.data,
+                       0).astype(jnp.uint32)
+    words = []
+    for at in range(0, col.char_cap, 4):
+        word = inside[:, at]
+        for b in range(1, min(4, col.char_cap - at)):
+            word = word | (inside[:, at + b] << (8 * b))
+        words.append(word)
+    return words
 
 
 def segment_boundaries(key_cols: list[ColumnVector],
@@ -541,24 +603,62 @@ def segment_boundaries(key_cols: list[ColumnVector],
             ln = jnp.take(col.lengths, perm)
             d_prev = jnp.roll(d, 1, axis=0)
             ln_prev = jnp.roll(ln, 1)
-            pos = jnp.arange(col.char_cap)[None, :]
-            in_a = pos < ln[:, None]
-            in_b = pos < ln_prev[:, None]
-            byte_neq = jnp.where(in_a | in_b,
-                                 jnp.where(in_a & in_b,
-                                           d != d_prev, True),
-                                 False).any(axis=1)
-            val_neq = byte_neq | (ln != ln_prev)
         else:
             d = jnp.take(col.data, perm)
             d_prev = jnp.roll(d, 1)
-            if col.dtype.is_floating:
-                # group NaNs together
-                both_nan = jnp.isnan(d) & jnp.isnan(d_prev)
-                val_neq = (d != d_prev) & ~both_nan
-            else:
-                val_neq = d != d_prev
-        neq = (v != v_prev) | (v & v_prev & val_neq)
-        diff = diff | neq
+            ln = ln_prev = None
+        diff = diff | key_rows_differ(col, v, d, ln, v_prev, d_prev,
+                                      ln_prev)
     first = jnp.arange(cap) == 0
     return sorted_mask & (diff | first)
+
+
+def elect_group_leaders(key_cols: list[ColumnVector],
+                        row_mask: jnp.ndarray, max_groups: int):
+    """Name a batch's groups without a sort, if it has few: round g
+    takes the first live row (`row_mask`) that has no slot yet as the
+    group's leader and gives every live row whose keys EQUAL the
+    leader's (`key_rows_differ`: exact, no hash) slot g.  The rounds
+    end when no live row is left without a slot, or after `max_groups`
+    of them, so a batch costs one pass over its key columns a group it
+    has, and a batch of many groups `max_groups` passes.
+
+    Returns (slot, leaders, num_groups, overflow): the per-row slot
+    (-1: a dead row, or one no round reached), the leaders' row
+    indices ([max_groups]; groups in order of first appearance), the
+    rounds that found a leader, and whether a live row is still
+    without a slot (the batch has more than `max_groups` groups)."""
+    cap = row_mask.shape[0]
+    iota = lax.iota(jnp.int32, cap)
+
+    def one_row(x, i):
+        return lax.dynamic_slice_in_dim(x, i, 1, axis=0)
+
+    def rounds_left(state):
+        g, _slot, _leaders, more = state
+        return more & (g < max_groups)
+
+    # (validity, data, lengths) a key column, as `key_rows_differ`
+    # takes them; a string's bytes packed into words once, not
+    # compared as a matrix a round
+    parts = [(col.validity, packed_string_words(col), col.lengths)
+             if col.dtype.is_string else (col.validity, col.data, None)
+             for col in key_cols]
+
+    def a_round(state):
+        g, slot, leaders, _ = state
+        free = row_mask & (slot < 0)
+        lead = jnp.min(jnp.where(free, iota, cap - 1))
+        same = free
+        for col, part in zip(key_cols, parts):
+            theirs = jax.tree_util.tree_map(
+                lambda x: one_row(x, lead), part)
+            same = same & ~key_rows_differ(col, *part, *theirs)
+        return (g + 1, jnp.where(same, g, slot),
+                leaders.at[g].set(lead), jnp.any(free & ~same))
+
+    g, slot, leaders, more = lax.while_loop(
+        rounds_left, a_round,
+        (jnp.int32(0), jnp.full(cap, -1, jnp.int32),
+         jnp.zeros(max_groups, jnp.int32), jnp.any(row_mask)))
+    return slot, leaders, g, more

@@ -65,6 +65,12 @@ NUM_FUSION_DEOPTS = "numFusionDeopts"
 # gangs that deopted back to the per-partition lane
 NUM_SPMD_DISPATCHES = "numSpmdDispatches"
 NUM_SPMD_DEOPTS = "numSpmdDeopts"
+# the grouped aggregate's few-groups body (exec/aggregate.py): batches
+# the update / merge kernel was handed with that body built in, and how
+# many of them it took (a device count: the batch decides, on the
+# device, by how many groups it has)
+NUM_FEW_GROUPS_OFFERED = "numFewGroupsOffered"
+NUM_FEW_GROUP_BATCHES = "numFewGroupBatches"
 # HBM residency ledger (utils/residency.py): tracked buffers still
 # attributed to a query when it finished — charged to the collected
 # plan root by the end-of-query leak check

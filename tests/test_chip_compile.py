@@ -243,3 +243,58 @@ def test_upload_split_of_string_columns_compiles_for_v5e(one_chip):
     assert len(outs) == 45 * 6
     assert {o.shape for o in outs} == {(CAP,), (CAP, 8), (CAP, 32)}
     assert compiled.memory_analysis() is not None
+
+
+# -- the grouped kernel at the SMALL capacities a merge runs at -----------
+@pytest.mark.parametrize("phase,cap", [
+    ("merge", 256),     # q1 at SF1: a partition's 46 partials, 184 rows
+    ("merge", 1024),
+    ("update", 1024),
+    ("update", 16384),  # the largest batch whose groups are not compacted
+])
+def test_grouped_kernel_compiles_at_small_capacities_for_v5e(one_chip,
+                                                             phase, cap):
+    """Both bodies of `jit_agg_update` / `jit_agg_merge` live in one
+    `lax.cond`, and at a few hundred rows the compiler keeps a branch's
+    buffers in VMEM: PR 36's first hand-in compiled at 65,536 rows here
+    and failed ON THE CHIP at 256 (`masked_positions`' int64 cumsum, 19
+    MB of scoped VMEM asked of 16).  q1's two string keys (the hash
+    lane), FLOAT64 measures."""
+    from spark_rapids_tpu import config as C
+    from spark_rapids_tpu.columnar.batch import ColumnarBatch
+    from spark_rapids_tpu.exec.aggregate import AggMode, HashAggregateExec
+    from spark_rapids_tpu.exec.basic import LocalBatchSource
+    from spark_rapids_tpu.exprs.aggregates import Average, Count, Sum
+    from spark_rapids_tpu.exprs.base import col
+    rng = np.random.default_rng(0)
+    rows = {"k": np.array(["ANR"[i % 3] for i in range(cap)], object),
+            "k2": np.array(["FO"[i % 2] for i in range(cap)], object),
+            "v": rng.uniform(1, 2, cap), "w": rng.uniform(1, 2, cap)}
+    funcs = [Sum(col("v")).alias("s"), Average(col("w")).alias("a"),
+             Count(None).alias("c")]
+    batch = ColumnarBatch.from_numpy(rows)
+    agg = HashAggregateExec([col("k"), col("k2")], funcs,
+                            LocalBatchSource([[batch]]),
+                            mode=AggMode.PARTIAL)
+    with C.session(C.RapidsConf({})):
+        if phase == "merge":
+            inter = agg._partial_schema()
+            (part,) = list(agg.execute_columnar())
+            batch = ColumnarBatch(inter, [c.with_capacity(cap)
+                                          for c in part.columns],
+                                  part.num_rows)
+            exec_ = agg._get_merge_exec(inter)
+        else:
+            exec_ = agg
+        kern = exec_._groupby_kernel(batch, phase,
+                                     agg._kernel_compact_cap(batch))
+    assert exec_._lane == "few-or-sort"
+
+    def place(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    args = jax.tree_util.tree_map(place,
+                                  (batch.columns, batch.num_rows_i32))
+    compiled = getattr(kern, "_ck_fn", kern).lower(*args).compile()
+    assert " conditional(" in compiled.as_text()
+    assert compiled.memory_analysis() is not None

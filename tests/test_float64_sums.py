@@ -205,10 +205,10 @@ MEASURES = {
 #: FLOAT32 measure merges on the sort-segment lane; INT64 intermediates
 #: stay on the banded lane.
 EXPECTED = {
-    ("float64", False): ("sort-segment", "sort-segment"),
-    ("float64", True): ("sort-segment", "sort-segment"),
-    ("float32", False): ("dict", "sort-segment"),
-    ("float32", True): ("banded", "sort-segment"),
+    ("float64", False): ("few-or-sort", "few-or-sort"),
+    ("float64", True): ("few-or-sort", "few-or-sort"),
+    ("float32", False): ("dict", "few-or-sort"),
+    ("float32", True): ("banded", "few-or-sort"),
     ("int32", False): ("dict", "banded"),
     ("int32", True): ("banded", "banded"),
     ("int64", False): ("dict", "banded"),
@@ -250,7 +250,7 @@ def test_float64_sum_and_average_read_the_same_with_the_lanes_off(func, wide):
             out.append(agg.collect().to_pandas().sort_values(
                 "k", ignore_index=True))
         assert (agg._lane, agg._merge_exec._lane) == (
-            "sort-segment", "sort-segment")
+            "few-or-sort", "few-or-sort")
     pd.testing.assert_frame_equal(out[0], out[1], check_exact=True)
     want = _frames(batches).groupby("k").agg(
         a=("v", "sum" if func == "sum" else "mean")).reset_index()
@@ -268,7 +268,7 @@ def test_average_merges_its_float64_sum_in_float64(measure):
                         wide=True)
     with C.session(C.RapidsConf(DEFAULTS)):
         got = agg.collect().to_pandas().sort_values("k", ignore_index=True)
-    assert (agg._lane, agg._merge_exec._lane) == ("banded", "sort-segment")
+    assert (agg._lane, agg._merge_exec._lane) == ("banded", "few-or-sort")
     want = _frames(batches).astype({"v": np.float64}).groupby("k").agg(
         a=("v", "mean")).reset_index()
     np.testing.assert_allclose(got["a"].to_numpy(), want["a"].to_numpy(),

@@ -847,9 +847,10 @@ def test_phase_spans_carry_their_args(q3_profiled):
         sum(s.args["rows_out"] for s in first)
     for update, merge in zip(updates, merges):
         assert update.args["phase"] == "update"
-        # q3 sums a FLOAT64 expression: float64 on the sort-segment
-        # lane, whatever the (default-on) lane switches say
-        assert update.args["lane"] == "sort-segment"
+        # q3 sums a FLOAT64 expression: float64 in the grouped kernel
+        # (its few-groups body or its sort body, as the batch has
+        # groups), whatever the (default-on) lane switches say
+        assert update.args["lane"] == "few-or-sort"
         # one partial a partition: there is nothing to merge it with
         assert merge.args["partials"] == update.args["batches"] == 1
         assert merge.args["lane"] is None and merge.args["rounds"] == 0

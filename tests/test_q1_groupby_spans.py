@@ -56,7 +56,13 @@ def q1(tables):
     assert none is None and len(plain) == 4
     answer, prof, syncs = _run(1, tables, True)
     assert answer.equals(plain)
+    from spark_rapids_tpu.plan.overrides import ExecutionPlanCapture
+    PROFILED_PLAN.append(ExecutionPlanCapture.last_plan)
     return prof, syncs, plain_syncs
+
+
+#: the plan of `q1`'s profiled run (later runs replace the capture's)
+PROFILED_PLAN: list = []
 
 
 def _named(prof, name):
@@ -193,14 +199,49 @@ def test_the_new_span_and_arguments_read_nothing_from_the_device(q1):
     assert own == plain_syncs
 
 
-def test_q1_takes_the_sort_segment_lane_under_the_default_conf(q1):
+def _nodes(plan, name, out=None):
+    out = [] if out is None else out
+    if type(plan).__name__ == name:
+        out.append(plan)
+    for c in getattr(plan, "children", []):
+        _nodes(c, name, out)
+    return out
+
+
+def test_q1_takes_the_few_groups_body_under_the_default_conf(q1):
     """Eight FLOAT64 aggregates on two STRING keys: no dict, banded or
     MXU lane takes them (`_measure_types`, `_dict_plan`), in the update
-    or in the merge."""
+    or in the merge; the grouped kernel does, and since every batch of
+    q1 has four groups its few-groups body runs every time: no sort, and
+    the FLOAT64 measures still summed in float64."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu import types as T
+    from spark_rapids_tpu.utils import metrics as M
     prof, _, _ = q1
     updates = _named(prof, "exec:groupby-update")
     merges = _named(prof, "exec:groupby-merge")
     assert {s.args["phase"] for s in updates} == {"update", "merge"}
-    assert {s.args["lane"] for s in updates} == {"sort-segment"}
-    assert {s.args["lane"] for s in merges} <= {"sort-segment", None}
-    assert "sort-segment" in {s.args["lane"] for s in merges}
+    assert {s.args["lane"] for s in updates} == {"few-or-sort"}
+    assert {s.args["lane"] for s in merges} <= {"few-or-sort", None}
+    assert "few-or-sort" in {s.args["lane"] for s in merges}
+    # the counter: every batch either aggregate was handed (the partial
+    # one's updates and merges, the final one's merges) took the few body
+    aggs = _nodes(PROFILED_PLAN[-1], "HashAggregateExec")
+    (partial,) = [a for a in aggs if a._pre_stage is not None]
+    (final,) = [a for a in aggs if a._pre_stage is None]
+    n_updates = sum(s.args["batches"] for s in updates
+                    if s.args["phase"] == "update")
+    # the partial aggregate's updates, a chunk each, and its merge of
+    # them, once a partition
+    assert n_updates == PARTITIONS * 3
+    assert partial.metrics.value(M.NUM_FEW_GROUPS_OFFERED) == \
+        n_updates + PARTITIONS
+    for agg in (partial, final):
+        offered = agg.metrics.value(M.NUM_FEW_GROUPS_OFFERED)
+        assert offered > 0
+        assert agg.metrics.value(M.NUM_FEW_GROUP_BATCHES) == offered
+    # float64 by construction: the partial layout's sum columns
+    sums = [f for f in partial.output_schema().fields
+            if f.name.startswith(("sum_", "avg_")) and f.name.endswith("#0")]
+    assert len(sums) == 7 and all(f.dtype == T.FLOAT64 for f in sums)
+    assert T.FLOAT64.storage_dtype == jnp.float64
