@@ -523,13 +523,14 @@ class TpuExec:
                     # waits for the device and reads back: the first
                     # half of the query's `exec:Readback`
                     with P.span(P.SPAN_READBACK) as sp:
-                        out = self._drain(out, mark)
+                        out, tally = self._drain(out, mark)
                         if sp is not None:
                             sp.args = {
                                 "phase": "drain",
                                 "rows": out.num_rows
                                 if out.num_rows_known else None,
-                                "bytes": out.device_size_bytes()}
+                                "bytes": out.device_size_bytes(),
+                                **tally}
                     return out
                 except CK.FastPathInvalid as e:
                     if final:
@@ -573,27 +574,31 @@ class TpuExec:
             scope.close()
 
     @staticmethod
-    def _drain(out: ColumnarBatch, mark) -> ColumnarBatch:
+    def _drain(out: ColumnarBatch, mark) -> tuple[ColumnarBatch, dict]:
         """The sync boundary of a collect: densify, start the copies to
-        the host, resolve the deferred checks."""
+        the host, resolve the deferred checks.  Returns the batch and
+        `CK.verify`'s tally (`checks_given`, `checks_read`)."""
         from spark_rapids_tpu import config as C
         from spark_rapids_tpu.utils import checks as CK
         out = out.dense()
         out.prefetch()
         # ONE verify over batch checks + the query's registered checks
         # = one stacked flag readback (a second verify call would pay
-        # its own round trip).  Under the async pipeline layer the
+        # its own round trip); the two hold the same checks, which
+        # verify reads once each.  Under the async pipeline layer the
         # batch's lazy row count rides the SAME readback (host-sync
         # diet: the to_pandas conversion right after this otherwise
         # pays its own round trip for the count).
         checks = list(out.checks) + CK.drain_since(mark)
+        tally: dict = {}
         if (not out.num_rows_known
                 and C.get_active_conf()[C.PIPELINE_ENABLED]):
-            (rows,) = CK.verify(checks, scalars=[out.num_rows_i32])
+            (rows,) = CK.verify(checks, scalars=[out.num_rows_i32],
+                                tally=tally)
             out.num_rows = int(rows)
         else:
-            CK.verify(checks)
-        return out
+            CK.verify(checks, tally=tally)
+        return out, tally
 
     def _collect_once(self) -> ColumnarBatch:
         from spark_rapids_tpu.columnar.batch import concat_batches, empty_batch

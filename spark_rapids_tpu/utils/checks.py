@@ -22,6 +22,8 @@ import dataclasses
 import threading
 from typing import Callable, Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 
@@ -198,92 +200,118 @@ def register(check: BatchCheck) -> BatchCheck:
     return check
 
 
-def verify(checks, scalars=()) -> list:
+def _stack_int32(*items):
+    return jnp.stack([jnp.asarray(f).astype(jnp.int32).reshape(())
+                      for f in items])
+
+
+_stack_int32.__name__ = "checks_stack"         # its name on the device
+_STACK = jax.jit(_stack_int32)
+#: the least arity of `_STACK`.  A call's arity is padded up to a power
+#: of two by repeating its LAST item, so the operands' dtypes and
+#: placements past the real ones follow the real ones: a run of one
+#: kind (bool flags) then compiles one program a width, and 2-300 such
+#: items at most seven.  (A padding constant of its own dtype would
+#: make the operands' signature, and so the program, one an arity.)
+_STACK_MIN = 8
+
+
+def _device_key(f):
+    try:
+        return frozenset(f.devices())
+    except Exception:
+        return None
+
+
+def _read_group(values: list) -> np.ndarray:
+    """The host values of one device group's arrays, in one blocking
+    read: one item read directly, more stacked as int32 by `_STACK` (one
+    dispatch).  The padding repeats an item of the group, so it is on
+    the group's devices."""
+    note_host_sync("checks.verify", nbytes=4 * len(values))
+    if len(values) == 1:
+        return np.asarray(values[0]).reshape(1)
+    width = max(_STACK_MIN, 1 << (len(values) - 1).bit_length())
+    return np.asarray(_STACK(*values, *[values[-1]] * (width - len(values))))
+
+
+def verify(checks, scalars=(), tally: Optional[dict] = None) -> list:
     """Resolve the given checks now (syncs); raise on any failure.
 
-    Device flags are stacked into one tiny array PER DEVICE GROUP and
-    pulled in one D2H transfer per group (single-chip: exactly one) —
-    per-array readbacks cost a full device round trip each, which
-    adds up when a query carries dozens of checks.
-    Flags with no identifiable single device (e.g. sharded across a
+    A check given more than once (a drain hands over a batch's checks
+    AND the registry, which hold the same ones) is read once: checks
+    are taken distinct by identity, in first-seen order, and one
+    already resolved is not read again.  The unresolved device flags
+    are read in ONE dispatch and ONE device-to-host transfer per device
+    group (single-chip: exactly one): `_STACK` casts each to an int32
+    scalar and stacks them, where casting and stacking eagerly cost
+    about three dispatches a flag.  A group of one item is read
+    directly.  Flags whose group does not stack (e.g. sharded across a
     mesh) fall back to per-flag readback.
 
     `scalars`: extra device int scalars (e.g. a collect's lazy output
     row count) that ride the SAME stacked readback — the host-sync diet
     for the collect boundary, which otherwise pays a second full round
-    trip reading the row count right after the flag wave.  Returns
-    their host values (ints), in order."""
+    trip reading the row count right after the flag wave.  They are
+    positional and never made distinct.  Returns their host values
+    (ints), in order.
+
+    `tally`, when given, receives `checks_given` (the checks handed in)
+    and `checks_read` (the distinct unresolved checks this call read);
+    both are known on the host."""
     checks = list(checks)
+    given = len(checks)
+    checks = list(dict.fromkeys(checks))
     scalars = list(scalars)
     scalar_vals: list = [None] * len(scalars)
+    unresolved = [c for c in checks if c._resolved is None]
+    if tally is not None:
+        tally["checks_given"] = given
+        tally["checks_read"] = len(unresolved)
     if not checks and not scalars:
         return scalar_vals
-    device_items, host_bad = [], []
-    for i, c in enumerate(checks):
-        if c._resolved is not None:
-            if c._resolved:
-                host_bad.append(i)
-            continue
-        f = c.flag
-        if hasattr(f, "devices") or hasattr(f, "sharding"):
-            device_items.append(("check", i, f))
-        else:
-            c._memoize(bool(np.asarray(f)))
-            if c._resolved:
-                host_bad.append(i)
+    # scalars first: a group's last item, which the padding repeats, is
+    # then a flag wherever there are flags
+    device_items = []
     for j, s in enumerate(scalars):
         if hasattr(s, "devices") or hasattr(s, "sharding"):
-            device_items.append(("scalar", j, s))
+            device_items.append((j, s))
         else:
             scalar_vals[j] = int(np.asarray(s))
-    bad_set = set(host_bad)
-    if device_items:
-        import jax.numpy as jnp
+    for c in unresolved:
+        f = c.flag
+        if hasattr(f, "devices") or hasattr(f, "sharding"):
+            device_items.append((c, f))
+        else:
+            c._memoize(bool(np.asarray(f)))
 
-        def _dev_key(f):
-            try:
-                return frozenset(f.devices())
-            except Exception:
-                return None
+    def resolve(owner, v) -> None:
+        if isinstance(owner, int):
+            scalar_vals[owner] = int(v)
+        else:
+            owner._memoize(bool(v))
 
-        # stack per device: jnp.stack raises on mixed-device operands
-        # (multichip runs commit flags to different mesh devices).
-        # Flags widen to int32 so row-count scalars share the stack.
-        groups: dict = {}
-        for kind, i, f in device_items:
-            groups.setdefault(_dev_key(f), []).append((kind, i, f))
-        for items in groups.values():
-            try:
-                note_host_sync("checks.verify", nbytes=4 * len(items))
-                stacked = np.asarray(jnp.stack(
-                    [jnp.asarray(f).astype(jnp.int32).reshape(())
-                     for _, _, f in items]))
-                for (kind, i, _), v in zip(items, stacked):
-                    if kind == "scalar":
-                        scalar_vals[i] = int(v)
-                    else:
-                        checks[i]._memoize(bool(v))
-                        if v:
-                            bad_set.add(i)
-            except Exception:
-                # arbitrary placement (e.g. flags sharded across devices):
-                # per-item readback still resolves correctly
-                for kind, i, f in items:
-                    note_host_sync("checks.verify", nbytes=4)
-                    if kind == "scalar":
-                        scalar_vals[i] = int(np.asarray(f))
-                        continue
-                    checks[i]._memoize(bool(np.asarray(f)))
-                    if checks[i]._resolved:
-                        bad_set.add(i)
-    bad = [c for i, c in enumerate(checks) if i in bad_set]
-    with _LOCK:
-        pending = _pending_list()
-        for c in checks:
-            try:
-                pending.remove(c)
-            except ValueError:
-                pass
+    groups: dict = {}
+    for owner, f in device_items:
+        groups.setdefault(_device_key(f), []).append((owner, f))
+    for items in groups.values():
+        try:
+            values = _read_group([f for _, f in items])
+        except Exception:
+            # arbitrary placement (e.g. flags sharded across devices):
+            # per-item readback still resolves correctly
+            for owner, f in items:
+                note_host_sync("checks.verify", nbytes=4)
+                resolve(owner, np.asarray(f))
+            continue
+        for (owner, _), v in zip(items, values):
+            resolve(owner, v)
+    bad = [c for c in checks if c._resolved]
+    if checks:
+        done = set(checks)
+        with _LOCK:
+            pending = _pending_list()
+            pending[:] = [c for c in pending if c not in done]
     for c in bad:
         if c.error is not None:
             raise c.error()

@@ -298,3 +298,19 @@ def test_grouped_kernel_compiles_at_small_capacities_for_v5e(one_chip,
     compiled = getattr(kern, "_ck_fn", kern).lower(*args).compile()
     assert " conditional(" in compiled.as_text()
     assert compiled.memory_analysis() is not None
+
+
+# -- the collect boundary's read of its deferred check flags ---------------
+@pytest.mark.parametrize("width", [8, 128, 256])
+def test_check_stack_compiles_for_v5e(one_chip, width):
+    """`utils/checks._STACK` at the arities a cell dispatches (q1 at SF1:
+    about 95 distinct flags and the row count, padded to 128; 8 the
+    least): the row count, then bool flags, each cast to int32 and
+    stacked."""
+    from spark_rapids_tpu.utils import checks as CK
+    args = [_spec(one_chip, (), jnp.int32)] + [
+        _spec(one_chip, (), jnp.bool_) for _ in range(width - 1)]
+    compiled = CK._STACK.lower(*args).compile()
+    (out,) = jax.tree_util.tree_leaves(compiled.out_info)
+    assert out.shape == (width,) and out.dtype == jnp.int32
+    assert compiled.memory_analysis() is not None

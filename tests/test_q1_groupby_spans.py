@@ -199,6 +199,27 @@ def test_the_new_span_and_arguments_read_nothing_from_the_device(q1):
     assert own == plain_syncs
 
 
+def test_the_drain_reads_each_collision_flag_once(tables, monkeypatch):
+    """Each grouped batch registers a deferred collision flag that rides
+    on its batch AND on the query's registry, so the collect's drain is
+    handed every flag twice; its `exec:Readback` span says so, and that
+    `checks.verify` read each once, in its one host sync."""
+    registered, original = [], CK.register
+
+    def register(check):
+        registered.append(check)
+        return original(check)
+    monkeypatch.setattr(CK, "register", register)
+    _, prof, syncs = _run(1, tables, True)
+    assert registered
+    assert {c.origin.split("[")[0] for c in registered} == {"hashGroupby"}
+    (drain,) = [s for s in _named(prof, "exec:Readback")
+                if s.args["phase"] == "drain"]
+    assert drain.args["checks_read"] == len(set(registered))
+    assert drain.args["checks_given"] == 2 * drain.args["checks_read"]
+    assert syncs["checks.verify"] == 1
+
+
 def _nodes(plan, name, out=None):
     out = [] if out is None else out
     if type(plan).__name__ == name:
